@@ -24,7 +24,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_arch
 from repro.configs.base import ArchSpec, ShapeSpec
 from repro.configs.lider_msmarco import RetrievalArchConfig
@@ -43,7 +42,7 @@ PEAK_FLOPS, HBM_BW, LINK_BW = 197e12, 819e9, 50e9
 def measure(bundle, mesh, loop_factor=None) -> dict:
     lf = loop_factor if loop_factor is not None else bundle.loop_factor
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         jf = jax.jit(
             bundle.fn,
             in_shardings=bundle.in_shardings,

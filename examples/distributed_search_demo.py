@@ -13,7 +13,6 @@ import time  # noqa: E402
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import compat  # noqa: E402
 from repro.core import distributed, lider  # noqa: E402
 from repro.core.baselines import flat_search  # noqa: E402
 from repro.core.utils import l2_normalize, recall_at_k  # noqa: E402
@@ -21,7 +20,7 @@ from repro.data import synthetic  # noqa: E402
 
 
 def main():
-    mesh = compat.mesh_from_devices(
+    mesh = jax.sharding.Mesh(
         np.array(jax.devices()).reshape(4, 2), ("data", "model")
     )
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "

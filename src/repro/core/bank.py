@@ -443,15 +443,32 @@ def refit_cluster(
     return sorted_keys, sorted_pos, resc, r
 
 
-@partial(jax.jit, static_argnames=("n_leaves",))
-def _fit_all_clusters(lsh, row_embs, row_valid, *, n_leaves):
-    return jax.vmap(partial(refit_cluster, lsh, n_leaves=n_leaves))(
-        row_embs, row_valid
-    )
+@partial(jax.jit, static_argnames=("n_leaves", "storage_dtype"))
+def _fit_all_clusters(lsh, stored, scales, row_valid, *, n_leaves, storage_dtype):
+    """``refit_cluster`` over every cluster, on the storage-effective rows.
+
+    Clusters go through in chunks whose rows are dequantized inside the
+    map, so the build never holds a second full ``(c, Lp, d)`` f32 table
+    beside the raw rows (at deployment scale that copy alone is most of a
+    chip's memory). Per-cluster math is unchanged, so the fit stays
+    bit-identical to the online refit in ``core.update``.
+    """
+
+    def fit(args):
+        rows, scl, valid = args
+        if scl is not None:
+            rows = dequantize_codes(rows, scl, storage_dtype)
+        return refit_cluster(lsh, rows, valid, n_leaves=n_leaves)
+
+    return jax.lax.map(fit, (stored, scales, row_valid), batch_size=64)
 
 
+@jax.jit
 def gather_cluster_rows(embs: jnp.ndarray, gids: jnp.ndarray) -> jnp.ndarray:
-    """Pack corpus rows into ``(c, Lp, d)`` per-cluster slots (zero at pads)."""
+    """Pack corpus rows into ``(c, Lp, d)`` per-cluster slots (zero at pads).
+
+    One jit, so the gather and the pad mask fuse into a single output
+    buffer instead of two full-size eager temporaries."""
     valid = gids >= 0
     return embs[jnp.maximum(gids, 0)] * valid[..., None]
 
@@ -485,6 +502,32 @@ def store_rows(
     raise ValueError(
         f"storage_dtype must be one of {STORAGE_DTYPES}, got {storage_dtype!r}"
     )
+
+
+@partial(jax.jit, static_argnames=("storage_dtype",), donate_argnums=(0,))
+def _encode_into(out, raw_chunk, start, storage_dtype):
+    stored, scales, _, sketches = store_rows(raw_chunk, storage_dtype)
+    return tuple(
+        jax.lax.dynamic_update_slice_in_dim(o, v, start, axis=0)
+        for o, v in zip(out, (stored, scales, sketches))
+    )
+
+
+def _encode_rows(raw_rows, storage_dtype, *, chunk: int = 32):
+    """Quantized ``store_rows`` without the rescore table: (codes, scales,
+    sketches), one jit call per ``chunk`` clusters written in place. The
+    sketch packing makes XLA relayout its input; over the whole
+    ``(c, Lp, d)`` table (also inside a scan, where the relayout is
+    hoisted) that copy alone is the table's size again."""
+    shapes = jax.eval_shape(
+        lambda r: store_rows(r, storage_dtype), raw_rows
+    )
+    out = tuple(
+        jnp.zeros(sh.shape, sh.dtype) for sh in (shapes[0], shapes[1], shapes[3])
+    )
+    for i in range(0, raw_rows.shape[0], chunk):
+        out = _encode_into(out, raw_rows[i:i + chunk], i, storage_dtype)
+    return out
 
 
 def set_rescore_tier(bank: ClusterBank, tier: str) -> ClusterBank:
@@ -587,17 +630,21 @@ def build_bank(
         raise CapacityOverflowError(n_dropped, capacity)
     gids, sizes = clustering.group_by_cluster(assignment, n_clusters, capacity)
     raw_rows = gather_cluster_rows(embs, gids)
-    stored, emb_scales, rescore_embs, sketches = store_rows(
-        raw_rows, storage_dtype
-    )
+    if storage_dtype in QUANTIZED_DTYPES:
+        # Encoded chunk by chunk in place (eagerly, each step of the chain
+        # would allocate a full-size f32 temporary); the raw rows
+        # themselves are the rescore table, so they are not routed through
+        # a jit (its output would be a copy).
+        stored, emb_scales, sketches = _encode_rows(raw_rows, storage_dtype)
+        rescore_embs = raw_rows
+    else:
+        stored, emb_scales, rescore_embs, sketches = store_rows(
+            raw_rows, storage_dtype
+        )
     lsh = lsh_lib.make_lsh(rng, embs.shape[-1], n_arrays, key_len)
-    fit_rows = (
-        dequantize_codes(stored, emb_scales, storage_dtype)
-        if emb_scales is not None
-        else stored
-    )
     sorted_keys, sorted_pos, resc, r = _fit_all_clusters(
-        lsh, fit_rows, gids >= 0, n_leaves=n_leaves
+        lsh, stored, emb_scales, gids >= 0, n_leaves=n_leaves,
+        storage_dtype=storage_dtype,
     )
     store = None
     if rescore_tier == "host":

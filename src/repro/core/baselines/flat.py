@@ -29,7 +29,11 @@ def flat_search(
     def body(carry, args):
         ids, scores = carry  # (B, k) running top-k
         chunk_embs, chunk_start = args
-        s = queries @ chunk_embs.T  # (B, chunk)
+        # HIGHEST: the TPU's default f32 matmul is one bf16 pass, and this
+        # is the exact reference recall is measured against.
+        s = jnp.dot(
+            queries, chunk_embs.T, precision=jax.lax.Precision.HIGHEST
+        )  # (B, chunk)
         cand_ids = chunk_start + jnp.arange(chunk, dtype=jnp.int32)
         cand_ids = jnp.where(cand_ids < n, cand_ids, -1)
         s = jnp.where(cand_ids[None, :] < 0, -jnp.inf, s)

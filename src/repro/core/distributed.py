@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import compat, faults
+from .. import faults
 from .bank import replicated_field_names
 from .clustering import update_centroids
 from .core_model import TopK, search_core_model
@@ -369,11 +369,12 @@ def make_sharded_search(
     qspec = P(qaxes, None) if qaxes else P(None, None)
     # shard_health is a small replicated (S,) bool vector — a *traced*
     # input, so flipping shard liveness reuses the compiled program.
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         body_provisional if host_tier else body,
         mesh=mesh,
         in_specs=(param_specs, qspec, P()),
         out_specs=(qspec, qspec, P()),
+        check_vma=False,
     )
     run = jax.jit(sharded)
 
@@ -523,10 +524,10 @@ def _make_grouped_search(
 
     c_local = c_total // n_cluster_shards
 
-    def _route(params, queries):
+    def _route(centroid_cm, centroids, queries):
         routed = search_core_model(
-            params.centroid_cm,
-            params.centroids,
+            centroid_cm,
+            centroids,
             queries,
             k=n_probe,
             r0=r0_centroid,
@@ -535,7 +536,19 @@ def _make_grouped_search(
         )
         return prune_probes(routed.ids, routed.scores, prune_margin)
 
-    route_jit = jax.jit(_route)
+    # Every device routes the whole batch against its replica of the
+    # centroid retriever. Inside shard_map the routing's Pallas kernels run
+    # per device; a plain jit over mesh-placed inputs would ask the TPU
+    # compiler to partition them, which it cannot.
+    route_jit = jax.jit(
+        jax.shard_map(
+            _route,
+            mesh=mesh,
+            in_specs=(P(), P(), P()),
+            out_specs=P(),
+            check_vma=False,
+        )
+    )
 
     _CELL_KEYS = (
         "sel", "sel_valid", "sel_b", "sel_cid_local", "dropped",
@@ -709,18 +722,21 @@ def _make_grouped_search(
         spec3, spec3, spec3, spec3, spec2, spec3, spec4, spec4, spec4
     )
     run = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             gbody,
             mesh=mesh,
             in_specs=(param_specs, qspec, P(), *cell_specs),
             out_specs=(qspec, qspec, P()),
+            check_vma=False,
         )
     )
 
     def search(params: LiderParams, queries: jnp.ndarray, shard_health=None):
         health = resolve_health(shard_health)
         note_health(search, health)
-        cids_np = np.asarray(jax.device_get(route_jit(params, queries)))
+        cids_np = np.asarray(jax.device_get(
+            route_jit(params.centroid_cm, params.centroids, queries)
+        ))
         cells = _host_cells(cids_np)
         cell_args = tuple(jnp.asarray(cells[key]) for key in _CELL_KEYS)
         rows_or_ids, sc, dropped = run(
@@ -773,10 +789,11 @@ def make_sharded_kmeans_step(
         return update_centroids(centroids, sums, counts)
 
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(daxes, None), P()),
             out_specs=P(),
+            check_vma=False,
         )
     )

@@ -1174,11 +1174,5 @@ def query_path_cache_size() -> int:
     can touch. After ``RetrievalEngine.warmup()`` this number must stay
     flat across any mix of batch sizes and ladder rungs — a delta means a
     query ate an XLA re-trace (tests + ``benchmarks.serve_scale`` gate on
-    delta == 0). Uses the jit cache-size introspection when this jax
-    version exposes it; contributes 0 per function otherwise."""
-    total = 0
-    for name in _QUERY_PATH_JITS:
-        fn = globals()[name]
-        if hasattr(fn, "_cache_size"):
-            total += fn._cache_size()
-    return total
+    delta == 0)."""
+    return sum(globals()[name]._cache_size() for name in _QUERY_PATH_JITS)

@@ -17,14 +17,15 @@ resolution problem" of binary alphabets while preserving the linear order
 
 TPU adaptation: hashing a corpus is a single fused ``X @ P`` matmul + sign +
 bit-pack; the Pallas kernel ``repro.kernels.lsh_hash`` streams this without
-materialising the ``(N, H*M)`` float tensor. Pure-jnp path below is the
-oracle and the default on CPU.
+materialising the ``(N, H*M)`` float tensor, and :func:`hash_vectors` runs it
+on TPU (its pure-jnp reference elsewhere).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from ..kernels.ops import lsh_hash_op
 from .types import pytree_dataclass
 
 # Sentinel key for padded slots (requires M <= 31). Python int, not a jnp
@@ -73,11 +74,20 @@ def unpack_bits(keys: jnp.ndarray, key_len: int) -> jnp.ndarray:
 
 
 def hash_vectors(params: LSHParams, x: jnp.ndarray) -> jnp.ndarray:
-    """Hash (..., dim) vectors into (..., H) packed uint32 hashkeys."""
-    proj = x.astype(jnp.float32) @ params.projections  # (..., H*M)
-    bits = (proj >= 0.0).astype(jnp.uint32)
-    bits = bits.reshape(*x.shape[:-1], params.n_arrays, params.key_len)
-    return pack_bits(bits)
+    """Hash (..., dim) vectors into (..., H) packed uint32 hashkeys.
+
+    One function for build and query, so both sides of every sorted array
+    hash alike: the ``lsh_hash`` Pallas kernel on TPU, its reference
+    (``kernels.ref.lsh_hash_ref``, the same projection + sign + big-endian
+    pack) elsewhere.
+    """
+    keys = lsh_hash_op(
+        x.reshape(-1, x.shape[-1]),
+        params.projections,
+        n_arrays=params.n_arrays,
+        key_len=params.key_len,
+    )
+    return keys.reshape(*x.shape[:-1], params.n_arrays)
 
 
 def mask_padded(keys: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:

@@ -12,6 +12,8 @@ which is what makes restart replay exact (fault_tolerance contract).
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,7 +30,15 @@ def retrieval_corpus(
 ) -> jnp.ndarray:
     """Clustered unit-norm corpus (N, d). ~256 points/mode approximates the
     local neighborhood density of real passage-embedding spaces."""
-    n_modes = n_modes or max(16, n // 256)
+    return _retrieval_corpus(
+        seed, n=n, dim=dim, n_modes=n_modes or max(16, n // 256), spread=spread
+    )
+
+
+# One jit: generated eagerly, the draw, the mode gather, their sum and the
+# normalisation would each hold a full (N, d) f32 buffer at once.
+@partial(jax.jit, static_argnames=("n", "dim", "n_modes"))
+def _retrieval_corpus(seed, *, n: int, dim: int, n_modes: int, spread: float):
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
     modes = jax.random.normal(k1, (n_modes, dim))
     assign = jax.random.randint(k2, (n,), 0, n_modes)

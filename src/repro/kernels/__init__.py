@@ -2,10 +2,10 @@
 
 - ``lsh_hash``      — fused projection + sign + bit-pack (build & query hash)
 - ``kmeans_assign`` — tiled distance + running argmin (Stage-1 Lloyd)
-- ``fused_verify``  — gather-score-reduce candidate verification: scalar-
-  prefetched ids steer double-buffered row DMAs, scores stay in VMEM, and a
-  streaming dedup top-k is the only HBM output (DESIGN.md
-  §Verification-kernel)
+- ``fused_verify``  — score-reduce candidate verification over XLA-gathered
+  candidate tiles: scores stay in VMEM and a streaming dedup top-k is the
+  only HBM output (DESIGN.md §Verification-kernel); ``sketch_prefilter``
+  and ``fused_verify_grouped`` share its merge
 
 ``ops`` holds the jit'd dispatchers (TPU -> kernel, CPU -> ``ref`` oracle);
 ``ref`` holds the pure-jnp oracles the tests sweep against.

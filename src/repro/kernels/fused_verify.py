@@ -1,43 +1,43 @@
-"""Fused gather-score-reduce verification kernel (the LIDER hot path).
+"""Fused score-reduce verification kernel (the LIDER hot path).
 
 LIDER's end-to-end AQT is dominated by candidate verification (paper
 Sec. 3.1/3.3.2): after the RMI predicts positions, each query gathers its
 ``C = P*H*R`` candidate embeddings and scores them exactly. The materialized
-formulation (``ref.verify_topk_ref``) writes a ``(B, C, d)`` candidate tensor
-to HBM, re-reads it for the einsum, and round-trips a ``(B, C)`` score matrix
-through the dedup/top-k — all traffic a fused kernel never needs to emit
-(DESIGN.md §Verification-kernel has the byte model).
+formulation (``ref.verify_topk_ref``) scores the gathered ``(B, C, d)``
+candidate tensor with an einsum and round-trips a ``(B, C)`` score matrix
+through the dedup/top-k (DESIGN.md §Verification-kernel has the byte
+model).
 
-This kernel makes verification a single VMEM-resident pass per query:
+This kernel makes scoring and selection a single VMEM-resident pass per
+query:
 
-- candidate row ids are **scalar-prefetched** (SMEM) so the kernel can steer
-  row-granularity DMAs itself;
-- each grid step streams ``block_c`` embedding rows HBM->VMEM with
-  **double-buffered async copies** (``pltpu.make_async_copy``): block ``j+1``
-  is in flight while block ``j`` is scored;
+- XLA gathers the candidate rows into a ``(B, n_blocks, block_c, width)``
+  block (the TPU's tiled HBM layouts pad rows below 128 lanes and pack 2-4
+  narrow rows per 32-bit sublane word, so the compiler refuses single-row
+  DMAs from inside the kernel), and the BlockSpec pipeline streams one
+  ``block_c`` tile per grid step, block ``j+1`` in flight while ``j`` is
+  scored;
 - scoring runs on the MXU in the embedding storage dtype (bf16 stays bf16;
   int8 code tables run **int8×int8→int32** with the per-candidate combined
   scale folded in afterwards — DESIGN.md §Quantized bank) with full-width
-  accumulation; packed int4 tables (``code_dtype="int4"``) DMA half the
-  bytes and unpack to int8 **in VMEM** (two arithmetic shifts) before the
-  same int8×int8→int32 pass — the HBM stream is 0.5 B/elem;
+  accumulation; packed int4 tables (``code_dtype="int4"``) unpack to int8
+  **in VMEM** (two arithmetic shifts) before the same int8×int8→int32 pass;
 - a masked **streaming top-k accumulator** lives in VMEM and merges each
   block with duplicate suppression (same semantics as
   ``core.utils.dedup_topk``: duplicates of one id carry equal scores, so
   keeping the first-selected occurrence is exact).
 
-Only the ``(B, k)`` result ever leaves the chip; neither the candidate tensor
-nor the score matrix exists in HBM.
+Neither the score matrix nor the dedup/sort round-trips exist in HBM; only
+the ``(B, k)`` result is written.
 
 ``row_ids`` index the embedding table (what to gather); ``out_ids`` are the
 ids to *report and dedup by* (defaults to ``row_ids``). LIDER passes flat
 ``(cluster, slot)`` rows as ``row_ids`` and global passage ids as
 ``out_ids``. ``out_ids < 0`` marks padding (scored ``-inf``).
 
-A second scalar-prefetch array carries per-(row, block) valid-candidate
-counts so fully-dead blocks (all probes pruned by the adaptive margin rule,
-or pure padding) skip their DMA issue/wait and MXU pass under ``pl.when`` —
-the mechanism that turns probe pruning into wall-clock savings (DESIGN.md
+Per-(row, block) valid-candidate counts ride the scalar prefetch so
+fully-dead blocks (all probes pruned by the adaptive margin rule, or pure
+padding) skip their MXU pass and merge under ``pl.when`` (DESIGN.md
 §Adaptive speed-quality control plane).
 """
 from __future__ import annotations
@@ -63,152 +63,233 @@ def _unpack_int4_vmem(rows: jnp.ndarray) -> jnp.ndarray:
     match outside the kernel (``quant.deinterleave_query_codes``), so the
     dot product over the full width is exact.
     """
-    lo = jnp.right_shift(jnp.left_shift(rows, 4).astype(jnp.int8), 4)
-    hi = jnp.right_shift(rows, 4)
-    return jnp.concatenate([lo, hi], axis=-1)
+    # Shifts run on the sign-extended int32 view: the TPU's vector unit has
+    # no 8-bit shifts.
+    x = rows.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(x, 28), 28)
+    hi = jnp.right_shift(x, 4)
+    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.int8)
 
 
 def _clamp_block_c(block_c: int, c: int) -> int:
     """Effective candidate-block width: ``min(block_c, c)`` rounded down to a
-    sublane-aligned multiple of 8 (floor 8). The round-down keeps the VMEM
-    scratch and the MXU operand shapes aligned when ``c`` is not a multiple
-    of the requested ``block_c``; the wrapper pads the candidate axis up to a
-    multiple of the result, so a ragged last block is always well-formed
-    rather than relying on caller-side padding being exact.
+    sublane-aligned multiple of 8 (floor 8). The round-down keeps the
+    candidate tiles and the MXU operand shapes aligned when ``c`` is not a
+    multiple of the requested ``block_c``; the wrapper pads the candidate
+    axis up to a multiple of the result, so a ragged last block is always
+    well-formed rather than relying on caller-side padding being exact.
     """
     return max(8, (min(block_c, c) // 8) * 8)
 
 
-def _fused_verify_kernel(
-    # scalar prefetch
-    row_ids_s,
-    blk_live_s,
-    # inputs: q_ref, oid_ref, [scl_ref if quantized], emb_hbm
-    q_ref,
-    oid_ref,
+def _merge_topk(acc_sc, acc_ids, sc, ids, k: int):
+    """Streaming dedup top-k merge of ``(R, k)`` accumulators with an
+    ``(R, n)`` block of scores/ids, row-wise.
+
+    Selects the max ``k`` times from [accumulator ++ block], keeping the two
+    halves apart rather than copying them into one array; each selection
+    kills every copy of the selected id in both halves (duplicates carry
+    equal scores, so this is exact). One helper serves the per-query
+    kernels (R = 1) and the grouped kernel (R = block_q).
+    Score ties between distinct ids break toward the smallest id — the order
+    ``dedup_topk`` produces (stable top_k over id-sorted candidates). Rows
+    with fewer than ``k`` live candidates pad with (-1, -inf).
+    """
+    r = acc_sc.shape[0]
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (r, k), 1)
+    big = jnp.int32(2**31 - 1)
+
+    def row_min(x):
+        return jnp.min(x, axis=1, keepdims=True)
+
+    def sel_body(i, carry):
+        a_sc, b_sc, o_sc, o_id = carry
+        m = jnp.maximum(
+            jnp.max(a_sc, axis=1, keepdims=True),
+            jnp.max(b_sc, axis=1, keepdims=True),
+        )  # (R, 1)
+        sid = jnp.minimum(
+            row_min(jnp.where(a_sc == m, acc_ids, big)),
+            row_min(jnp.where(b_sc == m, ids, big)),
+        )
+        sid = jnp.where(m == NEG_INF, jnp.int32(-1), sid)
+        live = sid >= 0
+        a_sc = jnp.where((acc_ids == sid) & live, NEG_INF, a_sc)
+        b_sc = jnp.where((ids == sid) & live, NEG_INF, b_sc)
+        o_sc = jnp.where(iota_k == i, m, o_sc)
+        o_id = jnp.where(iota_k == i, sid, o_id)
+        return a_sc, b_sc, o_sc, o_id
+
+    init = (
+        acc_sc,
+        sc,
+        jnp.full((r, k), NEG_INF, jnp.float32),
+        jnp.full((r, k), -1, jnp.int32),
+    )
+    _, _, o_sc, o_id = jax.lax.fori_loop(0, k, sel_body, init)
+    return o_sc, o_id
+
+
+def _topk_kernel(
+    blk_live_s,  # scalar prefetch: (B * n_blocks,) live candidates per block
+    q_ref,  # (1, d_q) query (codes / sketch) of row bi
+    oid_ref,  # (1, block_c) candidate ids (-1 = padding/pruned)
     *rest,
-    block_c: int,
     k: int,
     n_blocks: int,
-    quantized: bool,
-    code_dtype: str,
+    n_extra: int,
+    score,
 ):
-    # Quantized banks carry one extra blocked input: the (1, block_c)
-    # combined per-candidate scale (row scale × query scale) folded into the
-    # int32 scores just before the top-k merge.
-    if quantized:
-        scl_ref, emb_hbm, ids_out, sc_out, cand, acc_ids, acc_sc, sem = rest
-    else:
-        scl_ref = None
-        emb_hbm, ids_out, sc_out, cand, acc_ids, acc_sc, sem = rest
+    """Per-query score -> dedup top-k over one candidate block
+    (grid = (B, n_blocks), candidate axis innermost).
+
+    ``rest`` is ``(*extra_refs, cand_ref, ids_out, sc_out)`` with
+    ``cand_ref`` the (block_c, width) candidate rows of this block;
+    ``score(rows, q, *extras) -> (1, block_c) f32`` is the only part that
+    differs between the float pass, the code pass and the sketch pass.
+    """
+    extras = rest[:n_extra]
+    cand_ref, ids_out, sc_out = rest[n_extra:]
     bi = pl.program_id(0)
     cj = pl.program_id(1)
-    slot = jax.lax.rem(cj, 2)
-    nslot = jax.lax.rem(cj + 1, 2)
 
-    # Block-skip contract (DESIGN.md §Adaptive): ``blk_live_s[bi, j]`` is the
-    # number of valid (out_id >= 0) candidates in block j of query row bi,
-    # known before the kernel runs (scalar prefetch). A dead block — every
-    # candidate pruned or padding — would only contribute -inf scores, so we
-    # skip its DMA issue/wait and its MXU pass entirely; the accumulator
-    # simply carries over. Probe pruning therefore saves wall-clock, not just
-    # emits -inf.
-    live = blk_live_s[bi, cj] > 0
-
-    def row_dma(blk, s, i):
-        row = row_ids_s[bi, blk * block_c + i]
-        return pltpu.make_async_copy(emb_hbm.at[row], cand.at[s, i], sem.at[s])
-
-    def start_block(blk, s):
-        def body(i, _):
-            row_dma(blk, s, i).start()
-            return 0
-
-        jax.lax.fori_loop(0, block_c, body, 0)
-
+    # The (1, k) output blocks stay resident across the cj axis (same block
+    # index), so they are the running top-k accumulator.
     @pl.when(cj == 0)
     def _():
-        # New query row: reset the accumulator.
-        acc_sc[...] = jnp.full_like(acc_sc, NEG_INF)
-        acc_ids[...] = jnp.full_like(acc_ids, -1)
+        sc_out[...] = jnp.full_like(sc_out, NEG_INF)
+        ids_out[...] = jnp.full_like(ids_out, -1)
 
-    @pl.when((cj == 0) & live)
+    # Block-skip contract (DESIGN.md §Adaptive): a block whose candidates are
+    # all invalid — every probe feeding it pruned, or pure padding — would
+    # only contribute -inf scores, so its MXU/VPU pass and k-way merge are
+    # skipped and the accumulator carries over.
+    @pl.when(blk_live_s[bi * n_blocks + cj] > 0)
     def _():
-        start_block(0, slot)  # warm up the first live block
-
-    # Double buffering: block cj+1 goes in flight before we block on cj (dead
-    # blocks issue nothing). The nslot buffer's last DMA — from the previous
-    # live block on that slot — was waited at that block's own step, so the
-    # overwrite is safe.
-    nxt = jnp.minimum(cj + 1, n_blocks - 1)  # clamp: SMEM read is unguarded
-    @pl.when((cj + 1 < n_blocks) & (blk_live_s[bi, nxt] > 0))
-    def _():
-        start_block(cj + 1, nslot)
-
-    @pl.when(live)
-    def _():
-        def wait_body(i, _):
-            row_dma(cj, slot, i).wait()
-            return 0
-
-        jax.lax.fori_loop(0, block_c, wait_body, 0)
-
-        # Score the resident block: storage-dtype MXU inputs — int8×int8
-        # with int32 accumulation on a quantized bank (the per-candidate
-        # scale is folded in after, one f32 multiply per score), fp32
-        # accumulation otherwise.
-        rows = cand[slot]  # (block_c, d_store)
-        if code_dtype == "int4":
-            rows = _unpack_int4_vmem(rows)  # (block_c, d) deinterleaved
-        q = q_ref[...].astype(rows.dtype)  # (1, d)
-        scores = jax.lax.dot_general(
-            q,
-            rows,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.int32 if quantized else jnp.float32,
-        )  # (1, block_c)
-        if quantized:
-            scores = scores.astype(jnp.float32) * scl_ref[...]
-        oid = oid_ref[...]  # (1, block_c)
+        oid = oid_ref[...]
+        scores = score(cand_ref[...], q_ref[...], *(e[...] for e in extras))
         scores = jnp.where(oid >= 0, scores, NEG_INF)
+        sc, ids = _merge_topk(sc_out[...], ids_out[...], scores, oid, k)
+        sc_out[...] = sc
+        ids_out[...] = ids
 
-        # Streaming top-k merge with duplicate suppression: select the max k
-        # times from [accumulator ++ block]; each selection kills every copy
-        # of the selected id (duplicates carry equal scores, so this is
-        # exact). Score ties between distinct ids break toward the smallest
-        # id — the order ``dedup_topk`` produces (stable top_k over id-sorted
-        # candidates).
-        csc0 = jnp.concatenate([acc_sc[...], scores], axis=1)  # (1, L)
-        cid = jnp.concatenate([acc_ids[...], oid], axis=1)  # (1, L)
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
 
-        def sel_body(i, carry):
-            csc, asc, aid = carry
-            m = jnp.max(csc)
-            tie = csc == m  # all copies of the winner are ties (equal scores)
-            sid = jnp.min(jnp.where(tie, cid, jnp.int32(2**31 - 1)))
-            sid = jnp.where(
-                jnp.isneginf(m), jnp.int32(-1), sid
-            ).astype(jnp.int32)
-            kill = (cid == sid) & (sid >= 0)
-            csc = jnp.where(kill, NEG_INF, csc)
-            asc = jnp.where(iota_k == i, m, asc)
-            aid = jnp.where(iota_k == i, sid, aid)
-            return csc, asc, aid
+def _score_float(rows, q):
+    """Storage-dtype MXU pass, f32 accumulation. An f32 table is scored at
+    HIGHEST precision so the rescore is exact f32 math on the TPU too (its
+    default f32 matmul is a single bf16 pass); bf16 tables are exact bf16
+    products either way."""
+    precision = (
+        jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
+    )
+    return jax.lax.dot_general(
+        q.astype(rows.dtype),
+        rows,
+        (((1,), (1,)), ((), ())),
+        precision=precision,
+        preferred_element_type=jnp.float32,
+    )  # (1, block_c)
 
-        init = (
-            csc0,
-            jnp.full((1, k), NEG_INF, jnp.float32),
-            jnp.full((1, k), -1, jnp.int32),
-        )
-        _, asc, aid = jax.lax.fori_loop(0, k, sel_body, init)
-        acc_sc[...] = asc
-        acc_ids[...] = aid
 
-    @pl.when(cj == n_blocks - 1)
-    def _():
-        ids_out[...] = acc_ids[...]
-        sc_out[...] = acc_sc[...]
+def _score_codes(rows, q, comb, *, code_dtype: str):
+    """int8×int8→int32 MXU pass over int8 (or in-VMEM unpacked int4) codes;
+    the pre-gathered combined row×query scale is one f32 multiply after."""
+    if code_dtype == "int4":
+        rows = _unpack_int4_vmem(rows)  # (block_c, d) deinterleaved
+    acc = jax.lax.dot_general(
+        q,
+        rows,
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32,
+    )  # (1, block_c)
+    return acc.astype(jnp.float32) * comb
+
+
+def _score_sketch(rows, q):
+    """Negated Hamming distance: XOR + popcount on the VPU. The per-row sum
+    over words is a ones-vector contraction so the result lands as a
+    (1, block_c) row; popcounts <= 32 are exact in bf16 and their sum
+    (<= d < 2^24) is exact in the f32 accumulator."""
+    # Through int32: the TPU has no uint32 -> float conversion.
+    pc = jax.lax.population_count(jnp.bitwise_xor(rows, q)).astype(jnp.int32)
+    ones = jnp.ones((1, rows.shape[1]), jnp.bfloat16)
+    ham = jax.lax.dot_general(
+        ones,
+        pc.astype(jnp.float32).astype(jnp.bfloat16),
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # (1, block_c)
+    return -ham
+
+
+def _gather_topk(
+    table, row_ids, out_ids, q, extra, *, k, block_c, score, name, interpret
+):
+    """Shared wrapper of the per-query kernels.
+
+    Pads the candidate axis to whole blocks, gathers the candidate rows as a
+    ``(B, n_blocks, block_c, width)`` block (an XLA gather: the TPU's tiled
+    HBM layouts pack 2-4 narrow rows per 32-bit word and pad rows below 128
+    lanes, so single-row DMAs from inside the kernel do not lower), counts
+    live candidates per block for the skip path, and lays every per-row
+    input out so each block's last two dims equal the array's (the (8, 128)
+    tiling rule). Returns ``(B, k)`` ids and scores.
+    """
+    b, c = row_ids.shape
+    n = table.shape[0]
+    bc = _clamp_block_c(block_c, c)
+    pad = (-c) % bc
+    if pad:
+        row_ids = jnp.pad(row_ids, ((0, 0), (0, pad)))
+        out_ids = jnp.pad(out_ids, ((0, 0), (0, pad)), constant_values=-1)
+        if extra is not None:
+            extra = jnp.pad(extra, ((0, 0), (0, pad)))
+    n_blocks = (c + pad) // bc
+    safe_rows = jnp.clip(row_ids, 0, n - 1).reshape(b, n_blocks, bc)
+    cand = table[safe_rows]  # (B, n_blocks, bc, width)
+    out_ids = out_ids.astype(jnp.int32)
+    blk_live = jnp.sum(
+        (out_ids >= 0).reshape(b, n_blocks, bc), axis=-1, dtype=jnp.int32
+    ).reshape(-1)
+
+    def per_block(x):
+        return x.reshape(b, n_blocks, 1, bc)
+
+    idx_q = lambda bi, cj, live: (bi, 0, 0)
+    idx_blk = lambda bi, cj, live: (bi, cj, 0, 0)
+    blk_spec = pl.BlockSpec((None, None, 1, bc), idx_blk)
+    in_specs = [pl.BlockSpec((None, 1, q.shape[-1]), idx_q), blk_spec]
+    inputs = [q[:, None, :], per_block(out_ids)]
+    if extra is not None:
+        in_specs.append(blk_spec)
+        inputs.append(per_block(extra))
+    in_specs.append(pl.BlockSpec((None, None, bc, cand.shape[-1]), idx_blk))
+    inputs.append(cand)
+
+    out_spec = pl.BlockSpec((None, 1, k), idx_q)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, n_blocks),
+        in_specs=in_specs,
+        out_specs=[out_spec, out_spec],
+    )
+    ids, scores = pl.pallas_call(
+        functools.partial(
+            _topk_kernel,
+            k=k,
+            n_blocks=n_blocks,
+            n_extra=0 if extra is None else 1,
+            score=score,
+        ),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
+        ],
+        name=name,
+        interpret=interpret,
+    )(blk_live, *inputs)
+    return ids[:, 0], scores[:, 0]
 
 
 @functools.partial(
@@ -242,7 +323,7 @@ def fused_verify(
 
     With ``code_dtype="int4"`` (requires ``scales``), ``embs`` is a *packed*
     int4 code table of width ``d//2`` (two nibbles per byte —
-    ``quant.pack_int4``): row DMAs move half the bytes again (0.5 B/elem),
+    ``quant.pack_int4``): candidate tiles move half the bytes again (0.5 B/elem),
     the block is unpacked to int8 in VMEM, and the query codes are
     deinterleaved outside the kernel so the same int8×int8→int32 MXU pass
     applies unchanged.
@@ -256,202 +337,40 @@ def fused_verify(
     """
     from .quant import deinterleave_query_codes, quantize_rows
 
-    interpret = resolve_interpret(interpret)
     if out_ids is None:
         out_ids = row_ids
-    quantized = scales is not None
     if code_dtype not in ("int8", "int4"):
         raise ValueError(f"code_dtype must be 'int8' or 'int4', got {code_dtype!r}")
-    if code_dtype == "int4" and not quantized:
-        raise ValueError("code_dtype='int4' requires scales (a packed code table)")
-    b, c = row_ids.shape
-    n, d = embs.shape  # d is the STORED width (d_model//2 for packed int4)
-    d_q = d * 2 if code_dtype == "int4" else d  # query/logical width
-    bc = _clamp_block_c(block_c, c)
-    pad = (-c) % bc
-    if pad:
-        row_ids = jnp.pad(row_ids, ((0, 0), (0, pad)))
-        out_ids = jnp.pad(out_ids, ((0, 0), (0, pad)), constant_values=-1)
-    n_blocks = (c + pad) // bc
-    safe_rows = jnp.clip(row_ids, 0, n - 1).astype(jnp.int32)
-    out_ids = out_ids.astype(jnp.int32)
-    # Per-(row, block) valid-candidate counts for the block-skip path.
-    blk_live = jnp.sum(
-        (out_ids >= 0).reshape(b, n_blocks, bc), axis=-1, dtype=jnp.int32
-    )
-
-    idx_q = lambda bi, cj, ids, live: (bi, 0)
-    idx_blk = lambda bi, cj, ids, live: (bi, cj)
-    in_specs = [
-        pl.BlockSpec((1, d_q), idx_q),
-        pl.BlockSpec((1, bc), idx_blk),
-    ]
-    inputs = [queries, out_ids]
-    if quantized:
-        q_codes, q_scales = quantize_rows(queries)
+    if scales is None:
         if code_dtype == "int4":
-            # Match the kernel's concat([lo, hi]) unpack order (see
-            # _unpack_int4_vmem) — queries stay int8-quantized, only their
-            # element order changes, so the int32 dot is still exact.
-            q_codes = deinterleave_query_codes(q_codes)
-        inputs[0] = q_codes
-        # Combined per-candidate scale, gathered outside the kernel: O(B·C)
-        # f32 against the O(B·C·d) row bytes the int8 path saves. Invalid
-        # slots gather row 0's scale — harmless, their score is masked -inf.
-        comb = scales[safe_rows].astype(jnp.float32) * q_scales[:, None]
-        in_specs.append(pl.BlockSpec((1, bc), idx_blk))
-        inputs.append(comb)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))  # embs stay in HBM
-    inputs.append(embs)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_blocks),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, k), idx_q),
-            pl.BlockSpec((1, k), idx_q),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bc, d), embs.dtype),  # double-buffered rows
-            pltpu.VMEM((1, k), jnp.int32),  # top-k id accumulator
-            pltpu.VMEM((1, k), jnp.float32),  # top-k score accumulator
-            pltpu.SemaphoreType.DMA((2,)),  # one shared sem per buffer slot
-        ],
+            raise ValueError("code_dtype='int4' requires scales (a packed code table)")
+        return _gather_topk(
+            embs, row_ids, out_ids, queries, None, k=k, block_c=block_c,
+            score=_score_float, name="fused_verify_float",
+            interpret=resolve_interpret(interpret),
+        )
+    q_codes, q_scales = quantize_rows(queries)
+    if code_dtype == "int4":
+        # Match the kernel's concat([lo, hi]) unpack order (see
+        # _unpack_int4_vmem) — queries stay int8-quantized, only their
+        # element order changes, so the int32 dot is still exact.
+        q_codes = deinterleave_query_codes(q_codes)
+    # Combined per-candidate scale, gathered outside the kernel: O(B·C) f32
+    # against the O(B·C·d) row bytes the code pass saves. Invalid slots
+    # gather row 0's scale — harmless, their score is masked -inf.
+    safe = jnp.clip(row_ids, 0, embs.shape[0] - 1)
+    comb = scales[safe].astype(jnp.float32) * q_scales[:, None]
+    return _gather_topk(
+        embs, row_ids, out_ids, q_codes, comb, k=k, block_c=block_c,
+        score=functools.partial(_score_codes, code_dtype=code_dtype),
+        name=f"fused_verify_{code_dtype}",
+        interpret=resolve_interpret(interpret),
     )
-    ids, scores = pl.pallas_call(
-        functools.partial(
-            _fused_verify_kernel,
-            block_c=bc,
-            k=k,
-            n_blocks=n_blocks,
-            quantized=quantized,
-            code_dtype=code_dtype,
-        ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(safe_rows, blk_live, *inputs)
-    return ids, scores
 
 
 # ---------------------------------------------------------------------------
 # Binary-sketch pre-filter (DESIGN.md §Binary sketch tier)
 # ---------------------------------------------------------------------------
-
-
-def _sketch_filter_kernel(
-    # scalar prefetch
-    row_ids_s,
-    blk_live_s,
-    # inputs
-    q_ref,  # (1, w) uint32 — the query's packed sign sketch
-    oid_ref,  # (1, block_c) candidate ids (-1 = padding/pruned)
-    sk_hbm,  # (N, w) uint32 sketch table, stays in HBM
-    # outputs
-    ids_out,
-    sc_out,
-    # scratch
-    cand,
-    acc_ids,
-    acc_sc,
-    sem,
-    *,
-    block_c: int,
-    k: int,
-    n_blocks: int,
-):
-    """1-bit Hamming first pass: same grid, DMA steering, block-skip, and
-    streaming top-k merge as ``_fused_verify_kernel``, but the score is the
-    negated XOR+popcount Hamming distance against the query sketch — 1/8 of
-    the int8 row bytes per candidate, no MXU pass at all (the VPU popcount
-    replaces the dot product)."""
-    bi = pl.program_id(0)
-    cj = pl.program_id(1)
-    slot = jax.lax.rem(cj, 2)
-    nslot = jax.lax.rem(cj + 1, 2)
-    live = blk_live_s[bi, cj] > 0
-
-    def row_dma(blk, s, i):
-        row = row_ids_s[bi, blk * block_c + i]
-        return pltpu.make_async_copy(sk_hbm.at[row], cand.at[s, i], sem.at[s])
-
-    def start_block(blk, s):
-        def body(i, _):
-            row_dma(blk, s, i).start()
-            return 0
-
-        jax.lax.fori_loop(0, block_c, body, 0)
-
-    @pl.when(cj == 0)
-    def _():
-        acc_sc[...] = jnp.full_like(acc_sc, NEG_INF)
-        acc_ids[...] = jnp.full_like(acc_ids, -1)
-
-    @pl.when((cj == 0) & live)
-    def _():
-        start_block(0, slot)
-
-    nxt = jnp.minimum(cj + 1, n_blocks - 1)
-    @pl.when((cj + 1 < n_blocks) & (blk_live_s[bi, nxt] > 0))
-    def _():
-        start_block(cj + 1, nslot)
-
-    @pl.when(live)
-    def _():
-        def wait_body(i, _):
-            row_dma(cj, slot, i).wait()
-            return 0
-
-        jax.lax.fori_loop(0, block_c, wait_body, 0)
-
-        rows = cand[slot]  # (block_c, w) uint32
-        x = jnp.bitwise_xor(rows, q_ref[...])  # broadcast (block_c, w)
-        ham = jnp.sum(
-            jax.lax.population_count(x).astype(jnp.int32),
-            axis=-1,
-            keepdims=True,
-        )  # (block_c, 1)
-        # Negated Hamming as f32 is exact (<= d < 2^24), so the identical
-        # sel_body merge — and its smallest-id tie-break — applies unchanged.
-        scores = -ham.astype(jnp.float32).T  # (1, block_c)
-        oid = oid_ref[...]
-        scores = jnp.where(oid >= 0, scores, NEG_INF)
-
-        csc0 = jnp.concatenate([acc_sc[...], scores], axis=1)
-        cid = jnp.concatenate([acc_ids[...], oid], axis=1)
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-
-        def sel_body(i, carry):
-            csc, asc, aid = carry
-            m = jnp.max(csc)
-            tie = csc == m
-            sid = jnp.min(jnp.where(tie, cid, jnp.int32(2**31 - 1)))
-            sid = jnp.where(
-                jnp.isneginf(m), jnp.int32(-1), sid
-            ).astype(jnp.int32)
-            kill = (cid == sid) & (sid >= 0)
-            csc = jnp.where(kill, NEG_INF, csc)
-            asc = jnp.where(iota_k == i, m, asc)
-            aid = jnp.where(iota_k == i, sid, aid)
-            return csc, asc, aid
-
-        init = (
-            csc0,
-            jnp.full((1, k), NEG_INF, jnp.float32),
-            jnp.full((1, k), -1, jnp.int32),
-        )
-        _, asc, aid = jax.lax.fori_loop(0, k, sel_body, init)
-        acc_sc[...] = asc
-        acc_ids[...] = aid
-
-    @pl.when(cj == n_blocks - 1)
-    def _():
-        ids_out[...] = acc_ids[...]
-        sc_out[...] = acc_sc[...]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_c", "interpret"))
@@ -472,65 +391,21 @@ def sketch_prefilter(
     §Binary sketch tier): queries are sign-sketched outside the kernel
     (``quant.sketch_rows`` — the same packer that built the table), candidate
     sketch rows stream HBM->VMEM at 1/8 the int8 code bytes, and scoring is
-    XOR + popcount on the VPU. Dedup/top-k semantics — including padding
+    XOR + popcount on the VPU. It runs the same gather/block-skip/merge
+    kernel as ``fused_verify`` with a Hamming score, so padding
     (``out_ids < 0`` -> (-1, -inf)), dead-block skipping, and the
-    smallest-id tie-break — are identical to ``fused_verify``, so the
-    surviving top-``k`` rows feed the int4/int8 pass as an ordinary
-    ``row_ids``/``out_ids`` pair.
+    smallest-id tie-break are identical and the surviving top-``k`` rows
+    feed the int4/int8 pass as an ordinary ``row_ids``/``out_ids`` pair.
     """
     from .quant import sketch_rows
 
-    interpret = resolve_interpret(interpret)
     if out_ids is None:
         out_ids = row_ids
-    b, c = row_ids.shape
-    n, w = sketches.shape
-    q_sk = sketch_rows(queries)  # (B, w) uint32
-    bc = _clamp_block_c(block_c, c)
-    pad = (-c) % bc
-    if pad:
-        row_ids = jnp.pad(row_ids, ((0, 0), (0, pad)))
-        out_ids = jnp.pad(out_ids, ((0, 0), (0, pad)), constant_values=-1)
-    n_blocks = (c + pad) // bc
-    safe_rows = jnp.clip(row_ids, 0, n - 1).astype(jnp.int32)
-    out_ids = out_ids.astype(jnp.int32)
-    blk_live = jnp.sum(
-        (out_ids >= 0).reshape(b, n_blocks, bc), axis=-1, dtype=jnp.int32
+    return _gather_topk(
+        sketches, row_ids, out_ids, sketch_rows(queries), None, k=k,
+        block_c=block_c, score=_score_sketch, name="sketch_prefilter",
+        interpret=resolve_interpret(interpret),
     )
-
-    idx_q = lambda bi, cj, ids, live: (bi, 0)
-    idx_blk = lambda bi, cj, ids, live: (bi, cj)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1, w), idx_q),
-            pl.BlockSpec((1, bc), idx_blk),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # sketches stay in HBM
-        ],
-        out_specs=[
-            pl.BlockSpec((1, k), idx_q),
-            pl.BlockSpec((1, k), idx_q),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bc, w), jnp.uint32),  # double-buffered sketches
-            pltpu.VMEM((1, k), jnp.int32),
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-    )
-    ids, scores = pl.pallas_call(
-        functools.partial(
-            _sketch_filter_kernel, block_c=bc, k=k, n_blocks=n_blocks
-        ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(safe_rows, blk_live, q_sk, out_ids, sketches)
-    return ids, scores
 
 
 # ---------------------------------------------------------------------------
@@ -540,22 +415,18 @@ def sketch_prefilter(
 
 def _fused_verify_grouped_kernel(
     # scalar prefetch
-    sched_cids_s,
-    blk_live_s,
+    sched_cids_s,  # (S,) cluster of each step
+    blk_live_s,  # (S * n_blocks,) live candidates per (step, block)
     # blocked inputs
-    emb_ref,  # (1, bc, d_store) — steered to cluster sched_cids[s], block j
+    emb_ref,  # (bc, d_store) — steered to cluster sched_cids[s], block j
     scl_ref,  # (1, bc) per-row scales of the same block
-    q_ref,  # (1, block_q, d_q) query-code tile of step s
-    qscl_ref,  # (1, block_q) query scales of step s
-    oid_ref,  # (1, block_q, bc) per-(slot, row) candidate ids (-1 = not cand)
-    # outputs
+    q_ref,  # (block_q, d_q) query-code tile of step s
+    qscl_ref,  # (block_q, 1) query scales of step s
+    oid_ref,  # (block_q, bc) per-(slot, row) candidate ids (-1 = not cand)
+    # outputs: (block_q, kp), resident across j — the running accumulator
     ids_out,
     sc_out,
-    # scratch
-    acc_ids,
-    acc_sc,
     *,
-    block_q: int,
     kp: int,
     n_blocks: int,
     code_dtype: str,
@@ -565,24 +436,23 @@ def _fused_verify_grouped_kernel(
 
     @pl.when(cj == 0)
     def _():
-        acc_sc[...] = jnp.full_like(acc_sc, NEG_INF)
-        acc_ids[...] = jnp.full_like(acc_ids, -1)
+        sc_out[...] = jnp.full_like(sc_out, NEG_INF)
+        ids_out[...] = jnp.full_like(ids_out, -1)
 
     # Dead step-blocks (no candidate of any query in this tile touches these
     # rows — e.g. pruned probes or schedule padding) skip the MXU pass; the
     # block's rows still stream through the automatic pipeline, but scoring
     # and the k' merge are the dominant per-block cost at block_q > 1.
-    @pl.when(blk_live_s[s, cj] > 0)
+    @pl.when(blk_live_s[s * n_blocks + cj] > 0)
     def _():
-        rows = emb_ref[0]  # (bc, d_store)
+        rows = emb_ref[...]  # (bc, d_store)
         if code_dtype == "int4":
             rows = _unpack_int4_vmem(rows)  # (bc, d) deinterleaved
-        qt = q_ref[0].astype(rows.dtype)  # (block_q, d)
         # ONE MXU pass scores the whole query tile against the resident
         # cluster block — this is the DMA-sharing win: per-query scheduling
         # would re-stream these rows once per query in the tile.
         int_scores = jax.lax.dot_general(
-            qt,
+            q_ref[...],
             rows,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32,
@@ -590,49 +460,16 @@ def _fused_verify_grouped_kernel(
         # Combined scale as an in-kernel outer product (f32 multiply is
         # commutative, so this is bit-identical to the per-query path's
         # pre-gathered row×query scale).
-        comb = qscl_ref[0][:, None] * scl_ref[0][None, :]
+        comb = qscl_ref[...] * scl_ref[...]
         scores = int_scores.astype(jnp.float32) * comb
-        oid = oid_ref[0]  # (block_q, bc)
+        oid = oid_ref[...]  # (block_q, bc)
         scores = jnp.where(oid >= 0, scores, NEG_INF)
-
         # Row-vectorized streaming top-k' merge: same selection order and
         # smallest-id tie-break as the per-query kernel / dedup_topk, applied
         # to all block_q slots at once.
-        csc0 = jnp.concatenate([acc_sc[...], scores], axis=1)  # (bq, kp+bc)
-        cid0 = jnp.concatenate([acc_ids[...], oid], axis=1)
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (block_q, kp), 1)
-
-        def sel_body(i, carry):
-            csc, asc, aid = carry
-            m = jnp.max(csc, axis=1, keepdims=True)  # (bq, 1)
-            tie = csc == m
-            sid = jnp.min(
-                jnp.where(tie, cid0, jnp.int32(2**31 - 1)),
-                axis=1,
-                keepdims=True,
-            )
-            sid = jnp.where(jnp.isneginf(m), jnp.int32(-1), sid).astype(
-                jnp.int32
-            )
-            kill = (cid0 == sid) & (sid >= 0)
-            csc = jnp.where(kill, NEG_INF, csc)
-            asc = jnp.where(iota_k == i, m, asc)
-            aid = jnp.where(iota_k == i, sid, aid)
-            return csc, asc, aid
-
-        init = (
-            csc0,
-            jnp.full((block_q, kp), NEG_INF, jnp.float32),
-            jnp.full((block_q, kp), -1, jnp.int32),
-        )
-        _, asc, aid = jax.lax.fori_loop(0, kp, sel_body, init)
-        acc_sc[...] = asc
-        acc_ids[...] = aid
-
-    @pl.when(cj == n_blocks - 1)
-    def _():
-        ids_out[0] = acc_ids[...]
-        sc_out[0] = acc_sc[...]
+        sc, ids = _merge_topk(sc_out[...], ids_out[...], scores, oid, kp)
+        sc_out[...] = sc
+        ids_out[...] = ids
 
 
 def _grouped_block_c(block_c: int, lp: int) -> int:
@@ -733,37 +570,38 @@ def fused_verify_grouped(
         (step_slot_ids >= 0).reshape(s_steps, block_q, n_blocks, bc),
         axis=(1, 3),
         dtype=jnp.int32,
-    )
+    ).reshape(-1)
+    # Layouts whose blocks' last two dims equal the array's (the (8, 128)
+    # tiling rule holds for any bc): scales as (c, n_blocks, 1, bc), query
+    # scales as a (block_q, 1) column, candidate ids block-major.
+    row_scales = row_scales.astype(jnp.float32).reshape(c, n_blocks, 1, bc)
+    qscl_tiles = qscl_tiles[:, :, None]
+    oid_tiles = step_slot_ids.reshape(s_steps, block_q, n_blocks, bc)
+    oid_tiles = oid_tiles.transpose(0, 2, 1, 3)  # (S, n_blocks, bq, bc)
 
     idx_emb = lambda s, j, cids, live: (cids[s], j, 0)
-    idx_scl = lambda s, j, cids, live: (cids[s], j)
+    idx_scl = lambda s, j, cids, live: (cids[s], j, 0, 0)
     idx_step = lambda s, j, cids, live: (s, 0, 0)
-    idx_qscl = lambda s, j, cids, live: (s, 0)
-    idx_oid = lambda s, j, cids, live: (s, 0, j)
+    idx_oid = lambda s, j, cids, live: (s, j, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s_steps, n_blocks),
         in_specs=[
-            pl.BlockSpec((1, bc, d_store), idx_emb),
-            pl.BlockSpec((1, bc), idx_scl),
-            pl.BlockSpec((1, block_q, d_q), idx_step),
-            pl.BlockSpec((1, block_q), idx_qscl),
-            pl.BlockSpec((1, block_q, bc), idx_oid),
+            pl.BlockSpec((None, bc, d_store), idx_emb),
+            pl.BlockSpec((None, None, 1, bc), idx_scl),
+            pl.BlockSpec((None, block_q, d_q), idx_step),
+            pl.BlockSpec((None, block_q, 1), idx_step),
+            pl.BlockSpec((None, None, block_q, bc), idx_oid),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, kp), idx_step),
-            pl.BlockSpec((1, block_q, kp), idx_step),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, kp), jnp.int32),
-            pltpu.VMEM((block_q, kp), jnp.float32),
+            pl.BlockSpec((None, block_q, kp), idx_step),
+            pl.BlockSpec((None, block_q, kp), idx_step),
         ],
     )
     ids, scores = pl.pallas_call(
         functools.partial(
             _fused_verify_grouped_kernel,
-            block_q=block_q,
             kp=kp,
             n_blocks=n_blocks,
             code_dtype=code_dtype,
@@ -773,6 +611,7 @@ def fused_verify_grouped(
             jax.ShapeDtypeStruct((s_steps, block_q, kp), jnp.int32),
             jax.ShapeDtypeStruct((s_steps, block_q, kp), jnp.float32),
         ],
+        name=f"fused_verify_grouped_{code_dtype}",
         interpret=interpret,
-    )(sched_cids, blk_live, embs, row_scales, q_tiles, qscl_tiles, step_slot_ids)
+    )(sched_cids, blk_live, embs, row_scales, q_tiles, qscl_tiles, oid_tiles)
     return ids, scores

@@ -96,6 +96,7 @@ def kmeans_assign(
             jax.ShapeDtypeStruct((x.shape[0], 1), jnp.float32),
             jax.ShapeDtypeStruct((x.shape[0], 1), jnp.int32),
         ],
+        name="kmeans_assign",
         interpret=interpret,
     )(x, centroids)
     return best_i[:n, 0], best_d[:n, 0]

@@ -10,12 +10,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+# f32 oracles run at HIGHEST: the TPU's default f32 matmul is a single bf16
+# pass, which would make the oracle, not the kernel, the inexact side.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def lsh_hash_ref(
     x: jnp.ndarray, proj: jnp.ndarray, n_arrays: int, key_len: int
 ) -> jnp.ndarray:
     """(N, d) x (d, H*M) -> (N, H) packed big-endian uint32 hashkeys."""
-    acc = x.astype(jnp.float32) @ proj.astype(jnp.float32)
+    acc = jnp.dot(
+        x.astype(jnp.float32), proj.astype(jnp.float32), precision=HIGHEST
+    )
     bits = (acc >= 0.0).astype(jnp.uint32)
     bits = bits.reshape(x.shape[0], n_arrays, key_len)
     weights = (jnp.uint32(1) << jnp.arange(key_len - 1, -1, -1, dtype=jnp.uint32))
@@ -30,7 +36,7 @@ def kmeans_assign_ref(
     c = centroids.astype(jnp.float32)
     d2 = (
         jnp.sum(x * x, -1, keepdims=True)
-        - 2.0 * x @ c.T
+        - 2.0 * jnp.dot(x, c.T, precision=HIGHEST)
         + jnp.sum(c * c, -1)[None, :]
     )
     return jnp.argmin(d2, -1).astype(jnp.int32), jnp.min(d2, -1)
@@ -76,17 +82,24 @@ def verify_topk_ref(
 
     if out_ids is None:
         out_ids = row_ids
+    c = row_ids.shape[-1]
     safe = jnp.maximum(row_ids, 0)
     cand = embs[safe]  # (B, C, d) — the materialization being eliminated
     if scales is not None and code_dtype == "int4":
         cand = unpack_int4(cand)
     if scales is None:
+        # Score the same 8-aligned candidate width the kernel's blocks
+        # cover: XLA's CPU dot sums a ragged tail of < 8 columns in another
+        # order, and the f32 parity with the kernel is bit-exact only when
+        # every column takes the same path.
+        pad = (-c) % 8
         scores = jnp.einsum(
             "bcd,bd->bc",
-            cand,
+            jnp.pad(cand, ((0, 0), (0, pad), (0, 0))),
             queries.astype(cand.dtype),
+            precision=HIGHEST,
             preferred_element_type=jnp.float32,
-        )
+        )[:, :c]
     else:
         q_codes, q_scales = quantize_rows(queries)
         int_scores = jnp.einsum(

@@ -23,7 +23,6 @@ import traceback
 
 import jax
 
-from .. import compat
 from ..configs import ARCHS, get_arch
 from .mesh import make_production_mesh
 from .steps import make_bundle
@@ -99,7 +98,7 @@ def run_cell(arch_id: str, shape_name: str, mesh, mesh_name: str) -> dict:
         return rec
     t0 = time.time()
     try:
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             bundle = make_bundle(arch, shape, mesh)
             jf = jax.jit(
                 bundle.fn,

@@ -15,8 +15,6 @@ import math
 import jax
 import numpy as np
 
-from .. import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -29,12 +27,15 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
             "launch/dryrun.py (sets xla_force_host_platform_device_count)"
         )
     # The single-pod mesh uses the first 256 of the dry-run's 512 devices.
-    return compat.mesh_from_devices(np.asarray(devs[:need]).reshape(shape), axes)
+    return jax.sharding.Mesh(np.asarray(devs[:need]).reshape(shape), axes)
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests)."""
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

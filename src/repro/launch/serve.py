@@ -43,6 +43,7 @@ from ..serving import (
 from ..serving import traffic
 from ..serving.engine import EngineStats
 from ..training import checkpoint
+from .compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -204,6 +205,7 @@ def main() -> None:
         "wrong-generation answers (needs --replicas >= 2)",
     )
     args = ap.parse_args()
+    use_compile_cache()
     use_fused = {"auto": None, "on": True, "off": False}[args.use_fused]
     lifecycle = args.save_index or args.load_index or args.update_fraction > 0
     if lifecycle and args.backend != "lider":

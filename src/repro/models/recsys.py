@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from . import layers
 from .sharding import ALL, DP, TP, maybe_shard
 
@@ -40,8 +39,8 @@ def embedding_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     rows — each shard takes its local rows (masked) and the partials are
     psum'd. Otherwise a plain take. Differentiable (scatter-add transpose).
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return table[ids]
     dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     dp_size = 1
@@ -61,11 +60,12 @@ def embedding_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
 
     id_spec = P(dp if dp else None, *([None] * (ids.ndim - 1)))
     out_spec = P(dp if dp else None, *([None] * ids.ndim))
-    return compat.shard_map(
+    return jax.shard_map(
         local_lookup,
         mesh=mesh,
         in_specs=(P("model", None), id_spec),
         out_specs=out_spec,
+        check_vma=False,
     )(table, ids)
 
 

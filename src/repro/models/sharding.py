@@ -11,7 +11,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 
 DP = "dp"  # logical data-parallel axis -> ("pod", "data")
 TP = "tp"  # logical tensor/expert-parallel axis -> ("model",)
@@ -43,8 +42,8 @@ def resolve_spec(spec_entries, mesh_axis_names) -> P:
 
 def maybe_shard(x: jax.Array, *spec_entries) -> jax.Array:
     """with_sharding_constraint under an ambient mesh; identity otherwise."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     return jax.lax.with_sharding_constraint(
         x, resolve_spec(spec_entries, mesh.axis_names)

@@ -1161,11 +1161,12 @@ class RetrievalEngine:
         D2H conversion seconds (excluded from the AQT window), or None when
         the fetch failed and the batch was parked for a backoff retry.
 
-        A host fetch that exhausts all its retries does NOT abort the
-        drain: the batch is answered compressed-only from its provisional
-        top-k' (``degraded=True``) and the rung controller steps down one
-        rung for subsequent batches. Backoff is exponential with
-        deterministic (seeded) jitter so chaos runs replay identically."""
+        Under an active ``fault_plan``, a host fetch that exhausts all its
+        retries does NOT abort the drain: the batch is answered
+        compressed-only from its provisional top-k' (``degraded=True``) and
+        the rung controller steps down one rung for subsequent batches.
+        Backoff is exponential with deterministic (seeded) jitter so chaos
+        runs replay identically. Without a plan the fetch error raises."""
         pol = self.policy
         if not e.blocked:
             # Close the device wait BEFORE the fetch timer: np.asarray(prov)
@@ -1178,6 +1179,12 @@ class RetrievalEngine:
             fetched = self.search_fn.host_fetch(self.params, e.prov.ids)
             self.stats.host_fetch_us += (time.perf_counter() - tf0) * 1e6
         except Exception:
+            # Retry and compressed-only answers are the chaos-tested
+            # recovery path: without an injected-fault plan a fetch error
+            # is a real fault, and it propagates instead of turning into a
+            # degraded answer.
+            if self.fault_plan is None:
+                raise
             e.attempts += 1
             if e.attempts > pol.fetch_retries:
                 self.stats.n_fetch_failures += 1

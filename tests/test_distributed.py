@@ -15,8 +15,7 @@ def _run(body: str) -> str:
         import jax, numpy as np
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro import compat
-        mesh = compat.mesh_from_devices(
+        mesh = jax.sharding.Mesh(
             np.array(jax.devices()).reshape(4, 2), ("data", "model"))
         """
     ) + textwrap.dedent(body)
@@ -254,12 +253,12 @@ def test_sharded_embedding_lookup_equals_take():
         table = jax.random.normal(jax.random.PRNGKey(0), (64, 8))
         ids = jax.random.randint(jax.random.PRNGKey(1), (16, 3), 0, 64)
         plain = table[ids]
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             sharded = jax.jit(embedding_lookup)(table, ids)
         assert np.allclose(np.asarray(plain), np.asarray(sharded), atol=1e-6)
         # gradient path through the shard_map lookup
         g_plain = jax.grad(lambda t: jnp.sum(t[ids] ** 2))(table)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             g_shard = jax.jit(
                 jax.grad(lambda t: jnp.sum(embedding_lookup(t, ids) ** 2))
             )(table)
@@ -287,7 +286,7 @@ def test_lm_train_step_runs_sharded():
                           is_leaf=lambda x: isinstance(x, P))
         sp = jax.tree.map(lambda x, s: jax.device_put(x, s), params, ns)
         sb = jax.tree.map(lambda x: jax.device_put(x, NamedSharding(mesh, P(("data",), None))), batch)
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             got = float(jax.jit(lambda p, b: T.train_loss(p, cfg, b))(sp, sb))
         assert abs(ref - got) < 1e-3, (ref, got)
         print("LM_SHARD_OK")

@@ -5,13 +5,10 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.core import lider
 from repro.core.baselines import flat_search
 from repro.core.utils import recall_at_k
-
-_JAX_VERSION = tuple(int(p) for p in jax.__version__.split(".")[:2])
 
 
 def _setup(corpus):
@@ -37,12 +34,6 @@ def test_bf16_index_recall_close_to_f32(corpus):
     assert float(got) >= float(base) - 0.03  # A1 quality guard
 
 
-@pytest.mark.skipif(
-    _JAX_VERSION < (0, 5),
-    reason="jax<0.5 PRNG/compiler numerics shift this corpus's refine recall "
-    "by ~0.03, past the 0.02 guard band (the refine path itself is exercised "
-    "and parity-checked elsewhere); the guard is meaningful on current jax",
-)
 def test_refine_halves_window_at_small_recall_cost(corpus):
     x, q, gt, params = _setup(corpus)
     wide = recall_at_k(lider.search_lider(params, q, k=10, n_probe=12, r0=8).ids, gt)

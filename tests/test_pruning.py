@@ -169,7 +169,7 @@ def _assert_parity(embs, rows, q, k, block_c, out_ids):
     )
     wi, ws = ref.verify_topk_ref(embs, rows, q, k=k, out_ids=out_ids)
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
-    np.testing.assert_allclose(np.asarray(gs), np.asarray(ws), rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(gs), np.asarray(ws))
 
 
 def test_block_skip_parity_whole_blocks_pruned():

@@ -502,6 +502,20 @@ def test_float_checkpoint_has_no_quantized_leaves(tmp_path, corpus):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("storage_dtype", ["int8", "int4"])
+def test_build_encode_in_chunks_matches_store_rows(storage_dtype):
+    """The build's chunked in-place encode (bank._encode_rows) writes the
+    same codes, scales and sketches as one store_rows call, across chunk
+    boundaries and a ragged last chunk."""
+    from repro.core.bank import _encode_rows
+
+    raw = jax.random.normal(jax.random.PRNGKey(3), (10, 16, 40))
+    got = _encode_rows(raw, storage_dtype, chunk=4)
+    codes, scales, _, sketches = store_rows(raw, storage_dtype)
+    for g, w in zip(got, (codes, scales, sketches)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_store_rows_rejects_unknown_dtype():
     with pytest.raises(ValueError, match="storage_dtype"):
         store_rows(jnp.zeros((2, 4, 8)), "float16")

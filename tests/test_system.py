@@ -73,15 +73,14 @@ def test_every_cell_constructs_a_bundle(arch_id):
     dry-run; this guards the construction path in unit tests)."""
     import numpy as np
 
-    from repro import compat
     from repro.launch.steps import make_bundle
 
-    mesh = compat.mesh_from_devices(
+    mesh = jax.sharding.Mesh(
         np.array(jax.devices()).reshape(1, 1), ("data", "model")
     )
     arch = get_arch(arch_id)
     for shape in arch.shapes:
-        with compat.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             b = make_bundle(arch, shape, mesh)
         assert b.model_flops > 0
         flat_args = jax.tree.leaves(b.args)
