@@ -1,0 +1,6 @@
+"""Device: share of the traced window in which no operation ran on the
+chip (%)."""
+
+
+def read(run):
+    return None if run.trace is None else 100.0 * run.trace.idle_share
