@@ -15,6 +15,15 @@ maintenance and offline build cannot drift.
 ``search_lider`` is the single-device reference; ``core.distributed`` wraps
 the same ``incluster_search`` math in a shard_map with capacity-based
 query->cluster-shard dispatch for the production mesh.
+
+Search stages carry ``jax.named_scope`` names, so every device op (XLA
+fusion or Pallas call) of the served jits names its stage in its op-name
+metadata and a profiler trace can sum device time per stage:
+``lider.route`` (centroid routing + probe pruning), ``lider.candidates``
+(hash, rescale, RMI, window, the ``sorted_pos``/``gids`` gathers),
+``lider.sketch`` (binary-sketch pre-filter), ``lider.code_pass`` (the
+int8/int4 first pass) and ``lider.rescore`` (exact rescore + row->gid map).
+Scopes never nest across stages, and none is a kernel's name.
 """
 from __future__ import annotations
 
@@ -33,6 +42,12 @@ from .bank import ClusterBank
 from .core_model import CoreModelParams, TopK, build_core_model, search_core_model
 from .types import pytree_dataclass
 from .utils import dedup_topk
+
+# The stage scopes (module docstring) live in op metadata, which JAX's
+# persistent compile cache leaves out of its key by default: an executable
+# cached before a scope was added or moved would then profile under stale
+# stage names. Keyed on metadata, it compiles again instead.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -282,6 +297,7 @@ def set_rescore_tier(params: LiderParams, tier: str) -> LiderParams:
     )
 
 
+@jax.named_scope("lider.candidates")
 def _bank_candidates(
     bank: ClusterBank,
     queries: jnp.ndarray,
@@ -377,18 +393,19 @@ def _verify_bank_rows(
     quantized path (vs smallest gid on the float path) — both deterministic.
     """
     c, lp = bank.gids.shape
-    flat_table = bank.embs.reshape(c * lp, -1)
     if not bank.quantized:
-        return verify_topk_op(
-            flat_table,
-            flat_rows,
-            queries,
-            k=k,
-            out_ids=out_gids,
-            block_c=block_c,
-            use_pallas=use_pallas,
-        )
-    out_rows = jnp.where(out_gids >= 0, flat_rows, -1)
+        with jax.named_scope("lider.rescore"):
+            return verify_topk_op(
+                bank.embs.reshape(c * lp, -1),
+                flat_rows,
+                queries,
+                k=k,
+                out_ids=out_gids,
+                block_c=block_c,
+                use_pallas=use_pallas,
+            )
+    with jax.named_scope("lider.candidates"):
+        out_rows = jnp.where(out_gids >= 0, flat_rows, -1)
     kp = min(max(rescore_factor, 1) * k, out_rows.shape[-1])
     if sketch_factor is not None and bank.sketches is not None:
         # Binary-sketch pre-filter (DESIGN.md §Binary sketch tier): 1-bit
@@ -400,39 +417,43 @@ def _verify_bank_rows(
         # valid rows, per-row int scores are unchanged, and dedup collapses
         # the duplicates the sketch pass already collapsed.
         m = min(max(sketch_factor, 1) * kp, out_rows.shape[-1])
-        surv, _ = sketch_topk_op(
-            bank.sketches.reshape(c * lp, -1),
+        with jax.named_scope("lider.sketch"):
+            surv, _ = sketch_topk_op(
+                bank.sketches.reshape(c * lp, -1),
+                flat_rows,
+                queries,
+                k=m,
+                out_ids=out_rows,
+                block_c=block_c,
+                use_pallas=use_pallas,
+            )
+            flat_rows = jnp.maximum(surv, 0)
+        out_rows = surv
+    with jax.named_scope("lider.code_pass"):
+        prov_rows, _ = verify_topk_op(
+            bank.embs.reshape(c * lp, -1),
             flat_rows,
             queries,
-            k=m,
+            k=kp,
             out_ids=out_rows,
+            scales=bank.emb_scales.reshape(-1),
+            block_c=block_c,
+            code_dtype=bank.code_dtype,
+            use_pallas=use_pallas,
+        )
+    with jax.named_scope("lider.rescore"):
+        rows, scores = verify_topk_op(
+            bank.rescore_embs.reshape(c * lp, -1),
+            jnp.maximum(prov_rows, 0),
+            queries,
+            k=k,
+            out_ids=prov_rows,
             block_c=block_c,
             use_pallas=use_pallas,
         )
-        flat_rows = jnp.maximum(surv, 0)
-        out_rows = surv
-    prov_rows, _ = verify_topk_op(
-        flat_table,
-        flat_rows,
-        queries,
-        k=kp,
-        out_ids=out_rows,
-        scales=bank.emb_scales.reshape(-1),
-        block_c=block_c,
-        code_dtype=bank.code_dtype,
-        use_pallas=use_pallas,
-    )
-    rescore_table = bank.rescore_embs.reshape(c * lp, -1)
-    rows, scores = verify_topk_op(
-        rescore_table,
-        jnp.maximum(prov_rows, 0),
-        queries,
-        k=k,
-        out_ids=prov_rows,
-        block_c=block_c,
-        use_pallas=use_pallas,
-    )
-    ids = jnp.where(rows >= 0, bank.gids.reshape(-1)[jnp.maximum(rows, 0)], -1)
+        ids = jnp.where(
+            rows >= 0, bank.gids.reshape(-1)[jnp.maximum(rows, 0)], -1
+        )
     return ids, scores
 
 
@@ -549,18 +570,19 @@ def _search_lider_device(
 ) -> TopK | tuple[TopK, jnp.ndarray]:
     """Single-jit search for device-tier banks (float, or int8 with the
     rescore table resident next to the codes)."""
-    routed = route_queries(
-        params, queries, n_probe=n_probe, r0=r0_centroid, use_fused=use_fused,
-        block_c=block_c,
-    )
-    cids = prune_probes(routed.ids, routed.scores, prune_margin)
+    with jax.named_scope("lider.route"):
+        routed = route_queries(
+            params, queries, n_probe=n_probe, r0=r0_centroid,
+            use_fused=use_fused, block_c=block_c,
+        )
+        cids = prune_probes(routed.ids, routed.scores, prune_margin)
+        pruned = (routed.ids >= 0) & (cids < 0)
     out = incluster_search(
         params, queries, cids, k=k, r0=r0, refine=refine,
         use_fused=use_fused, rescore_factor=rescore_factor, block_c=block_c,
         sketch_factor=sketch_factor,
     )
     if with_stats:
-        pruned = (routed.ids >= 0) & (cids < 0)
         return out, pruned
     return out
 
@@ -604,8 +626,6 @@ def provisional_rows(
         bank, queries, cids, k=k, r0=r0, refine=refine
     )
     c, lp = bank.gids.shape
-    flat_table = bank.embs.reshape(c * lp, -1)
-    scales = bank.emb_scales.reshape(-1)
     if merge:
         fr = flat_emb.reshape(b, -1)
         og = gids.reshape(b, -1)
@@ -615,23 +635,27 @@ def provisional_rows(
         fr = flat_emb.reshape(b * p, -1)
         og = gids.reshape(b * p, -1)
         q = pair_q.reshape(b * p, -1)
-    out_rows = jnp.where(og >= 0, fr, -1)
+    with jax.named_scope("lider.candidates"):
+        out_rows = jnp.where(og >= 0, fr, -1)
     kp = min(max(rescore_factor, 1) * k, fr.shape[-1])
     if sketch_factor is not None and bank.sketches is not None:
         # Sketch pre-filter, same contract as the device-tier funnel
         # (_verify_bank_rows): survivors replace the candidate list so the
         # code pass below streams sketch_factor*k' rows instead of all C.
         m = min(max(sketch_factor, 1) * kp, fr.shape[-1])
-        surv, _ = sketch_topk_op(
-            bank.sketches.reshape(c * lp, -1), fr, q, k=m, out_ids=out_rows,
-            block_c=block_c, use_pallas=use_fused,
-        )
-        fr = jnp.maximum(surv, 0)
+        with jax.named_scope("lider.sketch"):
+            surv, _ = sketch_topk_op(
+                bank.sketches.reshape(c * lp, -1), fr, q, k=m,
+                out_ids=out_rows, block_c=block_c, use_pallas=use_fused,
+            )
+            fr = jnp.maximum(surv, 0)
         out_rows = surv
-    rows, sc = verify_topk_op(
-        flat_table, fr, q, k=kp, out_ids=out_rows, scales=scales,
-        block_c=block_c, code_dtype=bank.code_dtype, use_pallas=use_fused,
-    )
+    with jax.named_scope("lider.code_pass"):
+        rows, sc = verify_topk_op(
+            bank.embs.reshape(c * lp, -1), fr, q, k=kp, out_ids=out_rows,
+            scales=bank.emb_scales.reshape(-1), block_c=block_c,
+            code_dtype=bank.code_dtype, use_pallas=use_fused,
+        )
     if not merge:
         return TopK(ids=rows.reshape(b, p, kp), scores=sc.reshape(b, p, kp))
     return TopK(ids=rows, scores=sc)
@@ -694,17 +718,18 @@ def host_first_pass(
     the serving engine). The provisional scores ride along so a degraded
     engine can answer compressed-only (:func:`compressed_only_topk`) when
     the host fetch is unavailable."""
-    routed = route_queries(
-        params, queries, n_probe=n_probe, r0=r0_centroid, use_fused=use_fused,
-        block_c=block_c,
-    )
-    cids = prune_probes(routed.ids, routed.scores, prune_margin)
+    with jax.named_scope("lider.route"):
+        routed = route_queries(
+            params, queries, n_probe=n_probe, r0=r0_centroid,
+            use_fused=use_fused, block_c=block_c,
+        )
+        cids = prune_probes(routed.ids, routed.scores, prune_margin)
+        pruned = (routed.ids >= 0) & (cids < 0)
     prov = provisional_rows(
         params, queries, cids, k=k, r0=r0, refine=refine, use_fused=use_fused,
         rescore_factor=rescore_factor, block_c=block_c,
         sketch_factor=sketch_factor,
     )
-    pruned = (routed.ids >= 0) & (cids < 0)
     return prov, pruned
 
 
@@ -718,6 +743,7 @@ def host_fetch(params: LiderParams, prov_rows) -> np.ndarray:
 
 
 @partial(jax.jit, static_argnames=("k", "use_fused", "block_c"))
+@jax.named_scope("lider.rescore")
 def host_rescore(
     gids: jnp.ndarray,
     fetched: jnp.ndarray,

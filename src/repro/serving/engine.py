@@ -42,6 +42,7 @@ from ..core.baselines import (
 )
 from ..core.core_model import TopK
 from .scheduler import DEFAULT_TENANT, Request, Scheduler, SchedulerConfig
+from .spans import Span
 
 
 @dataclasses.dataclass
@@ -97,9 +98,17 @@ class EngineStats:
     # per-batch ratios in a bounded deque, same policy as above.
     n_sched_pairs: int = 0
     n_sched_steps: int = 0
-    sharing_trace: collections.deque = dataclasses.field(
-        default_factory=lambda: collections.deque(maxlen=256)
-    )
+    # Per-batch spans (serving/spans.py): host-to-device transfers (query
+    # batches, host-tier fetched rows) and the answers' device-to-host
+    # conversion. Per request, summed at the batch boundary: queue wait
+    # (submit to its batch's dispatch) and service (dispatch to answer
+    # recorded); for a device-answered request the two sum to latency_s.
+    h2d_us: float = 0.0
+    h2d_bytes: int = 0
+    d2h_us: float = 0.0
+    n_d2h: int = 0
+    queue_wait_us: float = 0.0
+    service_us: float = 0.0
 
     @property
     def aqt(self) -> float:
@@ -216,9 +225,11 @@ EVICTED = _EvictedType()
 class _PendingBatch:
     """One stage1-dispatched batch in the host-tier pipeline. ``rung``/
     ``bs`` are captured at dispatch (the live rung may step before the
-    batch finishes); ``retry_at`` is the earliest wall time a failed fetch
-    may be retried (None = ready now); ``overlap_armed`` is set when a
-    later batch's stage 1 was dispatched under this batch's fetch."""
+    batch finishes); ``seq`` is its dispatch sequence number (the ``batch``
+    of its spans) and ``t_disp`` its dispatch time; ``retry_at`` is the
+    earliest wall time a failed fetch may be retried (None = ready now);
+    ``overlap_armed`` is set when a later batch's stage 1 was dispatched
+    under this batch's fetch."""
 
     chunk: list
     bs: int
@@ -226,6 +237,8 @@ class _PendingBatch:
     prov: object
     pruned: object
     rung: int
+    seq: int
+    t_disp: float
     attempts: int = 0
     retry_at: Optional[float] = None
     overlap_armed: bool = False
@@ -568,6 +581,7 @@ class RetrievalEngine:
         )
         self.stats = EngineStats()
         self._next_id = 0
+        self._seq = 0  # batches dispatched: the next batch's span id
         # Preallocated padded batch buffer: drain fills it in place instead
         # of allocating (batch, dim) floats per batch.
         self._batch_buf = np.zeros((batch_size, dim), np.float32)
@@ -835,7 +849,30 @@ class RetrievalEngine:
             ),
         )
 
-    def _device_batch(self, chunk: list[Request], bs: int) -> jnp.ndarray:
+    def _span(self, name: str, seq: int, counter: str | None = None) -> Span:
+        return Span(name, batch=seq, stats=self.stats, counter=counter)
+
+    def _next_batch(self, chunk: list[Request]) -> tuple[int, int, float]:
+        """``(bs, seq, t_disp)`` of a batch leaving the queue: the smallest
+        pre-warmed batch size that holds ``chunk`` (one compiled trace per
+        ladder size — dispatching ``len(chunk)`` directly would re-trace
+        per distinct depth), its dispatch sequence number, its dispatch
+        time."""
+        bs = next(
+            (b for b in self.scheduler.ladder if b >= len(chunk)),
+            self.scheduler.ladder[-1],
+        )
+        seq = self._seq
+        self._seq += 1
+        return bs, seq, time.perf_counter()
+
+    def _take_next(self) -> list[Request]:
+        with self._span("engine.take_batch", self._seq):
+            return self._take_batch(self.scheduler.pick_batch_size())
+
+    def _device_batch(
+        self, chunk: list[Request], bs: int, seq: int
+    ) -> jnp.ndarray:
         """Fill the padded (bs, dim) device batch from ``chunk``.
 
         The device array must be a COPY of the preallocated buffer, never an
@@ -848,7 +885,9 @@ class RetrievalEngine:
             q[i] = req.query
         if len(chunk) < bs:  # zero stale rows from the last batch
             q[len(chunk):] = 0.0
-        return jnp.array(q)  # jnp.array copies; asarray may alias
+        self.stats.h2d_bytes += q.nbytes
+        with self._span("engine.h2d", seq, "h2d_us"):
+            return jnp.array(q)  # jnp.array copies; asarray may alias
 
     def _put_result(self, rid: int, value) -> None:
         """Insert one answer, enforcing the results-map bound."""
@@ -861,21 +900,35 @@ class RetrievalEngine:
                 self._evicted.popitem(last=False)
 
     def _record_batch(
-        self, chunk, n, out, pruned, *, bs=None, rung=None, degraded=False,
-    ) -> None:
-        """Account one completed batch and route its answers (outside the
-        AQT window — this includes the result D2H conversion).
+        self, chunk, out, pruned, *, bs, seq, t_disp, rung=None,
+        degraded=False,
+    ) -> float:
+        """Account one completed batch and route its answers. Returns its
+        seconds (the result D2H conversion included), which the AQT window
+        leaves out.
 
         ``bs``/``rung`` are the batch size and ladder rung the batch was
         *dispatched* with — under the pipelined drain the controller may
         have stepped the live rung between dispatch and completion, and the
         recorded rung must match the operating point that actually computed
-        the answer."""
-        bs = self.batch_size if bs is None else bs
-        rung = self.rung if rung is None else rung
-        faults.fire(faults.D2H)  # "delay" here models a slow __array__
-        ids = np.asarray(out.ids)
-        scores = np.asarray(out.scores)
+        the answer. ``seq``/``t_disp`` are its dispatch sequence number and
+        time."""
+        with self._span("engine.d2h", seq, "d2h_us") as d2h:
+            faults.fire(faults.D2H)  # "delay" here models a slow __array__
+            ids = np.asarray(out.ids)
+            scores = np.asarray(out.scores)
+        self.stats.n_d2h += 1
+        with self._span("engine.record", seq) as rec:
+            self._route_answers(
+                chunk, ids, scores, pruned, bs=bs, t_disp=t_disp,
+                rung=self.rung if rung is None else rung, degraded=degraded,
+            )
+        return d2h.s + rec.s
+
+    def _route_answers(
+        self, chunk, ids, scores, pruned, *, bs, t_disp, rung, degraded,
+    ) -> None:
+        n = len(chunk)
         self.stats.n_queries += n
         self.stats.n_batches += 1
         self.stats.n_padded += bs - n
@@ -894,8 +947,10 @@ class RetrievalEngine:
         now = time.perf_counter()
         deadline = self.policy.deadline_s
         cache = self.scheduler.cache
+        queue_wait = 0.0
         for i, req in enumerate(chunk):
             latency = now - req.t_submit
+            queue_wait += t_disp - req.t_submit
             self.stats.recent_latency_s.append(latency)
             if deadline is not None and latency > deadline:
                 self.stats.n_deadline_misses += 1
@@ -917,6 +972,8 @@ class RetrievalEngine:
                 cache.put(
                     req.fp, (self.k, self.generation, rung), ids[i], scores[i]
                 )
+        self.stats.queue_wait_us += queue_wait * 1e6
+        self.stats.service_us += n * (now - t_disp) * 1e6
 
     def _staged_host_serving(self) -> bool:
         """Host-tier LIDER params + a backend exposing the staged search."""
@@ -986,7 +1043,7 @@ class RetrievalEngine:
                 if max_dispatches is not None and n_disp >= max_dispatches:
                     break
                 self._adjust_rung()
-                chunk = self._take_batch(self.scheduler.pick_batch_size())
+                chunk = self._take_next()
                 if not chunk:  # everything was answered from the cache
                     continue
                 n_disp += 1
@@ -1008,46 +1065,38 @@ class RetrievalEngine:
         """
         with faults.activate(self.fault_plan):
             if self._staged_host_serving():
-                t0 = time.perf_counter()
-                e = self._dispatch_stage1(chunk)
-                d2h_s = 0.0
-                while True:
-                    if e.retry_at is not None:
-                        wait = e.retry_at - time.perf_counter()
-                        if wait > 0:
-                            time.sleep(wait)
-                    d2h = self._finish_host_batch(e)
-                    if d2h is not None:
-                        d2h_s = d2h
-                        break
-                self.stats.total_time_s += max(
-                    time.perf_counter() - t0 - d2h_s, 0.0
-                )
+                with Span() as whole:
+                    e = self._dispatch_stage1(chunk)
+                    while True:
+                        if e.retry_at is not None:
+                            wait = e.retry_at - time.perf_counter()
+                            if wait > 0:
+                                time.sleep(wait)
+                        d2h_s = self._finish_host_batch(e)
+                        if d2h_s is not None:
+                            break
+                self.stats.total_time_s += max(whole.s - d2h_s, 0.0)
             else:
                 self._execute_batch(chunk)
         return [self.results.pop(r.rid) for r in chunk]
 
     def _execute_batch(self, chunk: list[Request]) -> None:
         """The serial execution core: pad to the smallest pre-warmed batch
-        size, search, block, account. One compiled trace per ladder size —
-        dispatching ``len(chunk)`` directly would re-trace per distinct
-        depth."""
-        bs = next(
-            (b for b in self.scheduler.ladder if b >= len(chunk)),
-            self.scheduler.ladder[-1],
-        )
-        q = self._device_batch(chunk, bs)
-        t0 = time.perf_counter()
-        out, pruned = self._split_out(self._search(q))
+        size, search, block, account."""
+        bs, seq, t_disp = self._next_batch(chunk)
+        q = self._device_batch(chunk, bs, seq)
+        with self._span("engine.dispatch", seq) as disp:
+            out, pruned = self._split_out(self._search(q))
         # Block on BOTH outputs so AQT covers all device time — blocking on
         # ids alone under-counts when scores finish later. The AQT window
         # closes HERE: D2H conversion (np.asarray) is host-side transfer
         # the paper's efficiency metric must not include.
-        jax.block_until_ready((out.ids, out.scores))
-        dt = time.perf_counter() - t0
+        with self._span("engine.wait", seq) as wait:
+            jax.block_until_ready((out.ids, out.scores))
+        dt = disp.s + wait.s
         self.stats.total_time_s += dt
         self.scheduler.observe_service(bs, dt)
-        self._record_batch(chunk, len(chunk), out, pruned, bs=bs)
+        self._record_batch(chunk, out, pruned, bs=bs, seq=seq, t_disp=t_disp)
 
     def _drain_pipelined(self, max_dispatches: int | None = None) -> None:
         """Double-buffered host-tier drain (§Tiered embedding store).
@@ -1068,75 +1117,72 @@ class RetrievalEngine:
         (the engine only sleeps when every pending batch is backing off
         and there is nothing else to do).
         """
-        t0 = time.perf_counter()
         d2h_s = 0.0
         pending: collections.deque[_PendingBatch] = collections.deque()
         n_disp = 0
-        while len(self.scheduler) or pending:
-            may_dispatch = (
-                len(self.scheduler)
-                and len(pending) < self._pipeline_depth
-                and (max_dispatches is None or n_disp < max_dispatches)
-            )
-            if may_dispatch:
-                self._adjust_rung()
-                chunk = self._take_batch(self.scheduler.pick_batch_size())
-                if chunk:
-                    # Async dispatch: host_stage1 returns before the device
-                    # finishes, so every already-pending batch's host fetch
-                    # below overlaps this compute.
-                    for e in pending:
-                        e.overlap_armed = True
-                    pending.append(self._dispatch_stage1(chunk))
-                    n_disp += 1
-                continue
-            if not pending:
-                break  # queue non-empty but dispatch budget exhausted
-            now = time.perf_counter()
-            entry = next(
-                (
-                    e
-                    for e in pending
-                    if e.retry_at is None or e.retry_at <= now
-                ),
-                None,
-            )
-            if entry is None:
-                # Every pending batch is in fetch backoff and the dispatch
-                # window is closed — nothing useful to overlap; sleep to
-                # the earliest retry stamp.
-                wait = min(e.retry_at for e in pending) - now
-                if wait > 0:
-                    time.sleep(wait)
-                continue
-            finished_d2h = self._finish_host_batch(entry)
-            if finished_d2h is not None:
-                pending.remove(entry)
-                d2h_s += finished_d2h
-        self.stats.total_time_s += max(time.perf_counter() - t0 - d2h_s, 0.0)
+        with Span() as whole:
+            while len(self.scheduler) or pending:
+                may_dispatch = (
+                    len(self.scheduler)
+                    and len(pending) < self._pipeline_depth
+                    and (max_dispatches is None or n_disp < max_dispatches)
+                )
+                if may_dispatch:
+                    self._adjust_rung()
+                    chunk = self._take_next()
+                    if chunk:
+                        # Async dispatch: host_stage1 returns before the
+                        # device finishes, so every already-pending batch's
+                        # host fetch below overlaps this compute.
+                        for e in pending:
+                            e.overlap_armed = True
+                        pending.append(self._dispatch_stage1(chunk))
+                        n_disp += 1
+                    continue
+                if not pending:
+                    break  # queue non-empty but dispatch budget exhausted
+                now = time.perf_counter()
+                entry = next(
+                    (
+                        e
+                        for e in pending
+                        if e.retry_at is None or e.retry_at <= now
+                    ),
+                    None,
+                )
+                if entry is None:
+                    # Every pending batch is in fetch backoff and the
+                    # dispatch window is closed — nothing useful to
+                    # overlap; sleep to the earliest retry stamp.
+                    wait = min(e.retry_at for e in pending) - now
+                    if wait > 0:
+                        time.sleep(wait)
+                    continue
+                finished_d2h = self._finish_host_batch(entry)
+                if finished_d2h is not None:
+                    pending.remove(entry)
+                    d2h_s += finished_d2h
+        self.stats.total_time_s += max(whole.s - d2h_s, 0.0)
 
     def _dispatch_stage1(self, chunk: list[Request]) -> "_PendingBatch":
         """Pad + dispatch the compressed first pass; capture the operating
         point (rung) the batch is computed with so its answers are recorded
         against that point even if the controller steps the live rung
         before the batch completes."""
-        bs = next(
-            (b for b in self.scheduler.ladder if b >= len(chunk)),
-            self.scheduler.ladder[-1],
-        )
-        q = self._device_batch(chunk, bs)
-        t0 = time.perf_counter()
+        bs, seq, t_disp = self._next_batch(chunk)
+        q = self._device_batch(chunk, bs, seq)
         stats_out = {} if self._auto_block_q is not None else None
-        if stats_out is not None:
-            prov, pruned = self.search_fn.host_stage1(
-                self.params, q, self.k, point=self._effective_point(),
-                stats_out=stats_out,
-            )
-        else:
-            prov, pruned = self.search_fn.host_stage1(
-                self.params, q, self.k, point=self._rung_point()
-            )
-        self.scheduler.observe_service(bs, time.perf_counter() - t0)
+        with self._span("engine.dispatch", seq) as disp:
+            if stats_out is not None:
+                prov, pruned = self.search_fn.host_stage1(
+                    self.params, q, self.k, point=self._effective_point(),
+                    stats_out=stats_out,
+                )
+            else:
+                prov, pruned = self.search_fn.host_stage1(
+                    self.params, q, self.k, point=self._rung_point()
+                )
+        self.scheduler.observe_service(bs, disp.s)
         if stats_out:
             # Feed the drained schedule's measured sharing into the stats
             # and re-pick block_q for the NEXT dispatch from the bounded
@@ -1145,15 +1191,13 @@ class RetrievalEngine:
             # pre-warmed, so swapping costs zero query-path retraces.
             self.stats.n_sched_pairs += stats_out["n_pairs"]
             self.stats.n_sched_steps += stats_out["n_steps"]
-            self.stats.sharing_trace.append(
-                stats_out["n_pairs"] / max(stats_out["n_steps"], 1)
-            )
             self._probe_counts.append(stats_out["cluster_counts"])
             self._auto_block_q = pick_block_q(
                 self._probe_counts, self.block_q_ladder
             )
         return _PendingBatch(
-            chunk=chunk, bs=bs, q=q, prov=prov, pruned=pruned, rung=self.rung
+            chunk=chunk, bs=bs, q=q, prov=prov, pruned=pruned, rung=self.rung,
+            seq=seq, t_disp=t_disp,
         )
 
     def _finish_host_batch(self, e: "_PendingBatch") -> float | None:
@@ -1169,15 +1213,15 @@ class RetrievalEngine:
         runs replay identically. Without a plan the fetch error raises."""
         pol = self.policy
         if not e.blocked:
-            # Close the device wait BEFORE the fetch timer: np.asarray(prov)
+            # Close the device wait BEFORE the fetch span: np.asarray(prov)
             # inside host_fetch would otherwise block on the batch's first
             # pass and charge device compute to the host-fetch stat.
-            jax.block_until_ready(e.prov)
+            with self._span("engine.wait", e.seq):
+                jax.block_until_ready(e.prov)
             e.blocked = True
         try:
-            tf0 = time.perf_counter()
-            fetched = self.search_fn.host_fetch(self.params, e.prov.ids)
-            self.stats.host_fetch_us += (time.perf_counter() - tf0) * 1e6
+            with self._span("engine.host_fetch", e.seq, "host_fetch_us"):
+                fetched = self.search_fn.host_fetch(self.params, e.prov.ids)
         except Exception:
             # Retry and compressed-only answers are the chaos-tested
             # recovery path: without an injected-fault plan a fetch error
@@ -1199,15 +1243,19 @@ class RetrievalEngine:
         self.stats.n_host_fetches += 1
         if e.overlap_armed:
             self.stats.n_overlapped_fetches += 1
-        out = self.search_fn.host_stage2(
-            self.params, jnp.asarray(fetched), e.prov.ids, e.q, self.k
+        self.stats.h2d_bytes += fetched.nbytes
+        with self._span("engine.h2d", e.seq, "h2d_us"):
+            fetched = jnp.asarray(fetched)
+        with self._span("engine.dispatch", e.seq):
+            out = self.search_fn.host_stage2(
+                self.params, fetched, e.prov.ids, e.q, self.k
+            )
+        with self._span("engine.wait", e.seq):
+            jax.block_until_ready((out.ids, out.scores))
+        return self._record_batch(
+            e.chunk, out, e.pruned, bs=e.bs, seq=e.seq, t_disp=e.t_disp,
+            rung=e.rung,
         )
-        jax.block_until_ready((out.ids, out.scores))
-        tc0 = time.perf_counter()
-        self._record_batch(
-            e.chunk, len(e.chunk), out, e.pruned, bs=e.bs, rung=e.rung
-        )
-        return time.perf_counter() - tc0
 
     def _record_degraded(self, e: "_PendingBatch") -> float:
         """Answer a fetch-exhausted batch compressed-only: stage 1 already
@@ -1216,16 +1264,16 @@ class RetrievalEngine:
         if self.policy.ladder and self.rung < len(self.policy.ladder):
             self.rung += 1
             self.stats.n_rung_steps += 1
-        out = lider_lib.compressed_only_topk(
-            self.params.bank.gids, e.prov, k=self.k
+        with self._span("engine.dispatch", e.seq):
+            out = lider_lib.compressed_only_topk(
+                self.params.bank.gids, e.prov, k=self.k
+            )
+        with self._span("engine.wait", e.seq):
+            jax.block_until_ready((out.ids, out.scores))
+        return self._record_batch(
+            e.chunk, out, e.pruned, bs=e.bs, seq=e.seq, t_disp=e.t_disp,
+            rung=e.rung, degraded=True,
         )
-        jax.block_until_ready((out.ids, out.scores))
-        tc0 = time.perf_counter()
-        self._record_batch(
-            e.chunk, len(e.chunk), out, e.pruned,
-            bs=e.bs, rung=e.rung, degraded=True,
-        )
-        return time.perf_counter() - tc0
 
     def result(self, rid: int, *, keep: bool = False):
         """Fetch (and by default release) the answer for ``rid``.

@@ -57,6 +57,7 @@ from .. import faults
 from .engine import EVICTED, QueryResult, Shed
 from .replica import DEAD, HEALTHY, HealthPolicy, ReplicaDead, ReplicaSet
 from .scheduler import DEFAULT_TENANT, Request, Scheduler, SchedulerConfig
+from .spans import Span
 
 import threading
 
@@ -374,32 +375,34 @@ class QueryRouter:
     def _run_on(self, rep, chunk):
         """Worker-thread body: fire the dispatch fault site, execute the
         batch under the replica's lock, re-check liveness."""
-        t0 = time.perf_counter()
         try:
-            plan = self.fault_plan
-            if plan is not None:
-                spec = plan.fire(faults.REPLICA_DISPATCH)
-                if spec is not None and faults.spec_targets(spec, rep.name):
-                    if spec.mode == "straggle":
-                        time.sleep(spec.delay_s)
-                    elif spec.mode == "fail":
-                        raise faults.InjectedFault(
-                            faults.REPLICA_DISPATCH,
-                            f"injected dispatch failure on {rep.name!r}",
-                        )
-            if rep.killed:
-                raise ReplicaDead(rep.name)
-            with rep.lock:
+            with Span() as took:
+                plan = self.fault_plan
+                if plan is not None:
+                    spec = plan.fire(faults.REPLICA_DISPATCH)
+                    if spec is not None and faults.spec_targets(
+                        spec, rep.name
+                    ):
+                        if spec.mode == "straggle":
+                            time.sleep(spec.delay_s)
+                        elif spec.mode == "fail":
+                            raise faults.InjectedFault(
+                                faults.REPLICA_DISPATCH,
+                                f"injected dispatch failure on {rep.name!r}",
+                            )
                 if rep.killed:
                     raise ReplicaDead(rep.name)
-                answers = rep.engine.execute_chunk(list(chunk))
-            if rep.killed:
-                # Killed mid-flight: the device may have answered, but the
-                # replica is gone — fail over instead of delivering.
-                raise ReplicaDead(
-                    rep.name, f"replica {rep.name!r} killed mid-flight"
-                )
-            return answers, time.perf_counter() - t0
+                with rep.lock:
+                    if rep.killed:
+                        raise ReplicaDead(rep.name)
+                    answers = rep.engine.execute_chunk(list(chunk))
+                if rep.killed:
+                    # Killed mid-flight: the device may have answered, but
+                    # the replica is gone — fail over instead of delivering.
+                    raise ReplicaDead(
+                        rep.name, f"replica {rep.name!r} killed mid-flight"
+                    )
+            return answers, took.s
         finally:
             with self._lock:
                 rep.outstanding -= 1

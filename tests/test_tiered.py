@@ -330,7 +330,7 @@ def test_engine_autotunes_block_q_without_retrace(tier_pair):
     assert s.n_sched_pairs == 48 * 8
     assert 0 < s.n_sched_steps < s.n_sched_pairs
     assert s.sharing_ratio > 2.0
-    assert len(s.sharing_trace) == 3  # one measurement per drained batch
+    assert s.n_batches == 3  # one measurement per drained batch
     assert eng._auto_block_q == 8  # hot traffic -> deepest rung...
     # ...and the live pick is exactly the cost-model argmin over the window.
     assert pick_block_q(eng._probe_counts, ladder) == 8
