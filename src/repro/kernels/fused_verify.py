@@ -8,8 +8,8 @@ candidate tensor with an einsum and round-trips a ``(B, C)`` score matrix
 through the dedup/top-k (DESIGN.md §Verification-kernel has the byte
 model).
 
-This kernel makes scoring and selection a single VMEM-resident pass per
-query:
+This kernel makes scoring and selection a single VMEM-resident pass over
+the batch:
 
 - XLA gathers the candidate rows into a ``(B, n_blocks, block_c, width)``
   block (the TPU's tiled HBM layouts pad rows below 128 lanes and pack 2-4
@@ -25,7 +25,17 @@ query:
 - a masked **streaming top-k accumulator** lives in VMEM and merges each
   block with duplicate suppression (same semantics as
   ``core.utils.dedup_topk``: duplicates of one id carry equal scores, so
-  keeping the first-selected occurrence is exact).
+  keeping the first-selected occurrence is exact);
+- the grid is ``(ceil(B/R), n_blocks)``: each step scores ``R`` queries'
+  blocks (one contraction batched over the queries, each against its own
+  candidate rows) and merges them as one ``(R, block_c)`` block into an
+  ``(R, k)`` accumulator. A selection step of the merge is a chain of
+  dependent lane reductions paid in latency, so ``R`` rows cost about what
+  one does.
+  ``R = min(B, 32)`` follows the batch shape (``_rows_per_step``): B = 1
+  keeps one query a step; batches above 32 pad to whole groups with rows
+  whose ``out_ids`` are all -1, sliced off after. Results are bit-identical
+  for every ``R``.
 
 Neither the score matrix nor the dedup/sort round-trips exist in HBM; only
 the ``(B, k)`` result is written.
@@ -35,10 +45,11 @@ ids to *report and dedup by* (defaults to ``row_ids``). LIDER passes flat
 ``(cluster, slot)`` rows as ``row_ids`` and global passage ids as
 ``out_ids``. ``out_ids < 0`` marks padding (scored ``-inf``).
 
-Per-(row, block) valid-candidate counts ride the scalar prefetch so
-fully-dead blocks (all probes pruned by the adaptive margin rule, or pure
-padding) skip their MXU pass and merge under ``pl.when`` (DESIGN.md
-§Adaptive speed-quality control plane).
+Per-(row group, block) valid-candidate counts ride the scalar prefetch so
+blocks dead in every row of the group (all probes pruned by the adaptive
+margin rule, or pure padding) skip their MXU pass and merge under
+``pl.when`` (DESIGN.md §Adaptive speed-quality control plane); a dead row
+inside a live group merges only -inf and leaves its accumulator as it was.
 """
 from __future__ import annotations
 
@@ -90,7 +101,8 @@ def _merge_topk(acc_sc, acc_ids, sc, ids, k: int):
     halves apart rather than copying them into one array; each selection
     kills every copy of the selected id in both halves (duplicates carry
     equal scores, so this is exact). One helper serves the per-query
-    kernels (R = 1) and the grouped kernel (R = block_q).
+    kernels (R = ``_rows_per_step(B)``) and the grouped kernel
+    (R = block_q).
     Score ties between distinct ids break toward the smallest id — the order
     ``dedup_topk`` produces (stable top_k over id-sorted candidates). Rows
     with fewer than ``k`` live candidates pad with (-1, -inf).
@@ -130,30 +142,42 @@ def _merge_topk(acc_sc, acc_ids, sc, ids, k: int):
     return o_sc, o_id
 
 
+def _rows_per_step(b: int) -> int:
+    """Queries merged per grid step: the whole batch up to 32 rows (a
+    full-dim block), else 32 — four f32 vregs' sublanes, so each serial
+    selection step of ``_merge_topk`` serves 32 queries for little more
+    than the latency of one. Follows the batch shape and nothing else; B = 1
+    keeps one query a step. (R = 8, 16 and 32 were timed on a v5e at
+    B = 32: 32 was the fastest for every pass; PERF.md §6.)"""
+    return min(b, 32)
+
+
 def _topk_kernel(
-    blk_live_s,  # scalar prefetch: (B * n_blocks,) live candidates per block
-    q_ref,  # (1, d_q) query (codes / sketch) of row bi
-    oid_ref,  # (1, block_c) candidate ids (-1 = padding/pruned)
+    blk_live_s,  # scalar prefetch: (G * n_blocks,) live candidates per block
+    q_ref,  # (R, d_q) queries (codes / sketch) of row group g
+    oid_ref,  # (R, block_c) candidate ids (-1 = padding/pruned)
     *rest,
     k: int,
     n_blocks: int,
     n_extra: int,
     score,
 ):
-    """Per-query score -> dedup top-k over one candidate block
-    (grid = (B, n_blocks), candidate axis innermost).
+    """Score -> dedup top-k of R queries over one candidate block each
+    (grid = (G, n_blocks), candidate axis innermost; row group g holds
+    queries g*R .. g*R+R-1).
 
     ``rest`` is ``(*extra_refs, cand_ref, ids_out, sc_out)`` with
-    ``cand_ref`` the (block_c, width) candidate rows of this block;
-    ``score(rows, q, *extras) -> (1, block_c) f32`` is the only part that
-    differs between the float pass, the code pass and the sketch pass.
+    ``cand_ref`` the (R, block_c, width) candidate rows of this block, each
+    query its own; ``score(rows, q, *extras) -> (R, block_c) f32`` scores
+    each query against its own rows and is the only part that differs
+    between the float pass, the code pass and the sketch pass.
     """
     extras = rest[:n_extra]
     cand_ref, ids_out, sc_out = rest[n_extra:]
-    bi = pl.program_id(0)
+    g = pl.program_id(0)
     cj = pl.program_id(1)
 
-    # The (1, k) output blocks stay resident across the cj axis (same block
+    # The (R, k) output blocks stay resident across the cj axis (same block
     # index), so they are the running top-k accumulator.
     @pl.when(cj == 0)
     def _():
@@ -161,10 +185,11 @@ def _topk_kernel(
         ids_out[...] = jnp.full_like(ids_out, -1)
 
     # Block-skip contract (DESIGN.md §Adaptive): a block whose candidates are
-    # all invalid — every probe feeding it pruned, or pure padding — would
-    # only contribute -inf scores, so its MXU/VPU pass and k-way merge are
-    # skipped and the accumulator carries over.
-    @pl.when(blk_live_s[bi * n_blocks + cj] > 0)
+    # all invalid in every row of the group — every probe feeding them
+    # pruned, or pure padding — would only contribute -inf scores, so its
+    # MXU/VPU pass and k-way merge are skipped and the accumulator carries
+    # over. A dead row of a live group merges only -inf: no change.
+    @pl.when(blk_live_s[g * n_blocks + cj] > 0)
     def _():
         oid = oid_ref[...]
         scores = score(cand_ref[...], q_ref[...], *(e[...] for e in extras))
@@ -172,6 +197,16 @@ def _topk_kernel(
         sc, ids = _merge_topk(sc_out[...], ids_out[...], scores, oid, k)
         sc_out[...] = sc
         ids_out[...] = ids
+
+
+def _per_query_dot(q, rows, **kw):
+    """``(R, w)`` queries x ``(R, block_c, w)`` rows -> ``(R, block_c)``: each
+    query against its own rows, one contraction batched over the R queries
+    (the oracle's ``einsum("bcd,bd->bc")``, so f32 sums keep its order on
+    XLA's CPU dot too, where per-row slices of the block would not)."""
+    return jax.lax.dot_general(
+        q[:, None, :], rows, (((2,), (2,)), ((0,), (0,))), **kw
+    )[:, 0, :]
 
 
 def _score_float(rows, q):
@@ -182,44 +217,33 @@ def _score_float(rows, q):
     precision = (
         jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32 else None
     )
-    return jax.lax.dot_general(
+    return _per_query_dot(
         q.astype(rows.dtype),
         rows,
-        (((1,), (1,)), ((), ())),
         precision=precision,
         preferred_element_type=jnp.float32,
-    )  # (1, block_c)
+    )
 
 
 def _score_codes(rows, q, comb, *, code_dtype: str):
     """int8×int8→int32 MXU pass over int8 (or in-VMEM unpacked int4) codes;
     the pre-gathered combined row×query scale is one f32 multiply after."""
     if code_dtype == "int4":
-        rows = _unpack_int4_vmem(rows)  # (block_c, d) deinterleaved
-    acc = jax.lax.dot_general(
-        q,
-        rows,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )  # (1, block_c)
+        rows = _unpack_int4_vmem(rows)  # (R, block_c, d) deinterleaved
+    acc = _per_query_dot(q, rows, preferred_element_type=jnp.int32)
     return acc.astype(jnp.float32) * comb
 
 
 def _score_sketch(rows, q):
     """Negated Hamming distance: XOR + popcount on the VPU. The per-row sum
-    over words is a ones-vector contraction so the result lands as a
-    (1, block_c) row; popcounts <= 32 are exact in bf16 and their sum
+    over words is a ones-vector contraction so the result lands as an
+    (R, block_c) block; popcounts <= 32 are exact in bf16 and their sum
     (<= d < 2^24) is exact in the f32 accumulator."""
     # Through int32: the TPU has no uint32 -> float conversion.
-    pc = jax.lax.population_count(jnp.bitwise_xor(rows, q)).astype(jnp.int32)
-    ones = jnp.ones((1, rows.shape[1]), jnp.bfloat16)
-    ham = jax.lax.dot_general(
-        ones,
-        pc.astype(jnp.float32).astype(jnp.bfloat16),
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (1, block_c)
-    return -ham
+    pc = jax.lax.population_count(jnp.bitwise_xor(rows, q[:, None, :]))
+    pc = pc.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
+    ones = jnp.ones((rows.shape[0], rows.shape[2]), jnp.bfloat16)
+    return -_per_query_dot(ones, pc, preferred_element_type=jnp.float32)
 
 
 def _gather_topk(
@@ -227,49 +251,58 @@ def _gather_topk(
 ):
     """Shared wrapper of the per-query kernels.
 
-    Pads the candidate axis to whole blocks, gathers the candidate rows as a
-    ``(B, n_blocks, block_c, width)`` block (an XLA gather: the TPU's tiled
-    HBM layouts pack 2-4 narrow rows per 32-bit word and pad rows below 128
-    lanes, so single-row DMAs from inside the kernel do not lower), counts
-    live candidates per block for the skip path, and lays every per-row
-    input out so each block's last two dims equal the array's (the (8, 128)
-    tiling rule). Returns ``(B, k)`` ids and scores.
+    Pads the candidate axis to whole blocks and the batch to whole row
+    groups of ``R = _rows_per_step(B)`` (padding rows are all -1 and sliced
+    off), gathers the candidate rows as a ``(B, n_blocks, block_c, width)``
+    block (an XLA gather: the TPU's tiled HBM layouts pack 2-4 narrow rows
+    per 32-bit word and pad rows below 128 lanes, so single-row DMAs from
+    inside the kernel do not lower), counts live candidates per (row group,
+    block) for the skip path, and lays every per-row input out so each
+    block's last two dims equal the array's (the (8, 128) tiling rule).
+    Returns ``(B, k)`` ids and scores.
     """
     b, c = row_ids.shape
     n = table.shape[0]
     bc = _clamp_block_c(block_c, c)
-    pad = (-c) % bc
-    if pad:
-        row_ids = jnp.pad(row_ids, ((0, 0), (0, pad)))
-        out_ids = jnp.pad(out_ids, ((0, 0), (0, pad)), constant_values=-1)
+    r = _rows_per_step(b)
+    pad_c = (-c) % bc
+    pad_b = (-b) % r
+    if pad_c or pad_b:
+        widths = ((0, pad_b), (0, pad_c))
+        row_ids = jnp.pad(row_ids, widths)
+        out_ids = jnp.pad(out_ids, widths, constant_values=-1)
+        q = jnp.pad(q, ((0, pad_b), (0, 0)))
         if extra is not None:
-            extra = jnp.pad(extra, ((0, 0), (0, pad)))
-    n_blocks = (c + pad) // bc
-    safe_rows = jnp.clip(row_ids, 0, n - 1).reshape(b, n_blocks, bc)
-    cand = table[safe_rows]  # (B, n_blocks, bc, width)
+            extra = jnp.pad(extra, widths)
+    n_groups = (b + pad_b) // r
+    n_blocks = (c + pad_c) // bc
+    safe_rows = jnp.clip(row_ids, 0, n - 1).reshape(-1, n_blocks, bc)
+    cand = table[safe_rows]  # (B_pad, n_blocks, bc, width)
     out_ids = out_ids.astype(jnp.int32)
     blk_live = jnp.sum(
-        (out_ids >= 0).reshape(b, n_blocks, bc), axis=-1, dtype=jnp.int32
+        (out_ids >= 0).reshape(n_groups, r, n_blocks, bc),
+        axis=(1, 3),
+        dtype=jnp.int32,
     ).reshape(-1)
 
-    def per_block(x):
-        return x.reshape(b, n_blocks, 1, bc)
+    def per_block(x):  # (B_pad, C_pad) -> (G, n_blocks, R, bc)
+        return x.reshape(n_groups, r, n_blocks, bc).transpose(0, 2, 1, 3)
 
-    idx_q = lambda bi, cj, live: (bi, 0, 0)
-    idx_blk = lambda bi, cj, live: (bi, cj, 0, 0)
-    blk_spec = pl.BlockSpec((None, None, 1, bc), idx_blk)
-    in_specs = [pl.BlockSpec((None, 1, q.shape[-1]), idx_q), blk_spec]
-    inputs = [q[:, None, :], per_block(out_ids)]
+    idx_g = lambda g, cj, live: (g, 0, 0)
+    idx_blk = lambda g, cj, live: (g, cj, 0, 0)
+    blk_spec = pl.BlockSpec((None, None, r, bc), idx_blk)
+    in_specs = [pl.BlockSpec((None, r, q.shape[-1]), idx_g), blk_spec]
+    inputs = [q.reshape(n_groups, r, -1), per_block(out_ids)]
     if extra is not None:
         in_specs.append(blk_spec)
         inputs.append(per_block(extra))
-    in_specs.append(pl.BlockSpec((None, None, bc, cand.shape[-1]), idx_blk))
+    in_specs.append(pl.BlockSpec((r, None, bc, cand.shape[-1]), idx_blk))
     inputs.append(cand)
 
-    out_spec = pl.BlockSpec((None, 1, k), idx_q)
+    out_spec = pl.BlockSpec((None, r, k), idx_g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, n_blocks),
+        grid=(n_groups, n_blocks),
         in_specs=in_specs,
         out_specs=[out_spec, out_spec],
     )
@@ -282,14 +315,20 @@ def _gather_topk(
             score=score,
         ),
         grid_spec=grid_spec,
+        # The candidate tile is double-buffered: 32 rows of a wide f32 table
+        # (the route, the device rescore) outgrow the default scoped VMEM.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * r * bc * cand.shape[-1] * cand.dtype.itemsize
+            + (32 << 20)
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
-            jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_groups, r, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_groups, r, k), jnp.float32),
         ],
         name=name,
         interpret=interpret,
     )(blk_live, *inputs)
-    return ids[:, 0], scores[:, 0]
+    return ids.reshape(-1, k)[:b], scores.reshape(-1, k)[:b]
 
 
 @functools.partial(
@@ -328,10 +367,11 @@ def fused_verify(
     deinterleaved outside the kernel so the same int8×int8→int32 MXU pass
     applies unchanged.
 
-    Blocks whose candidates are *all* invalid — e.g. every probe feeding them
-    was pruned by the adaptive margin rule, or they are pure C-padding — are
-    skipped entirely (no DMA, no MXU pass): a per-block valid count rides the
-    scalar prefetch so the kernel knows a block is dead before touching it.
+    Blocks whose candidates are *all* invalid in every row of a row group —
+    e.g. every probe feeding them was pruned by the adaptive margin rule, or
+    they are pure C-padding — are skipped entirely (no MXU pass, no merge):
+    a per-(row group, block) valid count rides the scalar prefetch so the
+    kernel knows a block is dead before touching it.
     Output is bit-identical with or without skipping (dead candidates score
     -inf either way); an all-invalid row returns all (-1, -inf).
     """

@@ -11,8 +11,15 @@ import pytest
 from repro.core import lider
 from repro.core.utils import l2_normalize
 from repro.kernels import fused_verify, fused_verify_grouped, ref
+from repro.kernels.fused_verify import _rows_per_step
 from repro.kernels.quant import quantize_rows, quantize_rows_int4
 from repro.kernels.schedule import build_cluster_schedule
+
+
+# Batch sizes the parity tests run at: one query (R = 1), a batch under
+# one row group, a full sublane group, a ragged batch, the cells' batch (one
+# full group of 32), and two groups with padding rows.
+BATCHES = (1, 5, 8, 13, 32, 40)
 
 
 def _case(seed, n, d, b, c, dtype, id_lo=-1):
@@ -30,79 +37,95 @@ def _assert_parity(embs, row_ids, q, k, block_c, out_ids=None, rtol=1e-6):
     wi, ws = ref.verify_topk_ref(embs, row_ids, q, k=k, out_ids=out_ids)
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
     np.testing.assert_allclose(np.asarray(gs), np.asarray(ws), rtol=rtol, atol=rtol)
+    return np.asarray(gi), np.asarray(gs)
 
 
+def test_rows_per_step_follows_batch():
+    """R is the whole batch up to 32 rows, then 32: B = 1 keeps one query a
+    grid step, and no batch of the pow2 ladder is padded."""
+    got = [_rows_per_step(b) for b in (1, 2, 5, 8, 13, 32, 40, 64, 256)]
+    assert got == [1, 2, 5, 8, 13, 32, 32, 32, 32]
+
+
+@pytest.mark.parametrize("b", BATCHES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_parity_padded_ids(dtype):
+def test_parity_padded_ids(dtype, b):
     """-1 slots are excluded and never win a top-k slot."""
-    embs, ids, q = _case(0, 40, 32, 3, 17, dtype)
+    embs, ids, q = _case(0, 40, 32, b, 17, dtype)
     ids = ids.at[:, ::3].set(-1)
     _assert_parity(embs, ids, q, k=5, block_c=8)
 
 
+@pytest.mark.parametrize("b", BATCHES)
 @pytest.mark.parametrize("c,block_c", [(17, 8), (21, 4), (7, 16), (64, 16)])
-def test_parity_c_not_multiple_of_block(c, block_c):
-    embs, ids, q = _case(c, 50, 16, 2, c, jnp.float32)
+def test_parity_c_not_multiple_of_block(c, block_c, b):
+    embs, ids, q = _case(c, 50, 16, b, c, jnp.float32)
     _assert_parity(embs, ids, q, k=4, block_c=block_c)
 
 
-def test_parity_k_exceeds_valid_candidates():
+@pytest.mark.parametrize("b", BATCHES)
+def test_parity_k_exceeds_valid_candidates(b):
     """k > #unique valid ids: tail slots are (-1, -inf), same as the ref."""
-    embs, ids, q = _case(3, 30, 16, 2, 6, jnp.float32)
+    embs, ids, q = _case(3, 30, 16, b, 6, jnp.float32)
     ids = ids.at[:, 3:].set(-1)  # 3 valid per row, duplicates possible
-    gi, gs = fused_verify(embs, ids, q, k=8, block_c=4, interpret=True)
-    wi, ws = ref.verify_topk_ref(embs, ids, q, k=8)
-    np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
-    assert (np.asarray(gi)[:, 3:] == -1).all()
-    assert np.isneginf(np.asarray(gs)[:, 3:]).all()
+    gi, gs = _assert_parity(embs, ids, q, k=8, block_c=4)
+    assert (gi[:, 3:] == -1).all()
+    assert np.isneginf(gs[:, 3:]).all()
 
 
-def test_parity_duplicate_ids_deduped():
+@pytest.mark.parametrize("b", BATCHES)
+def test_parity_duplicate_ids_deduped(b):
     """Duplicate candidates occupy one top-k slot, not several."""
-    embs, ids, q = _case(4, 25, 16, 2, 12, jnp.float32, id_lo=0)
+    embs, ids, q = _case(4, 25, 16, b, 12, jnp.float32, id_lo=0)
     ids = ids.at[:, 6:].set(ids[:, :6])  # every candidate duplicated
-    gi, _ = fused_verify(embs, ids, q, k=6, block_c=4, interpret=True)
-    _assert_parity(embs, ids, q, k=6, block_c=4)
-    for row in np.asarray(gi):
+    gi, _ = _assert_parity(embs, ids, q, k=6, block_c=4)
+    for row in gi:
         v = row[row >= 0]
         assert len(set(v.tolist())) == len(v)
 
 
-def test_parity_score_ties_break_by_smallest_id():
+@pytest.mark.parametrize("b", BATCHES)
+def test_parity_score_ties_break_by_smallest_id(b):
     """Distinct ids with bit-equal scores (duplicate table rows) must come
-    out in the reference order: smallest id first."""
+    out in the reference order: smallest id first, in every row."""
     k1, k3 = jax.random.split(jax.random.PRNGKey(11), 2)
     embs = jax.random.normal(k1, (20, 16))
     embs = embs.at[7].set(embs[2]).at[13].set(embs[2])  # 3-way score tie
-    ids = jnp.asarray([[13, 2, 0, 7, 5, 13]])
-    q = jax.random.normal(k3, (1, 16))
-    _assert_parity(embs, ids, q, k=5, block_c=2)
+    ids = jnp.tile(jnp.asarray([[13, 2, 0, 7, 5, 13]]), (b, 1))
+    q = jax.random.normal(k3, (b, 16))
+    gi, _ = _assert_parity(embs, ids, q, k=5, block_c=2)
+    for row in gi:
+        tied = [i for i in row.tolist() if i in (2, 7, 13)]
+        assert tied == [2, 7, 13]
 
 
-def test_parity_out_ids_mapping():
+@pytest.mark.parametrize("b", BATCHES)
+def test_parity_out_ids_mapping(b):
     """row_ids gather rows; out_ids name/dedup them (the LIDER shape: flat
     (cluster, slot) rows in, global passage ids out)."""
-    embs, rows, q = _case(5, 40, 16, 3, 10, jnp.float32, id_lo=0)
+    embs, rows, q = _case(5, 40, 16, b, 10, jnp.float32, id_lo=0)
     out_ids = rows + 100  # distinct id space
     out_ids = out_ids.at[:, 1].set(-1)  # padding marked on out_ids only
     _assert_parity(embs, rows, q, k=4, block_c=4, out_ids=out_ids)
 
 
+@pytest.mark.parametrize("b", BATCHES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_parity_large_shape_sweep(dtype):
-    embs, ids, q = _case(6, 200, 64, 4, 70, dtype)
+def test_parity_large_shape_sweep(dtype, b):
+    embs, ids, q = _case(6, 200, 64, b, 70, dtype)
     rtol = 1e-6 if dtype == jnp.float32 else 2e-2
     _assert_parity(embs, ids, q, k=10, block_c=16, rtol=rtol)
 
 
+@pytest.mark.parametrize("b", BATCHES)
 @pytest.mark.parametrize("code_dtype", ["int8", "int4"])
-def test_quantized_parity_block_c_exceeds_c(code_dtype):
+def test_quantized_parity_block_c_exceeds_c(code_dtype, b):
     """Regression for the lane-aligned clamp ``bc = min(block_c, c)``: a
     block size larger than the candidate count (the kernel default 256 vs a
     tiny provisional list) must clamp, not pad the grid with out-of-range
     reads — and the clamp must stay exact on the quantized paths where the
     table width differs from the logical width (packed int4)."""
-    embs_f, ids, q = _case(9, 40, 32, 3, 10, jnp.float32)
+    embs_f, ids, q = _case(9, 40, 32, b, 10, jnp.float32)
     quant = quantize_rows if code_dtype == "int8" else quantize_rows_int4
     table, scales = quant(embs_f)
     gi, gs = fused_verify(
@@ -114,6 +137,50 @@ def test_quantized_parity_block_c_exceeds_c(code_dtype):
     )
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
     np.testing.assert_array_equal(np.asarray(gs), np.asarray(ws))
+
+
+@pytest.mark.parametrize("code_dtype", [None, "int8", "int4"])
+def test_row_group_mixes_dead_and_live_rows(code_dtype):
+    """Two row groups (B = 40): all-dead rows beside live ones in the same
+    group, a block dead in every row of the first group but live in the
+    second (skipped there, merged here), and padding rows. Dead rows come
+    back all (-1, -inf); every other row matches the reference exactly."""
+    embs_f, ids, q = _case(21, 60, 32, 40, 48, jnp.float32, id_lo=0)
+    dead_rows = [0, 3, 31, 33]  # in both groups, incl. a group's last row
+    ids = ids.at[jnp.asarray(dead_rows)].set(-1)
+    ids = ids.at[:32, 16:32].set(-1)  # block 1 dead across group 0 only
+    kw = {}
+    table = embs_f
+    if code_dtype is not None:
+        quant = quantize_rows if code_dtype == "int8" else quantize_rows_int4
+        table, kw["scales"] = quant(embs_f)
+        kw["code_dtype"] = code_dtype
+    gi, gs = fused_verify(table, ids, q, k=6, block_c=16, interpret=True, **kw)
+    wi, ws = ref.verify_topk_ref(table, ids, q, k=6, **kw)
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
+    np.testing.assert_allclose(np.asarray(gs), np.asarray(ws), rtol=1e-6)
+    assert (np.asarray(gi)[dead_rows] == -1).all()
+    assert np.isneginf(np.asarray(gs)[dead_rows]).all()
+    live = np.setdiff1d(np.arange(40), dead_rows)
+    assert (np.asarray(gi)[live] >= 0).all()
+
+
+@pytest.mark.parametrize("b", [8, 40])
+def test_same_id_in_two_queries_does_not_leak(b):
+    """Dedup is per query: an id that two rows of one group share is kept by
+    both when it wins in both, and never enters a row that lacks it."""
+    n, d = 64, 16
+    embs = l2_normalize(jax.random.normal(jax.random.PRNGKey(3), (n, d)))
+    q = jnp.tile(embs[5][None], (b, 1))  # every query's best row is 5
+    rng = np.random.default_rng(4)
+    ids = np.stack([rng.choice(np.arange(6, n), 12, replace=False) for _ in range(b)])
+    ids[::2, 7] = 5  # even rows hold id 5, odd rows never do
+    ids = jnp.asarray(ids, jnp.int32)
+    gi, _ = _assert_parity(embs, ids, q, k=4, block_c=8)
+    assert (gi[::2, 0] == 5).all()
+    assert not (gi[1::2] == 5).any()
+    for row, cand in zip(gi, np.asarray(ids)):
+        assert set(row[row >= 0].tolist()) <= set(cand.tolist())
 
 
 # ---------------------------------------------------------------------------

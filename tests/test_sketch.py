@@ -104,6 +104,11 @@ def test_sketch_hamming_scores_are_exact():
 # ---------------------------------------------------------------------------
 
 
+# Batch sizes: one query (one row a grid step), under one row group, a full
+# sublane group, ragged, the cells' 32 (one group), two groups with padding.
+BATCHES = (1, 5, 8, 13, 32, 40)
+
+
 def _mask(ids, pattern, block_c):
     if pattern == "all_live":
         return ids
@@ -113,36 +118,43 @@ def _mask(ids, pattern, block_c):
         return ids.at[:, block_c : 2 * block_c].set(-1)
     if pattern == "all_pruned_row":  # row 0 entirely dead
         return ids.at[0, :].set(-1)
+    if pattern == "dead_rows_in_live_group":  # every third row dead
+        return ids.at[::3, :].set(-1)
     raise ValueError(pattern)
 
 
+@pytest.mark.parametrize("b", BATCHES)
 @pytest.mark.parametrize(
-    "pattern", ["all_live", "tombstoned", "dead_block", "all_pruned_row"]
+    "pattern",
+    ["all_live", "tombstoned", "dead_block", "all_pruned_row",
+     "dead_rows_in_live_group"],
 )
-def test_sketch_kernel_matches_oracle(pattern):
+def test_sketch_kernel_matches_oracle(pattern, b):
     block_c = 8
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
     embs = jax.random.normal(k1, (64, 48))
-    ids = jax.random.randint(k2, (3, 4 * block_c), 0, 64)
-    q = jax.random.normal(k3, (3, 48))
+    ids = jax.random.randint(k2, (b, 4 * block_c), 0, 64)
+    q = jax.random.normal(k3, (b, 48))
     ids = _mask(ids, pattern, block_c)
     table = sketch_rows(embs)
     gi, gs = sketch_prefilter(table, ids, q, k=6, block_c=block_c, interpret=True)
     wi, ws = ref.sketch_topk_ref(table, ids, q, k=6)
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
     np.testing.assert_array_equal(np.asarray(gs), np.asarray(ws))
-    if pattern == "all_pruned_row":
-        assert (np.asarray(gi)[0] == -1).all()
-        assert np.isneginf(np.asarray(gs)[0]).all()
+    dead = {"all_pruned_row": [0], "dead_rows_in_live_group": list(range(0, b, 3))}
+    for row in dead.get(pattern, []):
+        assert (np.asarray(gi)[row] == -1).all()
+        assert np.isneginf(np.asarray(gs)[row]).all()
 
 
-def test_sketch_out_ids_suppression_matches_oracle():
+@pytest.mark.parametrize("b", BATCHES)
+def test_sketch_out_ids_suppression_matches_oracle(b):
     """Tombstoned candidates (``out_ids`` < 0) are suppressed identically by
     kernel and oracle — the same contract as ``verify_topk_op``."""
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(9), 3)
     embs = jax.random.normal(k1, (32, 32))
-    rows = jax.random.randint(k2, (2, 16), 0, 32)
-    q = jax.random.normal(k3, (2, 32))
+    rows = jax.random.randint(k2, (b, 16), 0, 32)
+    q = jax.random.normal(k3, (b, 32))
     out_ids = rows.at[:, 1::2].set(-1)  # every other candidate tombstoned
     table = sketch_rows(embs)
     gi, gs = sketch_prefilter(
@@ -151,9 +163,8 @@ def test_sketch_out_ids_suppression_matches_oracle():
     wi, ws = ref.sketch_topk_ref(table, rows, q, k=8, out_ids=out_ids)
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
     np.testing.assert_array_equal(np.asarray(gs), np.asarray(ws))
-    live = set(np.asarray(out_ids)[np.asarray(out_ids) >= 0].ravel().tolist())
-    got = np.asarray(gi)
-    assert set(got[got >= 0].ravel().tolist()) <= live
+    for got, live in zip(np.asarray(gi), np.asarray(out_ids)):
+        assert set(got[got >= 0].tolist()) <= set(live[live >= 0].tolist())
 
 
 # ---------------------------------------------------------------------------

@@ -76,12 +76,19 @@ def _kernels(text: str) -> list[str]:
     )
 
 
+# Batches the per-query kernels compile at: one query (one row a grid
+# step), a ragged batch (one full-dim row group), the cells' batch, and the
+# bulk batch (eight row groups of 32).
+BATCHES = (1, 13, 32, 256)
+
+
+@pytest.mark.parametrize("b", BATCHES)
 @pytest.mark.parametrize("dtype", ["float32", "int8", "int4"])
-def test_fused_verify_compiles(aot, dtype):
+def test_fused_verify_compiles(aot, dtype, b):
     quantized = dtype != "float32"
     width = D // 2 if dtype == "int4" else D
     table = (N, width), jnp.float32 if dtype == "float32" else jnp.int8
-    shapes = [table, ((B, C), jnp.int32), ((B, D), jnp.float32)]
+    shapes = [table, ((b, C), jnp.int32), ((b, D), jnp.float32)]
     if quantized:
         shapes.append(((N,), jnp.float32))
 
@@ -99,12 +106,13 @@ def test_fused_verify_compiles(aot, dtype):
     assert _kernels(aot(fn, *shapes)) == [want]
 
 
-def test_sketch_prefilter_compiles(aot):
+@pytest.mark.parametrize("b", BATCHES)
+def test_sketch_prefilter_compiles(aot, b):
     text = aot(
         lambda sk, rows, q: sketch_prefilter(sk, rows, q, k=160, interpret=False),
         ((N, D // 32), jnp.uint32),
-        ((B, C), jnp.int32),
-        ((B, D), jnp.float32),
+        ((b, C), jnp.int32),
+        ((b, D), jnp.float32),
     )
     assert _kernels(text) == ["sketch_prefilter"]
 
