@@ -513,20 +513,26 @@ def _encode_into(out, raw_chunk, start, storage_dtype):
     )
 
 
-def _encode_rows(raw_rows, storage_dtype, *, chunk: int = 32):
+# Clusters a build step gathers and encodes at once (``_encode_rows``).
+ENCODE_CHUNK = 32
+
+
+def _encode_rows(chunk_rows, shape, storage_dtype, *, chunk: int):
     """Quantized ``store_rows`` without the rescore table: (codes, scales,
-    sketches), one jit call per ``chunk`` clusters written in place. The
-    sketch packing makes XLA relayout its input; over the whole
-    ``(c, Lp, d)`` table (also inside a scan, where the relayout is
+    sketches) of ``shape`` ``(c, Lp, d)`` float rows, one jit call per
+    ``chunk`` clusters written in place; ``chunk_rows(lo, hi)`` gives the
+    rows of clusters ``lo:hi``. The sketch packing makes XLA relayout its
+    input; over the whole table (also inside a scan, where the relayout is
     hoisted) that copy alone is the table's size again."""
     shapes = jax.eval_shape(
-        lambda r: store_rows(r, storage_dtype), raw_rows
+        lambda r: store_rows(r, storage_dtype),
+        jax.ShapeDtypeStruct(shape, jnp.float32),
     )
     out = tuple(
         jnp.zeros(sh.shape, sh.dtype) for sh in (shapes[0], shapes[1], shapes[3])
     )
-    for i in range(0, raw_rows.shape[0], chunk):
-        out = _encode_into(out, raw_rows[i:i + chunk], i, storage_dtype)
+    for i in range(0, shape[0], chunk):
+        out = _encode_into(out, chunk_rows(i, i + chunk), i, storage_dtype)
     return out
 
 
@@ -608,9 +614,13 @@ def build_bank(
     unless ``allow_drops=True`` (the count is always returned so callers can
     surface it either way).
 
-    ``rescore_tier="host"`` (int8 only — DESIGN.md §Tiered embedding store)
-    builds the full-precision rescore table as a process-local host array
-    instead of a device-resident pytree leaf.
+    ``rescore_tier="host"`` (quantized only — DESIGN.md §Tiered embedding
+    store) builds the full-precision rescore table as a process-local host
+    array instead of a device-resident pytree leaf: each chunk of clusters
+    is gathered, encoded and copied into that array in turn, so the
+    capacity-padded ``(c, Lp, d)`` float table is never whole on the device
+    (at d = 4096 it would not fit). The index equals the device-tier build's
+    byte for byte.
     """
     if rescore_tier not in RESCORE_TIERS:
         raise ValueError(
@@ -629,31 +639,43 @@ def build_bank(
     if n_dropped and not allow_drops:
         raise CapacityOverflowError(n_dropped, capacity)
     gids, sizes = clustering.group_by_cluster(assignment, n_clusters, capacity)
-    raw_rows = gather_cluster_rows(embs, gids)
-    if storage_dtype in QUANTIZED_DTYPES:
+    shape = (n_clusters, capacity, embs.shape[-1])
+    store = None
+    if rescore_tier == "host":
+        table = np.empty(shape, np.float32)
+
+        def host_chunk(lo, hi):
+            rows = gather_cluster_rows(embs, gids[lo:hi])
+            table[lo:hi] = np.asarray(rows)
+            return rows
+
+        stored, emb_scales, sketches = _encode_rows(
+            host_chunk, shape, storage_dtype, chunk=ENCODE_CHUNK
+        )
+        store = EmbStore(
+            "host", rescore=table, gids=np.asarray(jax.device_get(gids))
+        )
+        rescore_embs = None
+    elif storage_dtype in QUANTIZED_DTYPES:
+        raw_rows = gather_cluster_rows(embs, gids)
         # Encoded chunk by chunk in place (eagerly, each step of the chain
         # would allocate a full-size f32 temporary); the raw rows
         # themselves are the rescore table, so they are not routed through
         # a jit (its output would be a copy).
-        stored, emb_scales, sketches = _encode_rows(raw_rows, storage_dtype)
+        stored, emb_scales, sketches = _encode_rows(
+            lambda lo, hi: raw_rows[lo:hi], shape, storage_dtype,
+            chunk=ENCODE_CHUNK,
+        )
         rescore_embs = raw_rows
     else:
         stored, emb_scales, rescore_embs, sketches = store_rows(
-            raw_rows, storage_dtype
+            gather_cluster_rows(embs, gids), storage_dtype
         )
     lsh = lsh_lib.make_lsh(rng, embs.shape[-1], n_arrays, key_len)
     sorted_keys, sorted_pos, resc, r = _fit_all_clusters(
         lsh, stored, emb_scales, gids >= 0, n_leaves=n_leaves,
         storage_dtype=storage_dtype,
     )
-    store = None
-    if rescore_tier == "host":
-        store = EmbStore(
-            "host",
-            rescore=np.asarray(jax.device_get(rescore_embs), np.float32),
-            gids=np.asarray(jax.device_get(gids)),
-        )
-        rescore_embs = None
     bank = ClusterBank(
         lsh=lsh,
         rescale=resc,

@@ -35,7 +35,10 @@ the batch:
   ``R = min(B, 32)`` follows the batch shape (``_rows_per_step``): B = 1
   keeps one query a step; batches above 32 pad to whole groups with rows
   whose ``out_ids`` are all -1, sliced off after. Results are bit-identical
-  for every ``R``.
+  for every ``R`` and ``block_c``;
+- the call's VMEM is planned from the row width (``_tile_plan``): a wide
+  row (d = 4096 f32) shrinks the candidate block so the double-buffered
+  tile, and an int4 tile's unpacked copy, fit what a d = 768 call asks.
 
 Neither the score matrix nor the dedup/sort round-trips exist in HBM; only
 the ``(B, k)`` result is written.
@@ -152,6 +155,52 @@ def _rows_per_step(b: int) -> int:
     return min(b, 32)
 
 
+# VMEM of one per-query call (``_tile_plan``). The cap is what the d = 768
+# route asks for (a double-buffered 32 x 256 x 768 f32 tile plus the
+# scratch allowance): every d = 768 call fits it unchanged, and wider rows
+# shrink the candidate block to stay inside it, well under a v5e's 128 MiB.
+_VMEM_SCRATCH = 32 << 20
+_VMEM_CAP = 2 * 32 * 256 * 768 * 4 + _VMEM_SCRATCH
+
+
+def _vmem_bytes(r: int, bc: int, row_bytes: int, unpacked_row_bytes: int) -> int:
+    """VMEM limit of an (R, bc) step: the double-buffered candidate tile,
+    plus a scratch allowance for scores, ids, the accumulators and Mosaic's
+    own use; a tile unpacked in VMEM (int4 codes widened to int8) gets half
+    the allowance on top of its own bytes where that is more."""
+    unpacked = r * bc * unpacked_row_bytes
+    return 2 * r * bc * row_bytes + max(
+        _VMEM_SCRATCH, unpacked + _VMEM_SCRATCH // 2
+    )
+
+
+def _tile_plan(
+    b: int, c: int, block_c: int, row_bytes: int, unpacked_row_bytes: int = 0
+) -> tuple[int, int, int]:
+    """``(R, block_c, vmem_limit_bytes)`` of one per-query call over ``b``
+    queries x ``c`` candidates of ``row_bytes`` bytes each.
+
+    ``R = _rows_per_step(b)``; the requested block (``_clamp_block_c``)
+    stands while its VMEM fits ``_VMEM_CAP`` — every call at d = 768 does.
+    Wider rows shrink the block 8 rows at a time, not the contraction: each
+    row's score is still one sum over the whole width, in the oracle's
+    order.
+    """
+    r = _rows_per_step(b)
+
+    def vmem(bc):
+        return _vmem_bytes(r, bc, row_bytes, unpacked_row_bytes)
+
+    bc = _clamp_block_c(block_c, c)
+    while bc > 8 and vmem(bc) > _VMEM_CAP:
+        bc -= 8
+    if vmem(bc) > _VMEM_CAP:
+        raise ValueError(
+            f"a candidate row of {row_bytes} B is too wide for one VMEM block"
+        )
+    return r, bc, vmem(bc)
+
+
 def _topk_kernel(
     blk_live_s,  # scalar prefetch: (G * n_blocks,) live candidates per block
     q_ref,  # (R, d_q) queries (codes / sketch) of row group g
@@ -247,14 +296,15 @@ def _score_sketch(rows, q):
 
 
 def _gather_topk(
-    table, row_ids, out_ids, q, extra, *, k, block_c, score, name, interpret
+    table, row_ids, out_ids, q, extra, *, k, block_c, score, name, interpret,
+    unpacked_row_bytes=0,
 ):
     """Shared wrapper of the per-query kernels.
 
     Pads the candidate axis to whole blocks and the batch to whole row
-    groups of ``R = _rows_per_step(B)`` (padding rows are all -1 and sliced
-    off), gathers the candidate rows as a ``(B, n_blocks, block_c, width)``
-    block (an XLA gather: the TPU's tiled HBM layouts pack 2-4 narrow rows
+    groups of ``R`` queries, both from ``_tile_plan`` (padding rows are all
+    -1 and sliced off), gathers the candidate rows as a
+    ``(B, n_blocks, block_c, width)`` block (an XLA gather: the TPU's tiled HBM layouts pack 2-4 narrow rows
     per 32-bit word and pad rows below 128 lanes, so single-row DMAs from
     inside the kernel do not lower), counts live candidates per (row group,
     block) for the skip path, and lays every per-row input out so each
@@ -263,8 +313,10 @@ def _gather_topk(
     """
     b, c = row_ids.shape
     n = table.shape[0]
-    bc = _clamp_block_c(block_c, c)
-    r = _rows_per_step(b)
+    r, bc, vmem_limit = _tile_plan(
+        b, c, block_c, table.shape[-1] * table.dtype.itemsize,
+        unpacked_row_bytes,
+    )
     pad_c = (-c) % bc
     pad_b = (-b) % r
     if pad_c or pad_b:
@@ -317,10 +369,8 @@ def _gather_topk(
         grid_spec=grid_spec,
         # The candidate tile is double-buffered: 32 rows of a wide f32 table
         # (the route, the device rescore) outgrow the default scoped VMEM.
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=2 * r * bc * cand.shape[-1] * cand.dtype.itemsize
-            + (32 << 20)
-        ),
+        # ``_tile_plan`` sizes the block and the limit from the row width.
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         out_shape=[
             jax.ShapeDtypeStruct((n_groups, r, k), jnp.int32),
             jax.ShapeDtypeStruct((n_groups, r, k), jnp.float32),
@@ -405,6 +455,8 @@ def fused_verify(
         score=functools.partial(_score_codes, code_dtype=code_dtype),
         name=f"fused_verify_{code_dtype}",
         interpret=resolve_interpret(interpret),
+        # The int4 tile is unpacked to int8 in VMEM: d bytes a row.
+        unpacked_row_bytes=2 * embs.shape[-1] if code_dtype == "int4" else 0,
     )
 
 

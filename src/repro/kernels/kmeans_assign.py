@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import resolve_interpret
+from .common import resolve_interpret, row_block
 
 _F32_MAX = 3.4e38  # python float: jnp scalars would be captured consts
 
@@ -68,7 +68,7 @@ def kmeans_assign(
     interpret = resolve_interpret(interpret)
     n, d = x.shape
     c = centroids.shape[0]
-    block_n = min(block_n, max(8, n))
+    block_n = row_block(block_n, n, d * x.dtype.itemsize)
     block_c = min(block_c, max(8, c))
     pad_n = (-n) % block_n
     pad_c = (-c) % block_c

@@ -22,7 +22,7 @@ import numpy as np
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import resolve_interpret
+from .common import resolve_interpret, row_block
 
 
 def _lsh_hash_kernel(x_ref, proj_ref, w_hi_ref, w_lo_ref, out_ref):
@@ -79,7 +79,7 @@ def lsh_hash(
     n, d = x.shape
     hm = proj.shape[1]
     assert hm == n_arrays * key_len
-    block_n = min(block_n, max(8, n))
+    block_n = row_block(block_n, n, d * x.dtype.itemsize)
     # A ragged last block is masked by Pallas, so x is never padded (a
     # padded copy would double the build's largest buffer).
     grid = (pl.cdiv(n, block_n),)

@@ -67,6 +67,7 @@ class EngineStats:
     # compressed first pass was already dispatched to the device before the
     # fetch ran — the double-buffered pipeline's payoff condition.
     host_fetch_us: float = 0.0
+    host_fetch_bytes: int = 0  # bytes of the rows those fetches returned
     n_host_fetches: int = 0
     n_overlapped_fetches: int = 0
     # Fault tolerance (DESIGN.md §Failure model): update transactions,
@@ -582,9 +583,6 @@ class RetrievalEngine:
         self.stats = EngineStats()
         self._next_id = 0
         self._seq = 0  # batches dispatched: the next batch's span id
-        # Preallocated padded batch buffer: drain fills it in place instead
-        # of allocating (batch, dim) floats per batch.
-        self._batch_buf = np.zeros((batch_size, dim), np.float32)
 
     @property
     def _accepts_point(self) -> bool:
@@ -875,19 +873,18 @@ class RetrievalEngine:
     ) -> jnp.ndarray:
         """Fill the padded (bs, dim) device batch from ``chunk``.
 
-        The device array must be a COPY of the preallocated buffer, never an
-        alias (CPU jax can zero-copy suitably-aligned NumPy arrays): the
-        pipelined drain refills the buffer for batch i+1 while batch i's
-        device input is still pending in its rescore stage.
+        Each batch fills a host buffer of its own: the transfer of a NumPy
+        array to the device may read it after the call has returned, and
+        the pipelined drain fills batch i+1 while batch i is in flight, so a
+        shared buffer refilled in place could hand batch i the next batch's
+        queries (seen on the CPU under load).
         """
-        q = self._batch_buf[:bs]
+        q = np.zeros((bs, self.dim), np.float32)
         for i, req in enumerate(chunk):
             q[i] = req.query
-        if len(chunk) < bs:  # zero stale rows from the last batch
-            q[len(chunk):] = 0.0
         self.stats.h2d_bytes += q.nbytes
         with self._span("engine.h2d", seq, "h2d_us"):
-            return jnp.array(q)  # jnp.array copies; asarray may alias
+            return jnp.asarray(q)
 
     def _put_result(self, rid: int, value) -> None:
         """Insert one answer, enforcing the results-map bound."""
@@ -1222,6 +1219,7 @@ class RetrievalEngine:
         try:
             with self._span("engine.host_fetch", e.seq, "host_fetch_us"):
                 fetched = self.search_fn.host_fetch(self.params, e.prov.ids)
+                self.stats.host_fetch_bytes += fetched.nbytes
         except Exception:
             # Retry and compressed-only answers are the chaos-tested
             # recovery path: without an injected-fault plan a fetch error

@@ -11,8 +11,13 @@ import pytest
 from repro.core import lider
 from repro.core.utils import l2_normalize
 from repro.kernels import fused_verify, fused_verify_grouped, ref
-from repro.kernels.fused_verify import _rows_per_step
-from repro.kernels.quant import quantize_rows, quantize_rows_int4
+from repro.kernels.fused_verify import (
+    _VMEM_CAP,
+    _rows_per_step,
+    _tile_plan,
+    sketch_prefilter,
+)
+from repro.kernels.quant import quantize_rows, quantize_rows_int4, sketch_rows
 from repro.kernels.schedule import build_cluster_schedule
 
 
@@ -45,6 +50,72 @@ def test_rows_per_step_follows_batch():
     grid step, and no batch of the pow2 ladder is padded."""
     got = [_rows_per_step(b) for b in (1, 2, 5, 8, 13, 32, 40, 64, 256)]
     assert got == [1, 2, 5, 8, 13, 32, 32, 32, 32]
+
+
+# Every per-query call of the two d=768 cells, (C, table row bytes, bytes a
+# row unpacks to in VMEM): route over the f32 centroids, exact rescore of
+# k'=40 f32 rows, sketch pre-filter over 24-word sketches, int4 pass over
+# the 160 sketch survivors, int8 pass over all 8,000 candidates.
+D768_CALLS = {
+    "route": (800, 768 * 4, 0),
+    "rescore": (40, 768 * 4, 0),
+    "sketch": (8000, 24 * 4, 0),
+    "int4": (160, 384, 768),
+    "int8": (8000, 768, 0),
+}
+
+
+@pytest.mark.parametrize("b", [1, 13, 32, 256])
+@pytest.mark.parametrize("call", sorted(D768_CALLS))
+def test_tile_plan_at_d768_is_unchanged(call, b):
+    """At d=768 every call keeps the R, block and VMEM limit it had before
+    the plan followed the row width: R = min(B, 32), the clamped block, and
+    the double-buffered tile plus 32 MiB."""
+    c, row_bytes, unpacked = D768_CALLS[call]
+    r, bc = min(b, 32), max(8, min(256, c) // 8 * 8)
+    assert _tile_plan(b, c, 256, row_bytes, unpacked) == (
+        r, bc, 2 * r * bc * row_bytes + (32 << 20))
+
+
+def test_tile_plan_shrinks_the_block_for_wide_rows():
+    """d=4096: the f32 route's block shrinks to the widest multiple of 8
+    that fits (48), the other calls keep theirs and R; a row too wide for
+    one 8-row block is an error."""
+    assert _tile_plan(32, 800, 256, 4096 * 4) == (32, 48, _VMEM_CAP)
+    assert _tile_plan(32, 40, 256, 4096 * 4)[:2] == (32, 40)
+    assert _tile_plan(32, 160, 256, 2048, 4096)[:2] == (32, 160)
+    assert _tile_plan(32, 8000, 256, 128 * 4)[:2] == (32, 256)
+    with pytest.raises(ValueError, match="too wide"):
+        _tile_plan(32, 800, 256, 10**9)
+    for b in (1, 32, 256):
+        for c, row_bytes, unpacked in [(800, 16384, 0), (40, 16384, 0),
+                                       (160, 2048, 4096), (8192, 2048, 4096)]:
+            assert _tile_plan(b, c, 256, row_bytes, unpacked)[2] <= _VMEM_CAP
+
+
+@pytest.mark.parametrize("b", [5, 32])
+@pytest.mark.parametrize("kind", ["float", "int4", "sketch"])
+def test_parity_at_d4096(kind, b):
+    """Interpret-mode parity with the oracle at the LLM embedder's width,
+    bit-equal: at B=32 the f32 block shrinks to 48 of its 96 (three
+    blocks for C=100), and each row's score is still one sum over d."""
+    embs, ids, q = _case(12, 64, 4096, b, 100, jnp.float32)
+    ids = ids.at[:, ::7].set(-1)
+    if kind == "float":
+        gi, gs = fused_verify(embs, ids, q, k=10, interpret=True)
+        wi, ws = ref.verify_topk_ref(embs, ids, q, k=10)
+    elif kind == "int4":
+        table, scales = quantize_rows_int4(embs)
+        gi, gs = fused_verify(table, ids, q, k=10, scales=scales,
+                              code_dtype="int4", interpret=True)
+        wi, ws = ref.verify_topk_ref(table, ids, q, k=10, scales=scales,
+                                     code_dtype="int4")
+    else:
+        table = sketch_rows(embs)
+        gi, gs = sketch_prefilter(table, ids, q, k=16, interpret=True)
+        wi, ws = ref.sketch_topk_ref(table, ids, q, k=16)
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
+    np.testing.assert_array_equal(np.asarray(gs), np.asarray(ws))
 
 
 @pytest.mark.parametrize("b", BATCHES)

@@ -510,7 +510,8 @@ def test_build_encode_in_chunks_matches_store_rows(storage_dtype):
     from repro.core.bank import _encode_rows
 
     raw = jax.random.normal(jax.random.PRNGKey(3), (10, 16, 40))
-    got = _encode_rows(raw, storage_dtype, chunk=4)
+    got = _encode_rows(lambda lo, hi: raw[lo:hi], raw.shape, storage_dtype,
+                       chunk=4)
     codes, scales, _, sketches = store_rows(raw, storage_dtype)
     for g, w in zip(got, (codes, scales, sketches)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

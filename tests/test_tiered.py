@@ -9,9 +9,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import lider, update
+from repro.core import bank as bank_lib, lider, update
+from repro.core.baselines.flat import flat_search
 from repro.core.bank import EmbStore, set_rescore_tier
-from repro.core.utils import recall_at_k
+from repro.core.utils import l2_normalize, recall_at_k
 from repro.serving import RetrievalEngine, make_backend
 from repro.serving.traffic import make_trace, run_open_loop
 from repro.training import checkpoint
@@ -80,6 +81,82 @@ def test_direct_host_build_matches_conversion(corpus):
     np.testing.assert_array_equal(built.bank.store.rescore,
                                   converted.bank.store.rescore)
     _assert_bit_parity(built, converted, q)
+
+
+def _bank_bytes(bank):
+    """Every leaf of a host-tier bank, and its host table and gid copy."""
+    leaves = {jax.tree_util.keystr(kp): np.asarray(v) for kp, v in
+              jax.tree_util.tree_leaves_with_path(bank)}
+    return dict(leaves, host_rows=bank.store.rescore,
+                host_gids=bank.store.gids)
+
+
+@pytest.fixture(scope="module")
+def wide_corpus():
+    """4,096 unit rows at d=256 (a mixture, like the benchmark's)."""
+    kc, kx, kn = jax.random.split(jax.random.PRNGKey(5), 3)
+    centers = jax.random.normal(kc, (24, 256))
+    assign = jax.random.randint(kx, (4096,), 0, 24)
+    return l2_normalize(
+        centers[assign] + 0.35 * jax.random.normal(kn, (4096, 256)))
+
+
+@pytest.mark.parametrize("chunk", [1, 3, 7, 16])
+@pytest.mark.parametrize("sd", ["int8", "int4"])
+def test_chunked_host_build_equals_one_shot_build(wide_corpus, monkeypatch,
+                                                  sd, chunk):
+    """The host-tier build gathers, encodes and copies a few clusters at a
+    time; the index equals the one-shot build (the whole capacity-padded
+    table gathered on the device, then moved to the host) byte for byte:
+    codes, scales, sketches, every sorted table and fit, host rows, gids."""
+    cfg = lider.LiderConfig(n_clusters=16, n_probe=4, n_arrays=4, n_leaves=4,
+                            kmeans_iters=5, storage_dtype=sd)
+    one_shot = lider.set_rescore_tier(
+        lider.build_lider(jax.random.PRNGKey(1), wide_corpus, cfg), "host")
+    monkeypatch.setattr(bank_lib, "ENCODE_CHUNK", chunk)
+    chunked = lider.build_lider(jax.random.PRNGKey(1), wide_corpus,
+                                dataclasses.replace(cfg, rescore_tier="host"))
+    want, got = _bank_bytes(one_shot.bank), _bank_bytes(chunked.bank)
+    assert sorted(got) == sorted(want)
+    for name in want:
+        assert got[name].dtype == want[name].dtype, name
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+def test_engine_serves_d4096_host_tier_like_flat_search():
+    """The LLM embedder's width through build_lider -> make_backend ->
+    RetrievalEngine on an int4 + sketch + host-tier index: every answered
+    row carries its exact float32 score, and recall against the brute-force
+    reference holds."""
+    kc, kx, kn, kq = jax.random.split(jax.random.PRNGKey(6), 4)
+    centers = jax.random.normal(kc, (16, 4096))
+    assign = jax.random.randint(kx, (2048,), 0, 16)
+    x = l2_normalize(
+        centers[assign] + 0.35 * jax.random.normal(kn, (2048, 4096)))
+    q = np.asarray(l2_normalize(
+        x[:48] + 0.08 * jax.random.normal(kq, (48, 4096))))
+    cfg = lider.LiderConfig(n_clusters=16, n_probe=4, n_arrays=4, n_leaves=4,
+                            kmeans_iters=5, storage_dtype="int4",
+                            rescore_tier="host")
+    params = lider.build_lider(jax.random.PRNGKey(2), x, cfg)
+    eng = _host_engine(params, 4096, sketch_factor=4)
+    eng.warmup()
+    rids = [eng.submit(v) for v in q]
+    eng.drain()
+    answers = [eng.result(r) for r in rids]
+    ids = np.stack([a[0] for a in answers])
+    scores = np.stack([a[1] for a in answers])
+    assert (ids >= 0).all()
+    # Each fetch returns a batch's k' = 40 rows of 4096 float32s.
+    s = eng.stats
+    assert s.n_host_fetches == 3
+    assert s.host_fetch_bytes == s.n_host_fetches * 16 * 40 * 4096 * 4
+    exact = np.einsum("bd,bkd->bk", q.astype(np.float64),
+                      np.asarray(x, np.float64)[ids])
+    # The benchmark's score limit for float32 rows (bench/configs).
+    np.testing.assert_allclose(scores, exact, rtol=0, atol=1e-5)
+    want = flat_search(x, jnp.asarray(q), k=10)
+    assert float(recall_at_k(jnp.asarray(ids), want.ids)) > 0.9
 
 
 def test_host_tier_requires_int8(corpus):
@@ -265,6 +342,30 @@ def test_engine_serves_host_tier_with_overlap(tier_pair):
     assert float(recall_at_k(jnp.asarray(got), gt[:48])) > 0.85
     # no pruning configured -> no probe stats (same contract as serial)
     assert s.n_probes_total == 0
+
+
+def test_batches_in_flight_keep_their_own_host_buffers(tier_pair):
+    """Under the pipelined drain (batch i+1 dispatched while batch i is in
+    flight) every answer belongs to its own query: each returned row's
+    score is that query's float32 inner product with the row. Queries are
+    distinct corpus rows, so a batch handed another batch's queries (a
+    shared host buffer refilled while its transfer was pending, seen on the
+    CPU under load) answers with other queries' scores."""
+    x, _, _, _, ph = tier_pair
+    xs = np.asarray(x)
+    eng = _host_engine(ph, xs.shape[1])
+    eng.warmup()
+    for start in range(0, 192, 48):  # four drains of three batches
+        rows = np.arange(start, start + 48)
+        rids = [eng.submit(xs[r]) for r in rows]
+        eng.drain()
+        for r, rid in zip(rows, rids):
+            ids, scores = eng.result(rid)
+            ids, scores = np.asarray(ids), np.asarray(scores)
+            assert (ids >= 0).all()
+            np.testing.assert_allclose(
+                scores, xs[ids] @ xs[r], rtol=0, atol=1e-5, err_msg=str(r))
+    assert eng.stats.n_overlapped_fetches > 0
 
 
 def test_open_loop_drain_chunk_one_keeps_overlap(tier_pair):

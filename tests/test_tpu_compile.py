@@ -1,5 +1,7 @@
 """Ahead-of-time compiles of the main-path Pallas kernels for a described
-TPU v5e chip, at the paper's width (d=768) and the serving shapes.
+TPU v5e chip, at the paper's width (d=768) and the serving shapes, and at
+a 7B LLM embedder's width (d=4096) at the shapes of the benchmark's
+``msmarco4096-e5mistral-int4-host`` configuration.
 
 Interpret mode (every other kernel test) cannot see what the TPU compiler
 refuses: block shapes off the (8, 128) tiling, unaligned DMA slices, ops
@@ -152,3 +154,65 @@ def test_kmeans_assign_compiles(aot):
         ((CLUSTERS, D), jnp.float32),
     )
     assert _kernels(text) == ["kmeans_assign"]
+
+
+# d=4096 (E5-Mistral-7B): the int4 + host-tier index at the benchmark
+# configuration's shapes. The tables are the served ones (codes, sketches,
+# the centroids the route scores, the fetched rescore rows), so each fits in
+# HBM; a 2**20 x 4096 f32 table would not.
+D_WIDE = 4096
+ROWS_WIDE = CLUSTERS * 1280  # clusters x slot capacity of the int4 bank
+ROUTE_C, CODE_C = 800, 160  # H*r0*c0 centroid candidates; sketch survivors
+
+
+def _wide_calls(b):
+    """(name, fn, shapes) of each per-query kernel call of one d=4096 batch."""
+    q = ((b, D_WIDE), jnp.float32)
+
+    def route(embs, rows, q):
+        return fused_verify(embs, rows, q, k=20, interpret=False)
+
+    def sketch(sk, rows, q):
+        return sketch_prefilter(sk, rows, q, k=160, interpret=False)
+
+    def code_pass(embs, rows, q, scales):
+        return fused_verify(embs, rows, q, k=40, scales=scales,
+                            code_dtype="int4", interpret=False)
+
+    def rescore(embs, rows, q):
+        return fused_verify(embs, rows, q, k=10, interpret=False)
+
+    return {
+        "route": ("fused_verify_float", route, [
+            ((CLUSTERS, D_WIDE), jnp.float32), ((b, ROUTE_C), jnp.int32), q]),
+        "sketch": ("sketch_prefilter", sketch, [
+            ((ROWS_WIDE, D_WIDE // 32), jnp.uint32), ((b, 8000), jnp.int32),
+            q]),
+        "code_pass": ("fused_verify_int4", code_pass, [
+            ((ROWS_WIDE, D_WIDE // 2), jnp.int8), ((b, CODE_C), jnp.int32), q,
+            ((ROWS_WIDE,), jnp.float32)]),
+        "rescore": ("fused_verify_float", rescore, [
+            ((b * 40, D_WIDE), jnp.float32), ((b, 40), jnp.int32), q]),
+    }
+
+
+@pytest.mark.parametrize("b", BATCHES)
+@pytest.mark.parametrize("call", ["route", "sketch", "code_pass", "rescore"])
+def test_wide_rows_compile(aot, call, b):
+    kernel, fn, shapes = _wide_calls(b)[call]
+    assert _kernels(aot(fn, *shapes)) == [kernel]
+
+
+@pytest.mark.parametrize("kernel", ["lsh_hash", "kmeans_assign"])
+def test_wide_build_kernels_compile(aot, kernel):
+    x = ((1 << 18, D_WIDE), jnp.float32)
+    if kernel == "lsh_hash":
+        text = aot(
+            lambda x, p: lsh_hash(x, p, n_arrays=10, key_len=16,
+                                  interpret=False),
+            x, ((D_WIDE, 160), jnp.float32),
+        )
+    else:
+        text = aot(lambda x, c: kmeans_assign(x, c, interpret=False),
+                   x, ((CLUSTERS, D_WIDE), jnp.float32))
+    assert _kernels(text) == [kernel]
