@@ -210,9 +210,9 @@ def candidates(jax, jnp, lider, params, cfg, q):
     routed = lider.route_queries(params, q, n_probe=cfg.n_probe,
                                  r0=cfg.r0_centroid)
     cand = jax.jit(lider._bank_candidates, static_argnames=("k", "r0", "refine"))
-    flat, gids = cand(params.bank, q, routed.ids, k=K, r0=cfg.r0, refine=False)
+    flat, valid = cand(params.bank, q, routed.ids, k=K, r0=cfg.r0, refine=False)
     rows = flat.reshape(q.shape[0], -1)
-    return routed.ids, rows, jnp.where(gids.reshape(rows.shape) >= 0, rows, -1)
+    return routed.ids, rows, jnp.where(valid.reshape(rows.shape), rows, -1)
 
 
 def check_int4_kernels(jax, jnp, lider, ref, schedule, params, cfg, q,

@@ -20,7 +20,8 @@ Search stages carry ``jax.named_scope`` names, so every device op (XLA
 fusion or Pallas call) of the served jits names its stage in its op-name
 metadata and a profiler trace can sum device time per stage:
 ``lider.route`` (centroid routing + probe pruning), ``lider.candidates``
-(hash, rescale, RMI, window, the ``sorted_pos``/``gids`` gathers),
+(hash, rescale, RMI, the contiguous ``sorted_pos`` window reads and the
+liveness mask taken from them; a float bank also maps candidates to gids),
 ``lider.sketch`` (binary-sketch pre-filter), ``lider.code_pass`` (the
 int8/int4 first pass) and ``lider.rescore`` (exact rescore + row->gid map).
 Scopes never nest across stages, and none is a kernel's name.
@@ -297,6 +298,58 @@ def set_rescore_tier(params: LiderParams, tier: str) -> LiderParams:
     )
 
 
+def _read_windows(
+    table: jnp.ndarray, cids: jnp.ndarray, start: jnp.ndarray, width: int
+) -> jnp.ndarray:
+    """``table[cids[b, p], j, start[b, p, j] : start[b, p, j] + width]`` for
+    every (query, probe, array): (B, P, H, width).
+
+    ``table`` is a bank's (c, H, Lp) sorted-order table. On a TPU it is
+    stored H-major in (8, 128) tiles, so its view as a list of those tiles
+    is free: no copy of the table is made (unless c or Lp must be padded to
+    a whole tile, which no served configuration needs). Each window reads
+    the ``nt`` aligned tiles that cover it (B·P·H·nt tile reads of 4 KiB,
+    whatever Lp is), keeps its cluster's sublane, and is shifted left by its
+    start within the first tile, two bits a step. ``cids`` must be in range
+    and ``start`` within ``[0, Lp - width]``."""
+    c, h, lp = table.shape
+    nt = (width + 126) // 128 + 1  # 128-lane tiles a window can touch
+    cp = -(-c // 8) * 8
+    n = max(-(-lp // 128), nt)
+    if (cp, n * 128) != (c, lp):
+        table = jnp.pad(table, ((0, cp - c), (0, 0), (0, n * 128 - lp)))
+    tiles = jnp.transpose(table, (1, 0, 2)).reshape(h, cp // 8, 8, n, 128)
+    tiles = jnp.transpose(tiles, (0, 1, 3, 2, 4)).reshape(-1, 8, 128)
+    s = start.reshape(-1)
+    cid = jnp.broadcast_to(cids[:, :, None], start.shape).reshape(-1)
+    arr = jnp.broadcast_to(jnp.arange(h, dtype=jnp.int32), start.shape).reshape(-1)
+    first = jnp.minimum(s // 128, n - nt)
+    tile = (arr * (cp // 8) + cid // 8) * n + first
+    x = jnp.take(
+        tiles, tile[:, None] + jnp.arange(nt, dtype=jnp.int32), axis=0,
+        mode="clip",
+    )  # (B·P·H, nt, 8, 128)
+    own = (cid % 8)[:, None, None, None] == jnp.arange(8)[None, None, :, None]
+    x = jnp.sum(jnp.where(own, x, 0), axis=2, dtype=table.dtype)
+    x = x.reshape(-1, nt * 128)
+    s = (s - first * 128)[:, None]
+    hi = (nt * 128 - width).bit_length()
+    while hi > 0:
+        # Shift by bits [lo, hi) of the start, two at a time. The window
+        # then starts within the first 2**lo lanes and still ends inside x,
+        # so the zeros padding a piece are never part of a window.
+        lo = max(hi - 2, 0)
+        w = width + (1 << lo) - 1
+        digit = (s >> lo) & ((1 << (hi - lo)) - 1)
+        out = x[:, :w]
+        for d in range(1, 1 << (hi - lo)):
+            piece = x[:, d << lo:(d << lo) + w]
+            piece = jnp.pad(piece, ((0, 0), (0, w - piece.shape[1])))
+            out = jnp.where(digit == d, piece, out)
+        x, hi = out, lo
+    return x.reshape(start.shape + (width,))
+
+
 @jax.named_scope("lider.candidates")
 def _bank_candidates(
     bank: ClusterBank,
@@ -308,11 +361,18 @@ def _bank_candidates(
     refine: bool,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Candidate generation over the probed clusters (hash -> rescale -> RMI
-    -> window expansion). Returns ``(flat_emb, gids)``, both (B, P, H, R):
-    flat ``(cluster, slot)`` rows into the ``(c*Lp, ...)`` tables and the
-    matching global passage ids (-1 at dead/invalid candidates). Shared by
-    the single-pass float search, the quantized two-stage search, and the
-    tiered first pass (§Tiered embedding store)."""
+    -> window read). Returns ``(flat_emb, valid)``, both (B, P, H, R): flat
+    ``(cluster, slot)`` rows into the ``(c*Lp, ...)`` tables, and whether
+    the candidate is live. Shared by the single-pass float search, the
+    quantized two-stage search, and the tiered first pass (§Tiered
+    embedding store).
+
+    Validity comes from ``sorted_pos`` alone: it holds -1 at padding, a
+    refit sorts only rows whose gid is live, and a delete sets -1 at every
+    entry pointing at a row it kills, so ``sorted_pos >= 0`` at a live
+    probe means a live row (``tests/test_update.py`` guards that). No gid is
+    read here; rows map to global ids only where a path needs them: the
+    float funnel (it dedups by gid) and the final top-k rows."""
     c, h, lp = bank.sorted_keys.shape
     b, p = cids.shape
     r = min(r0 * k, lp)
@@ -329,37 +389,29 @@ def _bank_candidates(
         rmi_lib.gather_banked(bank.rmi, safe_cid), scaled
     )  # (B, P, H)
 
-    h_idx = jnp.arange(h, dtype=jnp.int32)[None, None, :, None]
     if refine:
-        # Beyond-paper last-mile: gather a 2R key window around the RMI
+        # Beyond-paper last-mile: read a 2R key window around the RMI
         # prediction (keys are 4 B vs d*4 B embeddings) and binary-search the
         # exact position inside it, then expand only R around the truth.
         w1 = min(2 * r, lp)
         start1 = jnp.clip(jnp.round(pos).astype(jnp.int32) - w1 // 2, 0, lp - w1)
-        idx1 = start1[..., None] + jnp.arange(w1, dtype=jnp.int32)
-        flat1 = (safe_cid[:, :, None, None] * h + h_idx) * lp + idx1
-        keys_win = jnp.take(bank.sorted_keys.reshape(-1), flat1)  # (B,P,H,W1)
+        keys_win = _read_windows(bank.sorted_keys, safe_cid, start1, w1)
         qk = jnp.broadcast_to(qkeys[:, None, :], (b, p, h)).reshape(-1)
         rows = keys_win.reshape(-1, w1)
         off = jax.vmap(lambda row, q: jnp.searchsorted(row, q))(rows, qk)
         pos = (start1 + off.reshape(b, p, h).astype(jnp.int32)).astype(jnp.float32)
 
     start = jnp.clip(jnp.round(pos).astype(jnp.int32) - r // 2, 0, lp - r)
-    idx = start[..., None] + jnp.arange(r, dtype=jnp.int32)  # (B, P, H, R)
-    flat = (safe_cid[:, :, None, None] * h + h_idx) * lp + idx
-    local_pos = jnp.take(bank.sorted_pos.reshape(-1), flat)  # (B, P, H, R)
-
+    local_pos = _read_windows(bank.sorted_pos, safe_cid, start, r)  # (B,P,H,R)
     valid = (local_pos >= 0) & cvalid[:, :, None, None]
     flat_emb = safe_cid[:, :, None, None] * lp + jnp.maximum(local_pos, 0)
-    gids = jnp.take(bank.gids.reshape(-1), flat_emb)
-    gids = jnp.where(valid, gids, -1)
-    return flat_emb, gids
+    return flat_emb, valid
 
 
 def _verify_bank_rows(
     bank: ClusterBank,
     flat_rows: jnp.ndarray,
-    out_gids: jnp.ndarray,
+    valid: jnp.ndarray,
     queries: jnp.ndarray,
     *,
     k: int,
@@ -369,19 +421,23 @@ def _verify_bank_rows(
     sketch_factor: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Verify ``(Bq, C)`` flat bank rows -> gid-space top-k ids + scores
-    (device-tier rescore table).
+    (device-tier rescore table). ``valid`` ``(Bq, C)`` is the candidates'
+    liveness from :func:`_bank_candidates` (read off ``sorted_pos``).
 
     The single verification funnel for both ``incluster_search`` shapes
     (merged and per-pair). On a float bank this is one ``verify_topk_op``
-    call deduped by global id. On a quantized bank it is the two-stage pass
-    (DESIGN.md §Quantized bank):
+    call deduped by global id: the only funnel that maps every candidate to
+    its gid. On a quantized bank it is the two-stage pass (DESIGN.md
+    §Quantized bank), which reads no gid until the end:
 
-    1. int8 first pass over the code table, deduped by *flat row* — exact
-       within the bank, since a passage occupies exactly one (cluster, slot)
-       — keeping the provisional top-``k' = rescore_factor*k``;
+    1. int8 first pass over the code table, reporting ``where(valid, row,
+       -1)`` and deduped by *flat row* — exact within the bank, since a
+       passage occupies exactly one (cluster, slot) — keeping the
+       provisional top-``k' = rescore_factor*k``;
     2. exact rescore of those k' rows from the full-precision side table
        (a gather k'/C the size of the first pass), reusing the same fused
-       kernel; final rows map back to global ids through ``bank.gids``.
+       kernel; only the final k rows map to global ids through
+       ``bank.gids``.
 
     On a *host-tier* bank the rescore table is not device-resident, so the
     second stage cannot be traced here; stage 1 is :func:`provisional_rows`
@@ -394,6 +450,8 @@ def _verify_bank_rows(
     """
     c, lp = bank.gids.shape
     if not bank.quantized:
+        with jax.named_scope("lider.candidates"):
+            out_gids = jnp.where(valid, bank.gids.reshape(-1)[flat_rows], -1)
         with jax.named_scope("lider.rescore"):
             return verify_topk_op(
                 bank.embs.reshape(c * lp, -1),
@@ -405,7 +463,7 @@ def _verify_bank_rows(
                 use_pallas=use_pallas,
             )
     with jax.named_scope("lider.candidates"):
-        out_rows = jnp.where(out_gids >= 0, flat_rows, -1)
+        out_rows = jnp.where(valid, flat_rows, -1)
     kp = min(max(rescore_factor, 1) * k, out_rows.shape[-1])
     if sketch_factor is not None and bank.sketches is not None:
         # Binary-sketch pre-filter (DESIGN.md §Binary sketch tier): 1-bit
@@ -505,21 +563,21 @@ def incluster_search(
             "store)"
         )
     b, p = cids.shape
-    flat_emb, gids = _bank_candidates(
+    flat_emb, valid = _bank_candidates(
         bank, queries, cids, k=k, r0=r0, refine=refine
     )
 
     # Verification: gather rows from the flat (c*Lp, d) table (row_ids =
-    # flat_emb), dedup/report by global passage id (out_ids = gids, -1 where
-    # invalid — tombstoned rows carry gid -1 and are suppressed here).
-    # Scoring happens in the embedding storage dtype (bf16 stays bf16 on the
-    # MXU, int8 runs int8xint8->int32 + exact rescore) with fp32 accumulation
-    # for a stable top-k ordering.
+    # flat_emb), dedup/report by flat row (quantized) or global id (float),
+    # -1 where invalid — tombstoned rows are suppressed here. Scoring
+    # happens in the embedding storage dtype (bf16 stays bf16 on the MXU,
+    # int8 runs int8xint8->int32 + exact rescore) with fp32 accumulation for
+    # a stable top-k ordering.
     if merge:
         ids, sc = _verify_bank_rows(
             bank,
             flat_emb.reshape(b, -1),
-            gids.reshape(b, -1),
+            valid.reshape(b, -1),
             queries,
             k=k,
             rescore_factor=rescore_factor,
@@ -534,7 +592,7 @@ def incluster_search(
     ids, sc = _verify_bank_rows(
         bank,
         flat_emb.reshape(b * p, -1),
-        gids.reshape(b * p, -1),
+        valid.reshape(b * p, -1),
         pair_q.reshape(b * p, -1),
         k=k,
         rescore_factor=rescore_factor,
@@ -612,9 +670,11 @@ def provisional_rows(
     Same candidate generation and int8 first pass as the device-tier
     quantized search — deduped by flat bank row, same tie-break — but stops
     at the provisional top-``k' = rescore_factor*k``: ``ids`` are *flat bank
-    rows* (-1 padding) and ``scores`` are the compressed-domain scores. The
-    caller fetches those rows from the host tier (``bank.store.fetch``) and
-    finishes with :func:`rescore_fetched_rows` / :func:`host_rescore`.
+    rows* (-1 padding) and ``scores`` are the compressed-domain scores.
+    Dead candidates are masked by the liveness read off ``sorted_pos``; no
+    gid is read here. The caller fetches those rows from the host tier
+    (``bank.store.fetch``) and finishes with :func:`rescore_fetched_rows` /
+    :func:`host_rescore`, which map only the final rows to gids.
     ``merge=False`` keeps the per-(query, probe) pair shape for the
     distributed capacity-dispatch path.
     """
@@ -622,21 +682,21 @@ def provisional_rows(
     if not bank.quantized:
         raise ValueError("provisional_rows needs a quantized (int8/int4) bank")
     b, p = cids.shape
-    flat_emb, gids = _bank_candidates(
+    flat_emb, valid = _bank_candidates(
         bank, queries, cids, k=k, r0=r0, refine=refine
     )
     c, lp = bank.gids.shape
     if merge:
         fr = flat_emb.reshape(b, -1)
-        og = gids.reshape(b, -1)
+        ok = valid.reshape(b, -1)
         q = queries
     else:
         pair_q = jnp.broadcast_to(queries[:, None, :], (b, p, queries.shape[-1]))
         fr = flat_emb.reshape(b * p, -1)
-        og = gids.reshape(b * p, -1)
+        ok = valid.reshape(b * p, -1)
         q = pair_q.reshape(b * p, -1)
     with jax.named_scope("lider.candidates"):
-        out_rows = jnp.where(og >= 0, fr, -1)
+        out_rows = jnp.where(ok, fr, -1)
     kp = min(max(rescore_factor, 1) * k, fr.shape[-1])
     if sketch_factor is not None and bank.sketches is not None:
         # Sketch pre-filter, same contract as the device-tier funnel
@@ -855,10 +915,10 @@ def _cluster_major_first_pass(
     bank = params.bank
     b, p = cids.shape
     c, lp = bank.gids.shape
-    flat_emb, gids = _bank_candidates(
+    flat_emb, valid = _bank_candidates(
         bank, queries, cids, k=k, r0=r0, refine=refine
     )
-    out_rows = jnp.where(gids >= 0, flat_emb, -1)  # (B, P, H, R)
+    out_rows = jnp.where(valid, flat_emb, -1)  # (B, P, H, R)
     s_steps = sched_cids.shape[0]
     n_cand = p * flat_emb.shape[2] * flat_emb.shape[3]
     kp = min(max(rescore_factor, 1) * k, n_cand)

@@ -117,6 +117,49 @@ def test_capacity_growth_keeps_pad_multiple(corpus):
     assert new_gids & set(np.asarray(out.ids).ravel().tolist())
 
 
+def _assert_sorted_pos_live(bank, step):
+    """Every ``sorted_pos >= 0`` entry points at a row whose gid is live:
+    search reads candidate liveness off ``sorted_pos`` alone."""
+    sp = np.asarray(bank.sorted_pos)
+    gids = np.asarray(bank.gids)
+    c = sp.shape[0]
+    at = gids[np.arange(c)[:, None, None], np.maximum(sp, 0)]
+    assert (at[sp >= 0] >= 0).all(), step
+    # and every live row is still reachable from each sorted array
+    assert ((sp >= 0).sum(-1) == (gids >= 0).sum(-1)[:, None]).all(), step
+
+
+@pytest.mark.parametrize(
+    "over",
+    [{}, {"storage_dtype": "int8"},
+     {"storage_dtype": "int4", "rescore_tier": "host"}],
+    ids=["float32", "int8-device", "int4-host"],
+)
+def test_sorted_pos_points_only_at_live_rows(corpus, over):
+    """The invariant holds after build, upsert, capacity growth, a
+    tombstoning delete and a compacting delete."""
+    x, _, _ = corpus
+    cfg = dataclasses.replace(CFG, **over)
+    p = lider.build_lider(jax.random.PRNGKey(2), x[:3000], cfg)
+    _assert_sorted_pos_live(p.bank, "build")
+    p, _ = update.upsert(p, x[3000:])
+    _assert_sorted_pos_live(p.bank, "upsert")
+    burst = jnp.tile(x[:1], (p.capacity + CFG.pad_multiple, 1))
+    p, stats = update.upsert(p, burst, pad_multiple=CFG.pad_multiple)
+    assert stats.capacity_grew
+    _assert_sorted_pos_live(p.bank, "growth")
+    p, stats = update.delete(
+        p, jnp.arange(0, 4000, 5, dtype=jnp.int32), refit_threshold=1.0
+    )
+    assert stats.n_refit == 0 and int(p.bank.tombstones.sum()) == 800
+    _assert_sorted_pos_live(p.bank, "tombstone")
+    p, stats = update.delete(
+        p, jnp.arange(1, 4000, 5, dtype=jnp.int32), refit_threshold=0.0
+    )
+    assert stats.n_refit > 0
+    _assert_sorted_pos_live(p.bank, "compaction")
+
+
 def test_checkpoint_roundtrip_bit_identical(corpus, tmp_path):
     x, q, _ = corpus
     p = lider.build_lider(jax.random.PRNGKey(2), x, CFG)
