@@ -99,8 +99,9 @@ def _measure_floor(lider, np, params, queries, gt_ids, k, base_kw, ladder):
     worst = dict(base_kw)
     if ladder:
         worst.update(_point_kwargs(ladder[-1]))
-    prov, _ = lider.host_first_pass(
-        params, queries, k=k, **_point_kwargs(worst)
+    prov, _ = lider.first_pass(
+        params, queries, k=k,
+        point=lider.OperatingPoint(**_point_kwargs(worst)),
     )
     comp = lider.compressed_only_topk(params.bank.gids, prov, k=k)
     per_mode["compressed_only"] = float(

@@ -260,7 +260,7 @@ def _measure(b, c, n, d, k, dtype_name, block_c, rescore_factor, iters=3):
         # The exact second stage: rescore the provisional top-k' rows from
         # the full-precision table (k'/c the gather of the first pass). The
         # provisional set comes from a k'-deep first pass — the pipeline
-        # lider._verify_bank_rows actually runs — not from truncating the
+        # lider._verify actually runs — not from truncating the
         # k-deep parity run above.
         kp = min(rescore_factor * k, c)
 
@@ -277,7 +277,7 @@ def _measure(b, c, n, d, k, dtype_name, block_c, rescore_factor, iters=3):
             )
 
         # Overhead relative to the k'-deep first pass the real pipeline
-        # (lider._verify_bank_rows) runs — not the k-deep parity run above,
+        # (lider._verify) runs — not the k-deep parity run above,
         # whose smaller top-k accumulator would inflate the fraction.
         wall_first = _time(run_first_kp, iters)
         wall = _time(run_rescore, iters)

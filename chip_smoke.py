@@ -170,15 +170,13 @@ def assert_hash_close(name, got, want, x, proj, key_len: int) -> None:
 
 def serve(lider, serving, params, cfg, queries, *, sketch_factor, block_q):
     """Serve ``queries`` through RetrievalEngine; returns (ids, timings)."""
-    kw = dict(n_probe=cfg.n_probe, r0=cfg.r0, rescore_factor=cfg.rescore_factor)
-    if sketch_factor:
-        kw["sketch_factor"] = sketch_factor
-    # Cluster-major serving goes through the engine's block_q ladder: its
-    # schedules are padded to one fixed worst case, so no batch re-traces.
+    kw = dict(n_probe=cfg.n_probe, r0=cfg.r0, rescore_factor=cfg.rescore_factor,
+              sketch_factor=sketch_factor, block_q=block_q)
+    # Cluster-major schedules are padded to one fixed worst case a batch
+    # size, so no batch re-traces.
     engine = serving.RetrievalEngine(
         serving.make_backend("lider", None, updatable=True, **kw),
         batch_size=BATCH, k=K, dim=queries.shape[1], params=params,
-        block_q_ladder=(block_q,) if block_q else None,
     )
     t0 = time.perf_counter()
     engine.warmup()
@@ -208,7 +206,7 @@ def candidates(jax, jnp, lider, params, cfg, q):
     served search generates them: ``(cids (B, P), flat rows (B, C), rows
     to report (B, C), -1 where dead)``."""
     routed = lider.route_queries(params, q, n_probe=cfg.n_probe,
-                                 r0=cfg.r0_centroid)
+                                 r0_centroid=cfg.r0_centroid)
     cand = jax.jit(lider._bank_candidates, static_argnames=("k", "r0", "refine"))
     flat, valid = cand(params.bank, q, routed.ids, k=K, r0=cfg.r0, refine=False)
     rows = flat.reshape(q.shape[0], -1)
@@ -366,23 +364,24 @@ def run_one_chip(args, jax, on_tpu: bool) -> None:
     cids, sched, prov, fetched = check_int4_kernels(
         jax, jnp, lider, ref, schedule, p4, cfg4, q0, corpus[:4096])
     if on_tpu:
-        point = dict(n_probe=cfg4.n_probe, r0=cfg4.r0,
-                     rescore_factor=cfg4.rescore_factor)
+        point = lider.OperatingPoint(
+            n_probe=cfg4.n_probe, r0=cfg4.r0,
+            rescore_factor=cfg4.rescore_factor, sketch_factor=SKETCH_FACTOR)
         check_served_jits("int4_host", {
             "host_first_pass": (lider.host_first_pass.lower(
-                p4, q0, k=K, sketch_factor=SKETCH_FACTOR, **point),
+                p4, q0, k=K, point=point),
                 ROUTE | {"sketch_prefilter", "fused_verify_int4"}),
             "host_rescore": (lider.host_rescore.lower(
-                p4.bank.gids, fetched, prov, q0, k=K), {"fused_verify_float"}),
+                p4.bank.gids, fetched, prov, q0, k=K,
+                point=lider.OperatingPoint()), {"fused_verify_float"}),
             "_route_pruned": (lider._route_pruned.lower(
-                p4, q0, n_probe=cfg4.n_probe), ROUTE),
+                p4, q0, point=point.only("n_probe")), ROUTE),
             "_cluster_major_first_pass": (
                 lider._cluster_major_first_pass.lower(
                     p4, q0, cids, *(jnp.asarray(a) for a in (
                         sched.sched_cids, sched.sched_qids, sched.pair_step,
                         sched.pair_slot)),
-                    k=K, r0=cfg4.r0, rescore_factor=cfg4.rescore_factor,
-                    block_q=BLOCK_Q, sketch_factor=SKETCH_FACTOR),
+                    k=K, point=dataclasses.replace(point, block_q=BLOCK_Q)),
                 {"lsh_hash", "sketch_prefilter", "fused_verify_grouped_int4"}),
         })
     del p4
@@ -422,8 +421,9 @@ def run_one_chip(args, jax, on_tpu: bool) -> None:
     if on_tpu:
         check_served_jits("int8_device", {
             "_search_lider_device": (lider._search_lider_device.lower(
-                p8, q0, k=K, n_probe=cfg8.n_probe, r0=cfg8.r0,
-                rescore_factor=cfg8.rescore_factor),
+                p8, q0, k=K, point=lider.OperatingPoint(
+                    n_probe=cfg8.n_probe, r0=cfg8.r0,
+                    rescore_factor=cfg8.rescore_factor)),
                 ROUTE | {"fused_verify_int8"}),
         })
 

@@ -24,7 +24,6 @@ Search dataflow per device:
 from __future__ import annotations
 
 import math
-from functools import partial
 from typing import Sequence
 
 import jax
@@ -35,14 +34,16 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import faults
 from .bank import replicated_field_names
 from .clustering import update_centroids
-from .core_model import TopK, search_core_model
+from .core_model import TopK
 from .lider import (
     LiderParams,
+    OperatingPoint,
     _cluster_major_first_pass,
-    incluster_search,
+    _incluster,
+    _rescore_resident,
     provisional_rows,
-    prune_probes,
     rescore_fetched_rows,
+    route_probes,
 )
 from .utils import dedup_topk
 
@@ -104,25 +105,18 @@ def make_sharded_search(
     params_like: LiderParams,
     *,
     k: int,
-    n_probe: int,
-    r0: int = 4,
-    r0_centroid: int = 4,
     capacity_factor: float = 2.0,
     cluster_axes: Sequence[str] = ("data",),
     query_axes: Sequence[str] = ("model",),
-    refine: bool = False,
-    use_fused: bool | None = None,
-    prune_margin: float | None = None,
-    rescore_factor: int = 4,
-    block_c: int | None = None,
-    block_q: int | None = None,
-    sketch_factor: int | None = None,
+    **point,
 ):
     """Build the jitted multi-device search fn: (params, queries) -> (TopK, drops).
 
     ``params_like`` supplies the pytree structure/shapes (ShapeDtypeStructs are
     fine — used by the dry-run). Returned fn expects the query batch to be a
-    multiple of the query-axis size.
+    multiple of the query-axis size. ``point`` takes the
+    :class:`~repro.core.lider.OperatingPoint` fields, as ``search_lider``
+    does.
 
     ``use_fused`` selects the verification path inside the shard_map body
     (None -> fused Pallas kernel on TPU, materialized reference elsewhere;
@@ -178,6 +172,7 @@ def make_sharded_search(
     (``faults.SHARD_SEARCH``, mode ``kill_shard``) marks shards dead
     through the same mask.
     """
+    point = OperatingPoint(**point)
     caxes = tuple(cluster_axes)
     qaxes = tuple(query_axes)  # may be empty: replicated queries (batch-1)
     n_cluster_shards = math.prod(mesh.shape[a] for a in caxes)
@@ -195,18 +190,11 @@ def make_sharded_search(
         """Route + prune + capacity dispatch (shared by both tiers)."""
         c_local = local_params.bank.gids.shape[0]
         my = _flat_axis_index(caxes)
-        routed = search_core_model(
-            local_params.centroid_cm,
-            local_params.centroids,
-            q_loc,
-            k=n_probe,
-            r0=r0_centroid,
-            use_fused=use_fused,
-            block_c=block_c,
-        )
         # Adaptive probe pruning before dispatch: a pruned pair is -1, i.e.
         # never "mine" on any shard, so it consumes no capacity slot.
-        cids = prune_probes(routed.ids, routed.scores, prune_margin)
+        cids, _ = route_probes(
+            local_params, q_loc, point=point, prune_margin=point.prune_margin
+        )
         b_loc, p = cids.shape
         n_pairs = b_loc * p
         flat_cids = cids.reshape(-1)
@@ -256,17 +244,9 @@ def make_sharded_search(
         )
         n_pairs = b_loc * p
 
-        pair_topk = incluster_search(
-            local_params,
-            q_loc[sel_b],
-            sel_cid_local[:, None],
-            k=k,
-            r0=r0,
-            refine=refine,
-            use_fused=use_fused,
-            rescore_factor=rescore_factor,
-            block_c=block_c,
-            sketch_factor=sketch_factor,
+        pair_topk = _incluster(
+            local_params, q_loc[sel_b], sel_cid_local[:, None], k=k,
+            point=point,
         )  # (cap, k)
 
         # Scatter per-pair results back to their (query, probe-slot) rows.
@@ -320,16 +300,8 @@ def make_sharded_search(
         c_local, lp = local_params.bank.gids.shape
 
         pair_prov = provisional_rows(
-            local_params,
-            q_loc[sel_b],
-            sel_cid_local[:, None],
-            k=k,
-            r0=r0,
-            refine=refine,
-            use_fused=use_fused,
-            rescore_factor=rescore_factor,
-            block_c=block_c,
-            sketch_factor=sketch_factor,
+            local_params, q_loc[sel_b], sel_cid_local[:, None], k=k,
+            point=point,
         )  # (cap, k') local flat rows + compressed scores
         kp = pair_prov.ids.shape[-1]
         g_rows_pair = jnp.where(
@@ -384,7 +356,7 @@ def make_sharded_search(
             "shards_total": n_cluster_shards,
         }
 
-    if block_q is not None:
+    if point.block_q is not None:
         if not params_like.bank.quantized:
             raise ValueError(
                 "block_q (cluster-major schedule) on the sharded path "
@@ -402,17 +374,8 @@ def make_sharded_search(
             n_query_shards=n_query_shards,
             c_total=c_total,
             k=k,
-            n_probe=n_probe,
-            r0=r0,
-            r0_centroid=r0_centroid,
+            point=point,
             capacity_factor=capacity_factor,
-            refine=refine,
-            use_fused=use_fused,
-            prune_margin=prune_margin,
-            rescore_factor=rescore_factor,
-            block_c=block_c,
-            block_q=block_q,
-            sketch_factor=sketch_factor,
             resolve_health=_resolve_health,
             note_health=_note_health,
         )
@@ -443,12 +406,8 @@ def make_sharded_search(
         fetched = store.fetch(rows_np)  # host np.take on the local shard
         out_gids = store.take_gids(rows_np)  # host row->gid map
         out = _rescore_fetched(
-            jnp.asarray(fetched),
-            jnp.asarray(out_gids),
-            queries,
-            k=k,
-            use_fused=use_fused,
-            block_c=block_c,
+            jnp.asarray(fetched), jnp.asarray(out_gids), queries, k=k,
+            point=point.only("use_fused", "block_c"),
         )
         return out, dropped
 
@@ -456,25 +415,11 @@ def make_sharded_search(
     return search
 
 
-@partial(jax.jit, static_argnames=("k", "use_fused", "block_c"))
-def _rescore_fetched(
-    fetched: jnp.ndarray,
-    out_gids: jnp.ndarray,
-    queries: jnp.ndarray,
-    *,
-    k: int,
-    use_fused: bool | None,
-    block_c: int | None,
-) -> TopK:
-    """Top-level exact rescore of host-fetched rows (distributed front-end).
-
-    Dedups/reports by global id — gids are globally unique, so no cross-
-    shard coordination is needed; ties break by smallest gid (the float-path
-    convention)."""
-    ids, sc = rescore_fetched_rows(
-        fetched, out_gids, queries, k=k, use_fused=use_fused, block_c=block_c
-    )
-    return TopK(ids=ids, scores=sc)
+# Top-level exact rescore of host-fetched rows (distributed front end). It
+# dedups/reports by global id — gids are globally unique, so no cross-shard
+# coordination is needed; ties break by smallest gid (the float-path
+# convention).
+_rescore_fetched = jax.jit(rescore_fetched_rows, static_argnames=("k", "point"))
 
 
 def _make_grouped_search(
@@ -489,17 +434,8 @@ def _make_grouped_search(
     n_query_shards,
     c_total,
     k,
-    n_probe,
-    r0,
-    r0_centroid,
+    point,
     capacity_factor,
-    refine,
-    use_fused,
-    prune_margin,
-    rescore_factor,
-    block_c,
-    block_q,
-    sketch_factor,
     resolve_health,
     note_health,
 ):
@@ -519,32 +455,27 @@ def _make_grouped_search(
     the schedule arrays enter the single shard_map as sharded inputs —
     the merge all-gather stays the only hot-path collective.
     """
-    from ..kernels.ops import verify_topk_op
     from ..kernels.schedule import _pad_pow2, build_cluster_schedule
 
     c_local = c_total // n_cluster_shards
+    block_q, n_probe = point.block_q, point.n_probe
 
-    def _route(centroid_cm, centroids, queries):
-        routed = search_core_model(
-            centroid_cm,
-            centroids,
-            queries,
-            k=n_probe,
-            r0=r0_centroid,
-            use_fused=use_fused,
-            block_c=block_c,
+    def _route(local_params, queries):
+        cids, _ = route_probes(
+            local_params, queries, point=point, prune_margin=point.prune_margin
         )
-        return prune_probes(routed.ids, routed.scores, prune_margin)
+        return cids
 
     # Every device routes the whole batch against its replica of the
-    # centroid retriever. Inside shard_map the routing's Pallas kernels run
-    # per device; a plain jit over mesh-placed inputs would ask the TPU
-    # compiler to partition them, which it cannot.
+    # centroid retriever (the bank shards ride along unread). Inside
+    # shard_map the routing's Pallas kernels run per device; a plain jit
+    # over mesh-placed inputs would ask the TPU compiler to partition them,
+    # which it cannot.
     route_jit = jax.jit(
         jax.shard_map(
             _route,
             mesh=mesh,
-            in_specs=(P(), P(), P()),
+            in_specs=(param_specs, P()),
             out_specs=P(),
             check_vma=False,
         )
@@ -629,13 +560,7 @@ def _make_grouped_search(
             cell["pair_step"],
             cell["pair_slot"],
             k=k,
-            r0=r0,
-            refine=refine,
-            use_fused=use_fused,
-            rescore_factor=rescore_factor,
-            block_c=block_c,
-            block_q=block_q,
-            sketch_factor=sketch_factor,
+            point=point,
         )  # (cap, k') local flat rows + compressed scores
         scatter_idx = jnp.where(cell["sel_valid"], cell["sel"], n_pairs)
         alive = shard_health[my]
@@ -674,32 +599,21 @@ def _make_grouped_search(
             l_sc = jnp.where(alive, l_sc, -jnp.inf)
             out_ids, out_sc = _merge(l_rows, l_sc, kp)
         else:
-            # Device tier: exact rescore of each pair's provisional rows —
-            # the same stage-2 math as _verify_bank_rows — then the per-query
-            # scatter + merge of body.
-            rescore_table = local_params.bank.rescore_embs.reshape(
-                c_loc * lp, -1
+            # Device tier: exact rescore of each pair's provisional rows,
+            # then the per-query scatter + merge of body.
+            pair = _rescore_resident(
+                local_params.bank.gids, local_params.bank.rescore_embs,
+                prov.ids, q_pairs, k=k, point=point,
             )
-            rows, sc = verify_topk_op(
-                rescore_table,
-                jnp.maximum(prov.ids, 0),
-                q_pairs,
-                k=k,
-                out_ids=prov.ids,
-                block_c=block_c,
-                use_pallas=use_fused,
-            )
-            gid_tab = local_params.bank.gids.reshape(-1)
-            pair_ids = jnp.where(rows >= 0, gid_tab[jnp.maximum(rows, 0)], -1)
             ids_buf = (
                 jnp.full((n_pairs + 1, k), -1, dtype=jnp.int32)
                 .at[scatter_idx]
-                .set(pair_ids)
+                .set(pair.ids)
             )
             sc_buf = (
                 jnp.full((n_pairs + 1, k), -jnp.inf, dtype=jnp.float32)
                 .at[scatter_idx]
-                .set(sc)
+                .set(pair.scores)
             )
             l_ids, l_sc = dedup_topk(
                 ids_buf[:-1].reshape(b_loc, -1),
@@ -734,9 +648,7 @@ def _make_grouped_search(
     def search(params: LiderParams, queries: jnp.ndarray, shard_health=None):
         health = resolve_health(shard_health)
         note_health(search, health)
-        cids_np = np.asarray(jax.device_get(
-            route_jit(params.centroid_cm, params.centroids, queries)
-        ))
+        cids_np = np.asarray(jax.device_get(route_jit(params, queries)))
         cells = _host_cells(cids_np)
         cell_args = tuple(jnp.asarray(cells[key]) for key in _CELL_KEYS)
         rows_or_ids, sc, dropped = run(
@@ -749,12 +661,8 @@ def _make_grouped_search(
         fetched = store.fetch(rows_np)
         out_gids = store.take_gids(rows_np)
         out = _rescore_fetched(
-            jnp.asarray(fetched),
-            jnp.asarray(out_gids),
-            queries,
-            k=k,
-            use_fused=use_fused,
-            block_c=block_c,
+            jnp.asarray(fetched), jnp.asarray(out_gids), queries, k=k,
+            point=point.only("use_fused", "block_c"),
         )
         return out, dropped
 

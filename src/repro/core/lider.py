@@ -13,8 +13,9 @@ powers the incremental upsert/delete path in ``core.update``, so online
 maintenance and offline build cannot drift.
 
 ``search_lider`` is the single-device reference; ``core.distributed`` wraps
-the same ``incluster_search`` math in a shard_map with capacity-based
-query->cluster-shard dispatch for the production mesh.
+the same stages in a shard_map with capacity-based query->cluster-shard
+dispatch for the production mesh. Every search-time knob is a field of one
+:class:`OperatingPoint` (DESIGN.md §Operating point).
 
 Search stages carry ``jax.named_scope`` names, so every device op (XLA
 fusion or Pallas call) of the served jits names its stage in its op-name
@@ -52,35 +53,97 @@ jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 @dataclasses.dataclass(frozen=True)
+class OperatingPoint:
+    """Every search-time knob, and the only place their defaults live
+    (DESIGN.md §Operating point).
+
+    ``n_probe`` (paper c0) clusters are routed with a centroid window of
+    ``r0_centroid * n_probe``; each probe reads RMI windows of ``r0 * k``
+    slots, with ``refine`` the beyond-paper searchsorted correction;
+    ``use_fused`` picks the verification kernel (None -> the fused Pallas
+    pass on TPU, the materialized reference elsewhere; True/False force
+    either); ``prune_margin`` masks probes scored that far below a query's
+    best (None: no pruning); on quantized banks ``rescore_factor * k``
+    provisional rows are exactly rescored and ``sketch_factor * k'``
+    survivors of the 1-bit pre-filter reach the code pass (None: no
+    pre-filter); ``block_c`` is the kernels' candidate block (None: their
+    default) and ``block_q`` the cluster-major schedule's query tile (None:
+    the per-query schedule).
+
+    A jit takes the point as one static argument, so each distinct point is
+    one compile — except ``prune_margin``, which every jit takes traced
+    beside a point whose own margin is None: sweeping margins does not
+    recompile."""
+
+    n_probe: int = 20
+    r0: int = 4
+    r0_centroid: int = 4
+    refine: bool = False
+    use_fused: bool | None = None
+    prune_margin: float | None = None
+    rescore_factor: int = 4
+    block_c: int | None = None
+    block_q: int | None = None
+    sketch_factor: int | None = None
+
+    @property
+    def adaptive(self) -> bool:
+        return self.prune_margin is not None
+
+    def only(self, *names: str) -> "OperatingPoint":
+        """This point with every field but ``names`` at its default: the
+        static argument of a jit that reads only those fields, so points
+        that differ elsewhere share its compiled program."""
+        return OperatingPoint(**{n: getattr(self, n) for n in names})
+
+    def label(self) -> str:
+        tag = f"probe{self.n_probe}/r{self.r0}"
+        if self.refine:
+            tag += "/refine"
+        if self.adaptive:
+            tag += f"/margin{self.prune_margin:g}"
+        if self.rescore_factor != OperatingPoint.rescore_factor:
+            tag += f"/rescore{self.rescore_factor}"
+        if self.block_c is not None:
+            tag += f"/blk{self.block_c}"
+        if self.block_q is not None:
+            tag += f"/bq{self.block_q}"
+        if self.sketch_factor is not None:
+            tag += f"/sk{self.sketch_factor}"
+        return tag
+
+
+@dataclasses.dataclass(frozen=True)
 class LiderConfig:
-    """Static build/search configuration (paper Sec. 7.2.1 defaults)."""
+    """Static build configuration (paper Sec. 7.2.1 defaults).
+
+    ``n_probe``, ``r0``, ``r0_centroid`` and ``rescore_factor`` are the
+    configuration's stated search settings (defaulting to the operating
+    point's): the build reads none of them, but a deployment file states
+    them beside the index it describes, and its launcher turns them into
+    the served :class:`OperatingPoint`."""
 
     n_clusters: int = 1000  # c
-    n_probe: int = 20  # c0
+    n_probe: int = OperatingPoint.n_probe  # c0
     n_arrays: int = 10  # H (in-cluster)
     n_arrays_centroid: int = 10  # H (centroids retriever)
     key_len: int | None = None  # M (in-cluster); None -> ceil(log2 Lp)
     key_len_centroid: int | None = None  # M (centroids); None -> ceil(log2 c)
     n_leaves: int = 5  # RMI width W_i
     n_leaves_centroid: int = 10  # RMI width W_c
-    r0: int = 4  # expansion range factor, R = r0 * k
-    r0_centroid: int = 4
+    r0: int = OperatingPoint.r0  # expansion range factor, R = r0 * k
+    r0_centroid: int = OperatingPoint.r0_centroid
     kmeans_iters: int = 20
     capacity: int | None = None  # Lp cap; None -> max cluster size (no drops)
     pad_multiple: int = 8
-    refine: bool = False  # beyond-paper last-mile searchsorted correction
-    # Verification-kernel escape hatch: None -> fused Pallas pass on TPU,
-    # materialized reference elsewhere; True/False forces either path.
-    # Like n_probe/refine, search entry points take this as a kwarg and
-    # launchers feed it from the config (DESIGN.md §Verification-kernel).
-    use_fused: bool | None = None
     # Embedding storage dtype (DESIGN.md §Quantized bank): "float32",
     # "bfloat16", "int8", or "int4". int8 cuts the compulsory candidate-row
     # gather 4x vs f32; int4 packs two codes per byte (8x, 0.5 B/elem).
     # Both quantized dtypes add an exact rescore pass over the provisional
     # top-(rescore_factor * k) from the full-precision side table.
     storage_dtype: str = "float32"
-    rescore_factor: int = 4  # k' = rescore_factor * k (quantized storage only)
+    # k' = rescore_factor * k (quantized storage only)
+    rescore_factor: int = OperatingPoint.rescore_factor
     # Where the full-precision rescore side table lives (quantized storage
     # only; DESIGN.md §Tiered embedding store). "device": a pytree leaf next to
     # the codes (PR-4 layout — costs ~25% more HBM than f32). "host": a
@@ -88,29 +151,6 @@ class LiderConfig:
     # the staged fetch->rescore pipeline and the device-resident index
     # shrinks to codes + scales (~0.25x of f32).
     rescore_tier: str = "device"
-    # Verification-kernel candidate block size; None -> kernel default (256).
-    # Swept by the Pareto autotuner alongside the quantization knobs.
-    block_c: int | None = None
-    # Cluster-major multi-query batching (DESIGN.md §Cluster-major schedule;
-    # quantized banks only): queries in a batch probing the same cluster are
-    # grouped into block_q-wide tiles so the cluster's rows are streamed
-    # once per tile instead of once per query — the big first-pass DMA win
-    # under Zipf-skewed traffic. None keeps the per-query schedule.
-    # Bit-identical results either way; swept by the Pareto autotuner.
-    block_q: int | None = None
-    # Binary-sketch pre-filter tier (DESIGN.md §Binary sketch tier;
-    # quantized banks only): a 1-bit Hamming first pass over the packed
-    # sign-sketch table (1/8 the int8 row bytes) keeps the top
-    # ``sketch_factor * k'`` survivor rows per query, so the int4/int8 code
-    # DMA + MXU pass touches only survivors. None disables the tier; a
-    # factor large enough to cover every candidate is bit-identical to the
-    # unfiltered pass (tests gate this). Swept by the Pareto autotuner.
-    sketch_factor: int | None = None
-    # Adaptive probe pruning (DESIGN.md §Adaptive speed-quality control
-    # plane): probes whose layer-1 centroid score falls more than this
-    # margin below the per-query best are masked to -1 before layer 2.
-    # None disables pruning (bit-identical to the fixed-n_probe search).
-    prune_margin: float | None = None
     # Capacity overflow policy: when ``capacity`` is below the max cluster
     # size, overflow passages are silently unretrievable unless this is set
     # (bank.build_bank raises CapacityOverflowError otherwise).
@@ -257,33 +297,50 @@ def prune_probes(
 
 
 def route_queries(
-    params: LiderParams,
-    queries: jnp.ndarray,
-    *,
-    n_probe: int,
-    r0: int = 4,
-    use_fused: bool | None = None,
-    prune_margin: float | None = None,
-    block_c: int | None = None,
+    params: LiderParams, queries: jnp.ndarray, **point
 ) -> TopK:
     """Layer-1: centroids retriever -> (B, n_probe) cluster ids + scores.
 
-    With ``prune_margin`` set, low-confidence probes come back masked to
-    (-1, -inf) — the slot count stays ``n_probe`` so downstream shapes are
-    static. The centroid table itself always stays full precision (it is
-    KB–MB sized; quantizing it would risk routing quality for no traffic
-    win).
+    Takes :class:`OperatingPoint` fields as keywords and reads ``n_probe``,
+    ``r0_centroid``, ``use_fused``, ``block_c`` and ``prune_margin``. With
+    a margin set, low-confidence probes come back masked to (-1, -inf) —
+    the slot count stays ``n_probe`` so downstream shapes are static. The
+    centroid table itself always stays full precision (it is KB–MB sized;
+    quantizing it would risk routing quality for no traffic win).
     """
+    point = OperatingPoint(**point)
     routed = search_core_model(
-        params.centroid_cm, params.centroids, queries, k=n_probe, r0=r0,
-        use_fused=use_fused, block_c=block_c,
+        params.centroid_cm, params.centroids, queries, k=point.n_probe,
+        r0=point.r0_centroid, use_fused=point.use_fused, block_c=point.block_c,
     )
-    if prune_margin is None:
+    if point.prune_margin is None:
         return routed
-    cids = prune_probes(routed.ids, routed.scores, prune_margin)
+    cids = prune_probes(routed.ids, routed.scores, point.prune_margin)
     return TopK(
         ids=cids, scores=jnp.where(cids >= 0, routed.scores, -jnp.inf)
     )
+
+
+def route_probes(
+    params: LiderParams,
+    queries: jnp.ndarray,
+    *,
+    point: OperatingPoint,
+    prune_margin: float | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The route stage of every search: layer-1 routing at ``point``, the
+    margin rule at ``prune_margin`` (passed beside the point so it can be
+    traced), and the mask of probes routed but pruned. Returns ``(cids,
+    pruned)``, both (B, n_probe)."""
+    with jax.named_scope("lider.route"):
+        routed = route_queries(
+            params, queries, n_probe=point.n_probe,
+            r0_centroid=point.r0_centroid, use_fused=point.use_fused,
+            block_c=point.block_c,
+        )
+        cids = prune_probes(routed.ids, routed.scores, prune_margin)
+        pruned = (routed.ids >= 0) & (cids < 0)
+    return cids, pruned
 
 
 def set_rescore_tier(params: LiderParams, tier: str) -> LiderParams:
@@ -408,111 +465,241 @@ def _bank_candidates(
     return flat_emb, valid
 
 
-def _verify_bank_rows(
+def _candidate_rows(
     bank: ClusterBank,
-    flat_rows: jnp.ndarray,
-    valid: jnp.ndarray,
     queries: jnp.ndarray,
+    cids: jnp.ndarray,
     *,
     k: int,
-    rescore_factor: int,
-    block_c: int | None,
-    use_pallas: bool | None,
-    sketch_factor: int | None = None,
+    point: OperatingPoint,
+    merge: bool,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The probed clusters' candidates as verification rows: ``(rows,
+    live, q)``, one row per query over all its probes (``merge``) or one
+    per (query, probe) pair — the shape the sharded dispatch scatters back
+    — with ``q`` the query of each row. ``rows`` are flat ``(cluster,
+    slot)`` rows into the ``(c*Lp, ...)`` tables and ``live`` their
+    liveness (:func:`_bank_candidates`)."""
+    flat_emb, valid = _bank_candidates(
+        bank, queries, cids, k=k, r0=point.r0, refine=point.refine
+    )
+    b, p = cids.shape
+    if merge:
+        return flat_emb.reshape(b, -1), valid.reshape(b, -1), queries
+    pair_q = jnp.broadcast_to(queries[:, None, :], (b, p, queries.shape[-1]))
+    return (
+        flat_emb.reshape(b * p, -1),
+        valid.reshape(b * p, -1),
+        pair_q.reshape(b * p, -1),
+    )
+
+
+def _per_pair(out: TopK, cids: jnp.ndarray, merge: bool) -> TopK:
+    """Rows of :func:`_candidate_rows` back to (B, P, ·) when not merged."""
+    if merge:
+        return out
+    b, p = cids.shape
+    return TopK(ids=out.ids.reshape(b, p, -1), scores=out.scores.reshape(b, p, -1))
+
+
+def _sketch_filter(
+    bank: ClusterBank,
+    rows: jnp.ndarray,
+    out_rows: jnp.ndarray,
+    q: jnp.ndarray,
+    *,
+    kp: int,
+    point: OperatingPoint,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Verify ``(Bq, C)`` flat bank rows -> gid-space top-k ids + scores
-    (device-tier rescore table). ``valid`` ``(Bq, C)`` is the candidates'
-    liveness from :func:`_bank_candidates` (read off ``sorted_pos``).
-
-    The single verification funnel for both ``incluster_search`` shapes
-    (merged and per-pair). On a float bank this is one ``verify_topk_op``
-    call deduped by global id: the only funnel that maps every candidate to
-    its gid. On a quantized bank it is the two-stage pass (DESIGN.md
-    §Quantized bank), which reads no gid until the end:
-
-    1. int8 first pass over the code table, reporting ``where(valid, row,
-       -1)`` and deduped by *flat row* — exact within the bank, since a
-       passage occupies exactly one (cluster, slot) — keeping the
-       provisional top-``k' = rescore_factor*k``;
-    2. exact rescore of those k' rows from the full-precision side table
-       (a gather k'/C the size of the first pass), reusing the same fused
-       kernel; only the final k rows map to global ids through
-       ``bank.gids``.
-
-    On a *host-tier* bank the rescore table is not device-resident, so the
-    second stage cannot be traced here; stage 1 is :func:`provisional_rows`
-    and the fetch + rescore run between jits (:func:`search_lider` /
-    ``serving.RetrievalEngine`` pipeline) — this function is device-tier
-    only.
-
-    Score ties between distinct passages break by smallest flat row on the
-    quantized path (vs smallest gid on the float path) — both deterministic.
-    """
+    """Binary-sketch pre-filter (DESIGN.md §Binary sketch tier): a 1-bit
+    Hamming pass over the packed sign sketches keeps the top
+    ``sketch_factor * k'`` rows per query, deduped by ``out_rows`` (the
+    code pass's tie-break). Returns ``(survivors, rows to read)``: -1 /
+    row 0 where fewer survive. A factor covering every distinct candidate
+    is bit-identical to no filter: survivors then hold all valid rows, the
+    per-row code scores are unchanged, and dedup collapses the duplicates
+    the sketch pass already collapsed."""
     c, lp = bank.gids.shape
-    if not bank.quantized:
-        with jax.named_scope("lider.candidates"):
-            out_gids = jnp.where(valid, bank.gids.reshape(-1)[flat_rows], -1)
-        with jax.named_scope("lider.rescore"):
-            return verify_topk_op(
-                bank.embs.reshape(c * lp, -1),
-                flat_rows,
-                queries,
-                k=k,
-                out_ids=out_gids,
-                block_c=block_c,
-                use_pallas=use_pallas,
-            )
+    m = min(max(point.sketch_factor, 1) * kp, rows.shape[-1])
+    with jax.named_scope("lider.sketch"):
+        surv, _ = sketch_topk_op(
+            bank.sketches.reshape(c * lp, -1),
+            rows,
+            q,
+            k=m,
+            out_ids=out_rows,
+            block_c=point.block_c,
+            use_pallas=point.use_fused,
+        )
+        return surv, jnp.maximum(surv, 0)
+
+
+def _compressed_pass(
+    bank: ClusterBank,
+    rows: jnp.ndarray,
+    live: jnp.ndarray,
+    q: jnp.ndarray,
+    *,
+    k: int,
+    point: OperatingPoint,
+) -> TopK:
+    """The compressed first pass of a quantized bank: candidate mask ->
+    sketch pre-filter (with ``sketch_factor``) -> int8/int4 code pass.
+
+    Candidates are reported as ``where(live, row, -1)`` and deduped by
+    *flat row* — exact within the bank, since a passage occupies exactly
+    one (cluster, slot) — and the pass keeps the provisional top-``k' =
+    rescore_factor * k``: ``ids`` flat rows (-1 padding), ``scores`` the
+    compressed-domain scores. No gid is read: only the final rows map to
+    global ids (:func:`rescore_rows`). Score ties break by smallest flat
+    row (the float path breaks them by smallest gid)."""
+    c, lp = bank.gids.shape
     with jax.named_scope("lider.candidates"):
-        out_rows = jnp.where(valid, flat_rows, -1)
-    kp = min(max(rescore_factor, 1) * k, out_rows.shape[-1])
-    if sketch_factor is not None and bank.sketches is not None:
-        # Binary-sketch pre-filter (DESIGN.md §Binary sketch tier): 1-bit
-        # Hamming pass over the packed sign sketches keeps the top
-        # ``sketch_factor * k'`` survivor rows (deduped by flat row, same
-        # tie-break as the int pass), so the code-table DMA below streams
-        # only survivors. A factor covering every distinct candidate is
-        # bit-identical to the unfiltered pass: survivors then hold all
-        # valid rows, per-row int scores are unchanged, and dedup collapses
-        # the duplicates the sketch pass already collapsed.
-        m = min(max(sketch_factor, 1) * kp, out_rows.shape[-1])
-        with jax.named_scope("lider.sketch"):
-            surv, _ = sketch_topk_op(
-                bank.sketches.reshape(c * lp, -1),
-                flat_rows,
-                queries,
-                k=m,
-                out_ids=out_rows,
-                block_c=block_c,
-                use_pallas=use_pallas,
-            )
-            flat_rows = jnp.maximum(surv, 0)
-        out_rows = surv
+        out_rows = jnp.where(live, rows, -1)
+    kp = min(max(point.rescore_factor, 1) * k, rows.shape[-1])
+    if point.sketch_factor is not None and bank.sketches is not None:
+        out_rows, rows = _sketch_filter(
+            bank, rows, out_rows, q, kp=kp, point=point
+        )
     with jax.named_scope("lider.code_pass"):
-        prov_rows, _ = verify_topk_op(
+        ids, scores = verify_topk_op(
             bank.embs.reshape(c * lp, -1),
-            flat_rows,
-            queries,
+            rows,
+            q,
             k=kp,
             out_ids=out_rows,
             scales=bank.emb_scales.reshape(-1),
-            block_c=block_c,
+            block_c=point.block_c,
             code_dtype=bank.code_dtype,
-            use_pallas=use_pallas,
+            use_pallas=point.use_fused,
         )
+    return TopK(ids=ids, scores=scores)
+
+
+def row_gids(gids: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    """Flat bank rows -> global ids through the bank's (c, Lp) ``gids``
+    table (-1 stays -1)."""
+    return jnp.where(rows >= 0, gids.reshape(-1)[jnp.maximum(rows, 0)], -1)
+
+
+def rescore_rows(
+    table: jnp.ndarray,
+    row_ids: jnp.ndarray,
+    out_ids: jnp.ndarray,
+    queries: jnp.ndarray,
+    *,
+    k: int,
+    point: OperatingPoint,
+    gids: jnp.ndarray | None = None,
+) -> TopK:
+    """The exact rescore: score rows ``row_ids`` (B, k') of the
+    full-precision ``table`` against the queries and keep the top ``k``,
+    deduplicated and reported by ``out_ids`` (-1: dead). With ``gids`` the
+    surviving ``out_ids`` are flat bank rows and map to global ids
+    (:func:`row_gids`); without, they are global ids already.
+
+    The resident table and a host-fetched block run the same kernel, so
+    their scores and tie-breaks are bit-identical: see
+    :func:`_rescore_resident` and :func:`rescore_fetched_rows`."""
+    ids, scores = verify_topk_op(
+        table,
+        row_ids,
+        queries,
+        k=k,
+        out_ids=out_ids,
+        block_c=point.block_c,
+        use_pallas=point.use_fused,
+    )
+    if gids is not None:
+        ids = row_gids(gids, ids)
+    return TopK(ids=ids, scores=scores)
+
+
+def _rescore_resident(
+    gids: jnp.ndarray,
+    rescore_embs: jnp.ndarray,
+    prov_rows: jnp.ndarray,
+    queries: jnp.ndarray,
+    *,
+    k: int,
+    point: OperatingPoint,
+) -> TopK:
+    """Device tier: exact rescore of provisional flat rows (B, k') from the
+    resident rescore table (c, Lp, d), a gather k'/C the size of the first
+    pass."""
     with jax.named_scope("lider.rescore"):
-        rows, scores = verify_topk_op(
-            bank.rescore_embs.reshape(c * lp, -1),
+        return rescore_rows(
+            rescore_embs.reshape(-1, rescore_embs.shape[-1]),
             jnp.maximum(prov_rows, 0),
+            prov_rows,
             queries,
             k=k,
-            out_ids=prov_rows,
-            block_c=block_c,
-            use_pallas=use_pallas,
+            point=point,
+            gids=gids,
         )
-        ids = jnp.where(
-            rows >= 0, bank.gids.reshape(-1)[jnp.maximum(rows, 0)], -1
+
+
+def _verify(
+    bank: ClusterBank,
+    rows: jnp.ndarray,
+    live: jnp.ndarray,
+    q: jnp.ndarray,
+    *,
+    k: int,
+    point: OperatingPoint,
+) -> TopK:
+    """Candidate rows -> gid-space top-k on a device-tier bank.
+
+    A quantized bank runs the compressed first pass and the exact rescore
+    of its top-k' (DESIGN.md §Quantized bank). A float bank is one
+    ``verify_topk_op`` call deduped by global id: the only funnel that maps
+    every candidate to its gid."""
+    if bank.quantized:
+        prov = _compressed_pass(bank, rows, live, q, k=k, point=point)
+        return _rescore_resident(
+            bank.gids, bank.rescore_embs, prov.ids, q, k=k, point=point
         )
-    return ids, scores
+    c, lp = bank.gids.shape
+    with jax.named_scope("lider.candidates"):
+        out_gids = jnp.where(live, bank.gids.reshape(-1)[rows], -1)
+    with jax.named_scope("lider.rescore"):
+        ids, scores = verify_topk_op(
+            bank.embs.reshape(c * lp, -1),
+            rows,
+            q,
+            k=k,
+            out_ids=out_gids,
+            block_c=point.block_c,
+            use_pallas=point.use_fused,
+        )
+    return TopK(ids=ids, scores=scores)
+
+
+def _incluster(
+    params: LiderParams,
+    queries: jnp.ndarray,
+    cids: jnp.ndarray,
+    *,
+    k: int,
+    point: OperatingPoint,
+    merge: bool = True,
+) -> TopK:
+    """Layer 2 at ``point`` on a device-tier bank (:func:`incluster_search`
+    without its keyword surface)."""
+    if params.bank.rescore_tier == "host":
+        raise ValueError(
+            "incluster_search cannot complete on a host-tier bank — the "
+            "rescore table is off-device; use search_lider (staged "
+            "fetch->rescore pipeline) or provisional_rows + "
+            "rescore_fetched_rows directly (DESIGN.md §Tiered embedding "
+            "store)"
+        )
+    rows, live, q = _candidate_rows(
+        params.bank, queries, cids, k=k, point=point, merge=merge
+    )
+    return _per_pair(
+        _verify(params.bank, rows, live, q, k=k, point=point), cids, merge
+    )
 
 
 def incluster_search(
@@ -521,133 +708,58 @@ def incluster_search(
     cids: jnp.ndarray,
     *,
     k: int,
-    r0: int = 4,
-    refine: bool = False,
     merge: bool = True,
-    use_fused: bool | None = None,
     cid_scores: jnp.ndarray | None = None,
-    prune_margin: float | None = None,
-    rescore_factor: int = 4,
-    block_c: int | None = None,
-    sketch_factor: int | None = None,
+    **point,
 ) -> TopK:
     """Layer-2: search the probed clusters for each query.
 
-    ``queries``: (B, d); ``cids``: (B, P) cluster ids (-1 = unused probe slot).
-    With ``merge=False`` returns the per-pair top-k (B, P, k) — the shape the
-    distributed capacity-dispatch path scatters back before merging.
-    With ``cid_scores`` (the layer-1 routing scores) and ``prune_margin``
-    both set, probes outside the margin are masked to -1 here instead of by
-    the caller — either spelling yields the same candidate mask.
+    ``queries``: (B, d); ``cids``: (B, P) cluster ids (-1 = unused probe
+    slot); ``point``: :class:`OperatingPoint` fields. With ``merge=False``
+    returns the per-pair top-k (B, P, k). With ``cid_scores`` (the layer-1
+    routing scores) and ``prune_margin`` both set, probes outside the
+    margin are masked to -1 here instead of by the caller — either spelling
+    yields the same candidate mask.
 
-    Verification goes through ``verify_topk_op`` (``use_fused`` as in
-    ``LiderConfig``): the fused kernel streams the gathered rows through VMEM
-    and emits only the (B, k) result, instead of materializing the
-    (B, P, H, R, d) candidate tensor in HBM before the einsum. On an int8
-    bank the pass runs in the compressed domain and is followed by an exact
-    rescore of the provisional top-``rescore_factor * k`` rows
-    (:func:`_verify_bank_rows`); ``block_c`` tunes the kernel's candidate
-    block size.
+    Verification goes through ``verify_topk_op`` (``use_fused``): the fused
+    kernel streams the gathered rows through VMEM and emits only the (B, k)
+    result, instead of materializing the (B, P, H, R, d) candidate tensor
+    in HBM before the einsum. On a quantized bank the pass runs in the
+    compressed domain and is followed by an exact rescore of the
+    provisional top-``rescore_factor * k`` rows.
     """
-    if prune_margin is not None:
+    point = OperatingPoint(**point)
+    if point.prune_margin is not None:
         if cid_scores is None:
             raise ValueError("prune_margin needs cid_scores (layer-1 scores)")
-        cids = prune_probes(cids, cid_scores, prune_margin)
-    bank = params.bank
-    if bank.rescore_tier == "host":
-        raise ValueError(
-            "incluster_search cannot complete on a host-tier bank — the "
-            "rescore table is off-device; use search_lider (staged "
-            "fetch->rescore pipeline) or provisional_rows + "
-            "rescore_fetched_rows directly (DESIGN.md §Tiered embedding "
-            "store)"
-        )
-    b, p = cids.shape
-    flat_emb, valid = _bank_candidates(
-        bank, queries, cids, k=k, r0=r0, refine=refine
-    )
-
-    # Verification: gather rows from the flat (c*Lp, d) table (row_ids =
-    # flat_emb), dedup/report by flat row (quantized) or global id (float),
-    # -1 where invalid — tombstoned rows are suppressed here. Scoring
-    # happens in the embedding storage dtype (bf16 stays bf16 on the MXU,
-    # int8 runs int8xint8->int32 + exact rescore) with fp32 accumulation for
-    # a stable top-k ordering.
-    if merge:
-        ids, sc = _verify_bank_rows(
-            bank,
-            flat_emb.reshape(b, -1),
-            valid.reshape(b, -1),
-            queries,
-            k=k,
-            rescore_factor=rescore_factor,
-            block_c=block_c,
-            use_pallas=use_fused,
-            sketch_factor=sketch_factor,
-        )
-        return TopK(ids=ids, scores=sc)
-    # Per-pair top-k: flatten (query, probe) pairs into the batch axis so the
-    # same kernel covers the shape the distributed path scatters back.
-    pair_q = jnp.broadcast_to(queries[:, None, :], (b, p, queries.shape[-1]))
-    ids, sc = _verify_bank_rows(
-        bank,
-        flat_emb.reshape(b * p, -1),
-        valid.reshape(b * p, -1),
-        pair_q.reshape(b * p, -1),
-        k=k,
-        rescore_factor=rescore_factor,
-        block_c=block_c,
-        use_pallas=use_fused,
-        sketch_factor=sketch_factor,
-    )
-    return TopK(ids=ids.reshape(b, p, k), scores=sc.reshape(b, p, k))
+        cids = prune_probes(cids, cid_scores, point.prune_margin)
+    return _incluster(params, queries, cids, k=k, point=point, merge=merge)
 
 
-@partial(
-    jax.jit,
-    static_argnames=(
-        "k", "n_probe", "r0", "r0_centroid", "refine", "use_fused",
-        "with_stats", "rescore_factor", "block_c", "sketch_factor",
-    ),
-)
+@partial(jax.jit, static_argnames=("k", "point", "with_stats"))
 def _search_lider_device(
     params: LiderParams,
     queries: jnp.ndarray,
     *,
     k: int,
-    n_probe: int = 20,
-    r0: int = 4,
-    r0_centroid: int = 4,
-    refine: bool = False,
-    use_fused: bool | None = None,
+    point: OperatingPoint,
     prune_margin: float | None = None,
     with_stats: bool = False,
-    rescore_factor: int = 4,
-    block_c: int | None = None,
-    sketch_factor: int | None = None,
 ) -> TopK | tuple[TopK, jnp.ndarray]:
-    """Single-jit search for device-tier banks (float, or int8 with the
-    rescore table resident next to the codes)."""
-    with jax.named_scope("lider.route"):
-        routed = route_queries(
-            params, queries, n_probe=n_probe, r0=r0_centroid,
-            use_fused=use_fused, block_c=block_c,
-        )
-        cids = prune_probes(routed.ids, routed.scores, prune_margin)
-        pruned = (routed.ids >= 0) & (cids < 0)
-    out = incluster_search(
-        params, queries, cids, k=k, r0=r0, refine=refine,
-        use_fused=use_fused, rescore_factor=rescore_factor, block_c=block_c,
-        sketch_factor=sketch_factor,
+    """Single-jit search for device-tier banks (float, or quantized with
+    the rescore table resident next to the codes)."""
+    cids, pruned = route_probes(
+        params, queries, point=point, prune_margin=prune_margin
     )
+    out = _incluster(params, queries, cids, k=k, point=point)
     if with_stats:
         return out, pruned
     return out
 
 
 # ---------------------------------------------------------------------------
-# Tiered (host-resident rescore table) search: three explicit stages
-# (DESIGN.md §Tiered embedding store)
+# Staged search: compressed first pass, then the exact rescore (DESIGN.md
+# §Tiered embedding store, §Cluster-major schedule)
 # ---------------------------------------------------------------------------
 
 
@@ -657,68 +769,27 @@ def provisional_rows(
     cids: jnp.ndarray,
     *,
     k: int,
-    r0: int = 4,
-    refine: bool = False,
+    point: OperatingPoint,
     merge: bool = True,
-    use_fused: bool | None = None,
-    rescore_factor: int = 4,
-    block_c: int | None = None,
-    sketch_factor: int | None = None,
 ) -> TopK:
-    """Stage 1 of the tiered search: compressed-domain first pass only.
-
-    Same candidate generation and int8 first pass as the device-tier
-    quantized search — deduped by flat bank row, same tie-break — but stops
-    at the provisional top-``k' = rescore_factor*k``: ``ids`` are *flat bank
-    rows* (-1 padding) and ``scores`` are the compressed-domain scores.
-    Dead candidates are masked by the liveness read off ``sorted_pos``; no
-    gid is read here. The caller fetches those rows from the host tier
-    (``bank.store.fetch``) and finishes with :func:`rescore_fetched_rows` /
-    :func:`host_rescore`, which map only the final rows to gids.
-    ``merge=False`` keeps the per-(query, probe) pair shape for the
-    distributed capacity-dispatch path.
+    """Stage 1 of the tiered search: the compressed first pass only
+    (:func:`_compressed_pass`), stopping at the provisional top-``k'``:
+    ``ids`` are *flat bank rows* (-1 padding), ``scores`` the
+    compressed-domain scores. The caller fetches those rows from the host
+    tier (``bank.store.fetch``) and finishes with
+    :func:`rescore_fetched_rows` / :func:`host_rescore`, which map only the
+    final rows to gids. ``merge=False`` keeps the per-(query, probe) pair
+    shape for the distributed capacity-dispatch path.
     """
     bank = params.bank
     if not bank.quantized:
         raise ValueError("provisional_rows needs a quantized (int8/int4) bank")
-    b, p = cids.shape
-    flat_emb, valid = _bank_candidates(
-        bank, queries, cids, k=k, r0=r0, refine=refine
+    rows, live, q = _candidate_rows(
+        bank, queries, cids, k=k, point=point, merge=merge
     )
-    c, lp = bank.gids.shape
-    if merge:
-        fr = flat_emb.reshape(b, -1)
-        ok = valid.reshape(b, -1)
-        q = queries
-    else:
-        pair_q = jnp.broadcast_to(queries[:, None, :], (b, p, queries.shape[-1]))
-        fr = flat_emb.reshape(b * p, -1)
-        ok = valid.reshape(b * p, -1)
-        q = pair_q.reshape(b * p, -1)
-    with jax.named_scope("lider.candidates"):
-        out_rows = jnp.where(ok, fr, -1)
-    kp = min(max(rescore_factor, 1) * k, fr.shape[-1])
-    if sketch_factor is not None and bank.sketches is not None:
-        # Sketch pre-filter, same contract as the device-tier funnel
-        # (_verify_bank_rows): survivors replace the candidate list so the
-        # code pass below streams sketch_factor*k' rows instead of all C.
-        m = min(max(sketch_factor, 1) * kp, fr.shape[-1])
-        with jax.named_scope("lider.sketch"):
-            surv, _ = sketch_topk_op(
-                bank.sketches.reshape(c * lp, -1), fr, q, k=m,
-                out_ids=out_rows, block_c=block_c, use_pallas=use_fused,
-            )
-            fr = jnp.maximum(surv, 0)
-        out_rows = surv
-    with jax.named_scope("lider.code_pass"):
-        rows, sc = verify_topk_op(
-            bank.embs.reshape(c * lp, -1), fr, q, k=kp, out_ids=out_rows,
-            scales=bank.emb_scales.reshape(-1), block_c=block_c,
-            code_dtype=bank.code_dtype, use_pallas=use_fused,
-        )
-    if not merge:
-        return TopK(ids=rows.reshape(b, p, kp), scores=sc.reshape(b, p, kp))
-    return TopK(ids=rows, scores=sc)
+    return _per_pair(
+        _compressed_pass(bank, rows, live, q, k=k, point=point), cids, merge
+    )
 
 
 def rescore_fetched_rows(
@@ -727,48 +798,34 @@ def rescore_fetched_rows(
     queries: jnp.ndarray,
     *,
     k: int,
-    use_fused: bool | None = None,
-    block_c: int | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+    point: OperatingPoint,
+    gids: jnp.ndarray | None = None,
+) -> TopK:
     """Stage 3 of the tiered search: exact rescore over host-fetched rows.
 
     ``fetched``: (B, k', d) full-precision rows (the H2D payload — only
-    ``B*k'*d`` floats); ``out_ids``: (B, k') the ids to dedup/report by
-    (flat bank rows on the single-device path — the device-tier tie-break —
-    or global ids on the distributed path). Runs the *same* fused kernel as
-    the device-tier rescore with the fetched block as its table, so scores
-    and tie-breaks are bit-identical to scoring against the resident table.
+    ``B*k'*d`` floats); ``out_ids``: (B, k') the ids to dedup/report by:
+    flat bank rows on the single-device path (the device-tier tie-break,
+    mapped through ``gids``) or global ids on the distributed path
+    (``gids=None``). The fetched block is the table of
+    :func:`rescore_rows`.
     """
     b, kp, d = fetched.shape
     table = fetched.reshape(b * kp, d)
     row_ids = jnp.arange(b * kp, dtype=jnp.int32).reshape(b, kp)
-    return verify_topk_op(
-        table, row_ids, queries, k=k, out_ids=out_ids,
-        block_c=block_c, use_pallas=use_fused,
+    return rescore_rows(
+        table, row_ids, out_ids, queries, k=k, point=point, gids=gids
     )
 
 
-@partial(
-    jax.jit,
-    static_argnames=(
-        "k", "n_probe", "r0", "r0_centroid", "refine", "use_fused",
-        "rescore_factor", "block_c", "sketch_factor",
-    ),
-)
+@partial(jax.jit, static_argnames=("k", "point"))
 def host_first_pass(
     params: LiderParams,
     queries: jnp.ndarray,
     *,
     k: int,
-    n_probe: int = 20,
-    r0: int = 4,
-    r0_centroid: int = 4,
-    refine: bool = False,
-    use_fused: bool | None = None,
+    point: OperatingPoint,
     prune_margin: float | None = None,
-    rescore_factor: int = 4,
-    block_c: int | None = None,
-    sketch_factor: int | None = None,
 ) -> tuple[TopK, jnp.ndarray]:
     """Jit'd stage 1+2a of the tiered search: route + prune + compressed
     first pass. Returns ``(prov, pruned_mask (B, n_probe))`` where ``prov``
@@ -778,19 +835,10 @@ def host_first_pass(
     the serving engine). The provisional scores ride along so a degraded
     engine can answer compressed-only (:func:`compressed_only_topk`) when
     the host fetch is unavailable."""
-    with jax.named_scope("lider.route"):
-        routed = route_queries(
-            params, queries, n_probe=n_probe, r0=r0_centroid,
-            use_fused=use_fused, block_c=block_c,
-        )
-        cids = prune_probes(routed.ids, routed.scores, prune_margin)
-        pruned = (routed.ids >= 0) & (cids < 0)
-    prov = provisional_rows(
-        params, queries, cids, k=k, r0=r0, refine=refine, use_fused=use_fused,
-        rescore_factor=rescore_factor, block_c=block_c,
-        sketch_factor=sketch_factor,
+    cids, pruned = route_probes(
+        params, queries, point=point, prune_margin=prune_margin
     )
-    return prov, pruned
+    return provisional_rows(params, queries, cids, k=k, point=point), pruned
 
 
 def host_fetch(params: LiderParams, prov_rows) -> np.ndarray:
@@ -802,7 +850,7 @@ def host_fetch(params: LiderParams, prov_rows) -> np.ndarray:
     return params.bank.store.fetch(np.asarray(prov_rows))
 
 
-@partial(jax.jit, static_argnames=("k", "use_fused", "block_c"))
+@partial(jax.jit, static_argnames=("k", "point"))
 @jax.named_scope("lider.rescore")
 def host_rescore(
     gids: jnp.ndarray,
@@ -811,19 +859,16 @@ def host_rescore(
     queries: jnp.ndarray,
     *,
     k: int,
-    use_fused: bool | None = None,
-    block_c: int | None = None,
+    point: OperatingPoint,
 ) -> TopK:
     """Jit'd stage 3: exact rescore of the fetched rows + row->gid mapping.
 
     Dedup/tie-break by flat bank row — identical to the device-tier
     quantized path — then the surviving rows map to global ids through the
     bank's ``gids`` table (a device-resident (c, Lp) int32 leaf)."""
-    rows, scores = rescore_fetched_rows(
-        fetched, prov_rows, queries, k=k, use_fused=use_fused, block_c=block_c
+    return rescore_fetched_rows(
+        fetched, prov_rows, queries, k=k, point=point, gids=gids
     )
-    ids = jnp.where(rows >= 0, gids.reshape(-1)[jnp.maximum(rows, 0)], -1)
-    return TopK(ids=ids, scores=scores)
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -839,48 +884,17 @@ def compressed_only_topk(
     degradation ladder's last rung (DESIGN.md §Failure model)."""
     rows = prov.ids[..., :k]
     scores = prov.scores[..., :k]
-    ids = jnp.where(rows >= 0, gids.reshape(-1)[jnp.maximum(rows, 0)], -1)
-    return TopK(ids=ids, scores=scores)
+    return TopK(ids=row_gids(gids, rows), scores=scores)
 
 
-# ---------------------------------------------------------------------------
-# Cluster-major multi-query search (DESIGN.md §Cluster-major schedule)
-# ---------------------------------------------------------------------------
+# The cluster-major schedule's device stages (DESIGN.md §Cluster-major
+# schedule): routing and the device-tier rescore as jits of their own, the
+# host schedule pre-pass running between them and the grouped first pass.
+_route_pruned = jax.jit(route_probes, static_argnames=("point",))
+_rescore_provisional = jax.jit(_rescore_resident, static_argnames=("k", "point"))
 
 
-@partial(
-    jax.jit,
-    static_argnames=("n_probe", "r0_centroid", "use_fused", "block_c"),
-)
-def _route_pruned(
-    params: LiderParams,
-    queries: jnp.ndarray,
-    *,
-    n_probe: int,
-    r0_centroid: int = 4,
-    use_fused: bool | None = None,
-    prune_margin: float | None = None,
-    block_c: int | None = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Jit'd routing stage of the cluster-major search: layer-1 route +
-    margin prune. Returns ``(cids (B, P), pruned_mask (B, P))`` — the probe
-    lists the host schedule pre-pass groups by cluster."""
-    routed = route_queries(
-        params, queries, n_probe=n_probe, r0=r0_centroid, use_fused=use_fused,
-        block_c=block_c,
-    )
-    cids = prune_probes(routed.ids, routed.scores, prune_margin)
-    pruned = (routed.ids >= 0) & (cids < 0)
-    return cids, pruned
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "k", "r0", "refine", "use_fused", "rescore_factor", "block_c",
-        "block_q", "sketch_factor",
-    ),
-)
+@partial(jax.jit, static_argnames=("k", "point"))
 def _cluster_major_first_pass(
     params: LiderParams,
     queries: jnp.ndarray,
@@ -891,13 +905,7 @@ def _cluster_major_first_pass(
     pair_slot: jnp.ndarray,
     *,
     k: int,
-    r0: int = 4,
-    refine: bool = False,
-    use_fused: bool | None = None,
-    rescore_factor: int = 4,
-    block_c: int | None = None,
-    block_q: int = 8,
-    sketch_factor: int | None = None,
+    point: OperatingPoint,
 ) -> TopK:
     """Jit'd compressed first pass on the cluster-major schedule.
 
@@ -913,34 +921,29 @@ def _cluster_major_first_pass(
     shared — tests/test_fused_verify.py gates this).
     """
     bank = params.bank
+    block_q = point.block_q
     b, p = cids.shape
     c, lp = bank.gids.shape
     flat_emb, valid = _bank_candidates(
-        bank, queries, cids, k=k, r0=r0, refine=refine
+        bank, queries, cids, k=k, r0=point.r0, refine=point.refine
     )
     out_rows = jnp.where(valid, flat_emb, -1)  # (B, P, H, R)
     s_steps = sched_cids.shape[0]
     n_cand = p * flat_emb.shape[2] * flat_emb.shape[3]
-    kp = min(max(rescore_factor, 1) * k, n_cand)
+    kp = min(max(point.rescore_factor, 1) * k, n_cand)
 
-    if sketch_factor is not None and bank.sketches is not None:
-        # Sketch pre-filter on the cluster-major path: the per-query Hamming
-        # pass sees the SAME merged candidate list as the per-query funnel
-        # (_verify_bank_rows), so it selects the same survivors — then the
-        # per-(step, slot) candidate mask is rebuilt from survivors only.
-        # Each survivor maps back to its (query, probe) pair through its
-        # cluster id (flat row // Lp; probe lists hold distinct clusters),
-        # and from there to the pair's (step, slot) — so the grouped kernel
-        # streams the same survivor set the per-query filtered pass scores.
-        m = min(max(sketch_factor, 1) * kp, n_cand)
-        surv, _ = sketch_topk_op(
-            bank.sketches.reshape(c * lp, -1),
-            flat_emb.reshape(b, -1),
-            queries,
-            k=m,
-            out_ids=out_rows.reshape(b, -1),
-            block_c=block_c,
-            use_pallas=use_fused,
+    if point.sketch_factor is not None and bank.sketches is not None:
+        # The per-query Hamming pass sees the SAME merged candidate list as
+        # the per-query first pass, so it selects the same survivors — then
+        # the per-(step, slot) candidate mask is rebuilt from survivors
+        # only. Each survivor maps back to its (query, probe) pair through
+        # its cluster id (flat row // Lp; probe lists hold distinct
+        # clusters), and from there to the pair's (step, slot) — so the
+        # grouped kernel streams the same survivor set the per-query
+        # filtered pass scores.
+        surv, _ = _sketch_filter(
+            bank, flat_emb.reshape(b, -1), out_rows.reshape(b, -1), queries,
+            kp=kp, point=point,
         )
         surv_cid = surv // lp  # (B, m); -1 survivors masked below
         match = (cids[:, None, :] == surv_cid[:, :, None]) & (
@@ -988,9 +991,9 @@ def _cluster_major_first_pass(
         step_slot_ids,
         kp=kp_pair,
         block_q=block_q,
-        block_c=block_c,
+        block_c=point.block_c,
         code_dtype=bank.code_dtype,
-        use_pallas=use_fused,
+        use_pallas=point.use_fused,
     )
 
     # Scatter-back: gather each query's pairs' per-cluster top-k' streams
@@ -1010,155 +1013,64 @@ def _cluster_major_first_pass(
     return TopK(ids=prov_rows, scores=prov_sc)
 
 
-@partial(jax.jit, static_argnames=("k", "use_fused", "block_c"))
-def _rescore_provisional(
-    gids: jnp.ndarray,
-    rescore_embs: jnp.ndarray,
-    prov_rows: jnp.ndarray,
-    queries: jnp.ndarray,
-    *,
-    k: int,
-    use_fused: bool | None = None,
-    block_c: int | None = None,
-) -> TopK:
-    """Device-tier exact rescore of a provisional top-k' (the same stage-2
-    math as ``_verify_bank_rows``, split out so the cluster-major first pass
-    can feed it between jits)."""
-    rescore_table = rescore_embs.reshape(-1, rescore_embs.shape[-1])
-    rows, scores = verify_topk_op(
-        rescore_table,
-        jnp.maximum(prov_rows, 0),
-        queries,
-        k=k,
-        out_ids=prov_rows,
-        block_c=block_c,
-        use_pallas=use_fused,
-    )
-    ids = jnp.where(rows >= 0, gids.reshape(-1)[jnp.maximum(rows, 0)], -1)
-    return TopK(ids=ids, scores=scores)
-
-
-def host_first_pass_cluster_major(
+def first_pass(
     params: LiderParams,
     queries: jnp.ndarray,
     *,
     k: int,
-    n_probe: int = 20,
-    r0: int = 4,
-    r0_centroid: int = 4,
-    refine: bool = False,
-    use_fused: bool | None = None,
-    prune_margin: float | None = None,
-    rescore_factor: int = 4,
-    block_c: int | None = None,
-    block_q: int = 8,
-    sketch_factor: int | None = None,
-    stats_out: dict | None = None,
+    point: OperatingPoint,
 ) -> tuple[TopK, jnp.ndarray]:
-    """Cluster-major spelling of :func:`host_first_pass` — same
-    ``(prov, pruned)`` contract, so the serving engine's double-buffered
-    fetch->rescore pipeline works unchanged with ``block_q`` set.
+    """Stage 1 of the staged search at ``point``: route + prune +
+    compressed first pass, returning ``(prov, pruned)`` as
+    :func:`host_first_pass` does; the fetch (host tier) and the exact
+    rescore finish the query. The margin reaches every jit traced.
 
-    Not one jit (the schedule pre-pass is host-side and data-dependent), but
-    both device stages inside it are jits, so stage-1 dispatch still returns
-    before the device finishes and the pipeline's overlap is preserved.
-
-    ``stats_out`` (the online block_q autotuner's hook) does two things:
-    the dict is filled with the drained schedule's measured sharing
-    (``n_pairs``/``n_steps``) plus the batch's per-cluster pair counts, AND
-    the schedule is padded to the fixed worst case ``_pad_pow2(B·n_probe)``
-    instead of the data-dependent power of two — so every batch of the same
-    (B, block_q) hits ONE compiled kernel shape and the autotuner can swap
-    ``block_q`` between drains with zero query-path retraces (padding steps
-    are dead; results unchanged).
+    ``block_q`` set (quantized banks only) runs the cluster-major schedule:
+    routing jit -> host schedule pre-pass (data-dependent, so it cannot
+    live inside one jit) -> grouped first-pass jit. Both device stages are
+    jits, so the call returns before the device finishes and the serving
+    pipeline's overlap is kept. Every schedule is padded to the batch's
+    worst case ``_pad_pow2(B·n_probe)`` steps (padding steps are dead), so
+    each batch size compiles one grouped program and no batch re-traces.
     """
     from ..kernels.schedule import _pad_pow2, build_cluster_schedule
 
-    cids, pruned = _route_pruned(
-        params, queries, n_probe=n_probe, r0_centroid=r0_centroid,
-        use_fused=use_fused, prune_margin=prune_margin, block_c=block_c,
-    )
-    pad_to = None
-    if stats_out is not None:
-        pad_to = _pad_pow2(queries.shape[0] * n_probe)
-    cids_np = np.asarray(jax.device_get(cids))
-    sched = build_cluster_schedule(cids_np, block_q=block_q, pad_to=pad_to)
-    if stats_out is not None:
-        stats_out["n_pairs"] = sched.n_pairs
-        stats_out["n_steps"] = sched.n_steps
-        stats_out["cluster_counts"] = np.unique(
-            cids_np[cids_np >= 0], return_counts=True
-        )[1]
-    prov = _cluster_major_first_pass(
-        params,
-        queries,
-        cids,
-        jnp.asarray(sched.sched_cids),
-        jnp.asarray(sched.sched_qids),
-        jnp.asarray(sched.pair_step),
-        jnp.asarray(sched.pair_slot),
-        k=k,
-        r0=r0,
-        refine=refine,
-        use_fused=use_fused,
-        rescore_factor=rescore_factor,
-        block_c=block_c,
-        block_q=block_q,
-        sketch_factor=sketch_factor,
-    )
-    return prov, pruned
-
-
-def _search_lider_cluster_major(
-    params: LiderParams,
-    queries: jnp.ndarray,
-    *,
-    k: int,
-    n_probe: int,
-    r0: int,
-    r0_centroid: int,
-    refine: bool,
-    use_fused: bool | None,
-    prune_margin: float | None,
-    with_stats: bool,
-    rescore_factor: int,
-    block_c: int | None,
-    block_q: int,
-    sketch_factor: int | None = None,
-) -> TopK | tuple[TopK, jnp.ndarray]:
-    """Staged cluster-major search: route (jit) -> host schedule pre-pass ->
-    grouped first pass (jit) -> exact rescore (tier-appropriate).
-
-    The schedule is data-dependent (it groups the batch's routed probe lists
-    by cluster), so it cannot live inside one jit — the same staging pattern
-    as the host-tier search. Step counts are padded to powers of two, so the
-    grouped kernel's compile count stays O(log batch-pairs).
-    """
-    bank = params.bank
-    if not bank.quantized:
+    margin = point.prune_margin
+    if point.block_q is None:
+        return host_first_pass(
+            params, queries, k=k,
+            point=dataclasses.replace(point, prune_margin=None),
+            prune_margin=margin,
+        )
+    if not params.bank.quantized:
         raise ValueError(
             "block_q (cluster-major schedule) requires a quantized "
             "(int8/int4) bank — the grouped kernel streams code tiles; "
             "use the per-query schedule (block_q=None) for float banks"
         )
-    prov, pruned = host_first_pass_cluster_major(
-        params, queries, k=k, n_probe=n_probe, r0=r0,
-        r0_centroid=r0_centroid, refine=refine, use_fused=use_fused,
-        prune_margin=prune_margin, rescore_factor=rescore_factor,
-        block_c=block_c, block_q=block_q, sketch_factor=sketch_factor,
+    cids, pruned = _route_pruned(
+        params, queries, prune_margin=margin,
+        point=point.only("n_probe", "r0_centroid", "use_fused", "block_c"),
     )
-    if bank.rescore_tier == "host":
-        fetched = host_fetch(params, prov.ids)
-        out = host_rescore(
-            bank.gids, jnp.asarray(fetched), prov.ids, queries, k=k,
-            use_fused=use_fused, block_c=block_c,
-        )
-    else:
-        out = _rescore_provisional(
-            bank.gids, bank.rescore_embs, prov.ids, queries, k=k,
-            use_fused=use_fused, block_c=block_c,
-        )
-    return (out, pruned) if with_stats else out
+    sched = build_cluster_schedule(
+        np.asarray(jax.device_get(cids)), block_q=point.block_q,
+        pad_to=_pad_pow2(queries.shape[0] * point.n_probe),
+    )
+    prov = _cluster_major_first_pass(
+        params,
+        queries,
+        cids,
+        *(jnp.asarray(a) for a in (
+            sched.sched_cids, sched.sched_qids, sched.pair_step,
+            sched.pair_slot,
+        )),
+        k=k,
+        point=point.only(
+            "r0", "refine", "use_fused", "rescore_factor", "block_c",
+            "block_q", "sketch_factor",
+        ),
+    )
+    return prov, pruned
 
 
 def search_lider(
@@ -1166,19 +1078,12 @@ def search_lider(
     queries: jnp.ndarray,
     *,
     k: int,
-    n_probe: int = 20,
-    r0: int = 4,
-    r0_centroid: int = 4,
-    refine: bool = False,
-    use_fused: bool | None = None,
-    prune_margin: float | None = None,
     with_stats: bool = False,
-    rescore_factor: int = 4,
-    block_c: int | None = None,
-    block_q: int | None = None,
-    sketch_factor: int | None = None,
+    **point,
 ) -> TopK | tuple[TopK, jnp.ndarray]:
-    """End-to-end LIDER ANN search (paper Sec. 3.3.2), single device.
+    """End-to-end LIDER ANN search (paper Sec. 3.3.2), single device, at
+    the operating point given by ``point``'s :class:`OperatingPoint` fields
+    (an unknown keyword raises ``TypeError``).
 
     ``prune_margin`` enables adaptive probe pruning (see :func:`prune_probes`;
     traced, so sweeping margins does not recompile; ``None`` is bit-identical
@@ -1186,14 +1091,13 @@ def search_lider(
     (B, n_probe) bool mask of probes that were routed but pruned — serving
     aggregates it into the per-batch pruned-probe fraction.
 
-    On an int8 bank (``LiderConfig.storage_dtype="int8"``) layer-2
-    verification runs compressed-domain first, then exactly rescores the
-    provisional top-``rescore_factor * k``; the knobs are static so each
-    (rescore_factor, block_c) pair is one compile.
+    On a quantized bank (``LiderConfig.storage_dtype`` "int8"/"int4")
+    layer-2 verification runs compressed-domain first, then exactly
+    rescores the provisional top-``rescore_factor * k``.
 
     Tier dispatch (DESIGN.md §Tiered embedding store): on a device-tier bank
     the whole search is one jit. On a *host-tier* bank it runs as three
-    explicit stages — jit'd compressed first pass (:func:`host_first_pass`),
+    explicit stages — jit'd compressed first pass (:func:`first_pass`),
     host-side exact-row fetch (:func:`host_fetch`: ``np.take`` on the
     process-local tier, H2D of only ``B·k'·d`` floats), jit'd fused rescore
     (:func:`host_rescore`) — returning bit-identical (ids, scores) to the
@@ -1211,34 +1115,28 @@ def search_lider(
     covering factor is bit-identical to the unfiltered search; small
     factors trade recall for ~16x less first-pass traffic than int4.
     """
-    if block_q is not None:
-        return _search_lider_cluster_major(
-            params, queries, k=k, n_probe=n_probe, r0=r0,
-            r0_centroid=r0_centroid, refine=refine, use_fused=use_fused,
-            prune_margin=prune_margin, with_stats=with_stats,
-            rescore_factor=rescore_factor, block_c=block_c, block_q=block_q,
-            sketch_factor=sketch_factor,
+    point = OperatingPoint(**point)
+    bank = params.bank
+    if point.block_q is None and bank.rescore_tier == "device":
+        return _search_lider_device(
+            params, queries, k=k,
+            point=dataclasses.replace(point, prune_margin=None),
+            prune_margin=point.prune_margin, with_stats=with_stats,
         )
-    if params.bank.rescore_tier == "host":
-        prov, pruned = host_first_pass(
-            params, queries, k=k, n_probe=n_probe, r0=r0,
-            r0_centroid=r0_centroid, refine=refine, use_fused=use_fused,
-            prune_margin=prune_margin, rescore_factor=rescore_factor,
-            block_c=block_c, sketch_factor=sketch_factor,
-        )
+    prov, pruned = first_pass(params, queries, k=k, point=point)
+    rescore = point.only("use_fused", "block_c")
+    if bank.rescore_tier == "host":
         fetched = host_fetch(params, prov.ids)
         out = host_rescore(
-            params.bank.gids, jnp.asarray(fetched), prov.ids, queries, k=k,
-            use_fused=use_fused, block_c=block_c,
+            bank.gids, jnp.asarray(fetched), prov.ids, queries, k=k,
+            point=rescore,
         )
-        return (out, pruned) if with_stats else out
-    return _search_lider_device(
-        params, queries, k=k, n_probe=n_probe, r0=r0,
-        r0_centroid=r0_centroid, refine=refine, use_fused=use_fused,
-        prune_margin=prune_margin, with_stats=with_stats,
-        rescore_factor=rescore_factor, block_c=block_c,
-        sketch_factor=sketch_factor,
-    )
+    else:
+        out = _rescore_provisional(
+            bank.gids, bank.rescore_embs, prov.ids, queries, k=k,
+            point=rescore,
+        )
+    return (out, pruned) if with_stats else out
 
 
 # Every jit on the serving query path (all tiers + the degraded fallback).

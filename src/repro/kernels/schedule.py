@@ -94,9 +94,9 @@ def build_cluster_schedule(
     spans consecutive steps. Steps are ordered by cluster id ascending.
 
     ``pad_to`` overrides the power-of-two step padding with a FIXED padded
-    step count — the online block_q autotuner passes the worst case
-    ``_pad_pow2(B * P)`` (n_steps <= n_pairs <= B·P always) so every batch
-    of the same (B, block_q) compiles exactly one downstream kernel shape
+    step count — the searches pass a worst case (``_pad_pow2(B * P)`` on
+    one device, n_steps <= n_pairs <= B·P always) so every batch of the
+    same (B, block_q) compiles exactly one downstream kernel shape
     regardless of the observed probe distribution (zero query-path
     retraces). Padding steps are dead (empty tiles, ``blk_live = 0``), so
     results are unchanged. Values below the real step count fall back to

@@ -263,11 +263,8 @@ def main() -> None:
         cfg = lider_lib.LiderConfig(
             n_clusters=args.n_clusters,
             n_probe=args.n_probe,
-            refine=args.refine,
-            use_fused=use_fused,
             storage_dtype=args.storage_dtype,
             rescore_factor=args.rescore_factor,
-            block_c=args.block_c,
             rescore_tier=args.rescore_tier or "device",
         )
         if args.load_index:
@@ -283,9 +280,6 @@ def main() -> None:
                     f"[serve] WARNING: capacity overflow dropped "
                     f"{build_stats.n_dropped} passages at build"
                 )
-        # Config is the single source for the search-time knobs below
-        # (same convention as n_probe/refine).
-        use_fused = cfg.use_fused
     elif args.backend == "pq":
         index = build_pq(jax.random.PRNGKey(0), embs)
     elif args.backend == "ivfpq":
@@ -321,16 +315,16 @@ def main() -> None:
             n_probes=tuple(
                 p for p in (2, 4, 8, 16, 32) if p <= args.n_clusters
             ),
-            refine=args.refine,
             rescore_factors=(args.rescore_factor,),
             block_cs=(args.block_c,),
             block_qs=(args.block_q,),
             sketch_factors=(args.sketch_factor,),
+            refine=args.refine,
+            use_fused=use_fused,
         )
         t0 = time.time()
         results = pareto_lib.sweep(
             index, held_q, held_gt.ids, grid, k=args.k, repeats=2,
-            use_fused=use_fused,
         )
         sel = pareto_lib.select_operating_point(results, args.recall_target)
         n_probe, prune_margin = sel.point.n_probe, sel.point.prune_margin

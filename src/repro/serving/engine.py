@@ -24,7 +24,6 @@ import collections
 import dataclasses
 import random
 import time
-from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -92,13 +91,6 @@ class EngineStats:
     recent_latency_s: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=1024)
     )
-    # Cluster-major schedule accounting (DESIGN.md §Cluster-major schedule):
-    # scheduled (query, probe) pairs vs the grouped-kernel steps that served
-    # them. pairs/steps is the measured DMA-sharing ratio — the signal the
-    # online block_q autotuner feeds on. Aggregates in counters, recent
-    # per-batch ratios in a bounded deque, same policy as above.
-    n_sched_pairs: int = 0
-    n_sched_steps: int = 0
     # Per-batch spans (serving/spans.py): host-to-device transfers (query
     # batches, host-tier fetched rows) and the answers' device-to-host
     # conversion. Per request, summed at the batch boundary: queue wait
@@ -142,13 +134,6 @@ class EngineStats:
     def pruned_probe_fraction(self) -> float:
         """Fraction of routed probes the margin rule pruned (all batches)."""
         return self.n_probes_pruned / max(self.n_probes_total, 1)
-
-    @property
-    def sharing_ratio(self) -> float:
-        """Measured cluster-tile DMA sharing across all cluster-major
-        batches: scheduled pairs per grouped-kernel step (>= 1; 1.0 means
-        no two queries in a batch ever probed the same cluster)."""
-        return self.n_sched_pairs / max(self.n_sched_steps, 1)
 
 
 class QueryResult:
@@ -246,23 +231,14 @@ class _PendingBatch:
     blocked: bool = False
 
 
-# Operating-point knobs a degradation-ladder rung may override (the PR-3
-# control-plane axes; anything else in a rung dict — e.g. the modeled
-# ``expected_recall`` floor — is bench/report metadata the engine ignores).
-_POINT_KEYS = frozenset(
-    {
-        "n_probe", "r0", "prune_margin", "refine", "rescore_factor",
-        "block_c", "block_q", "sketch_factor",
-    }
-)
-
-
 @dataclasses.dataclass(frozen=True)
 class DegradePolicy:
     """Fault-tolerance policy for :class:`RetrievalEngine`.
 
     ``ladder`` is a sequence of operating-point override dicts (cheapest
-    last), typically from ``tuning.pareto.degradation_ladder``; under
+    last; keys other than ``lider.OperatingPoint`` fields, such as the
+    modeled ``expected_recall`` floor, are report metadata the engine
+    ignores), typically from ``tuning.pareto.degradation_ladder``; under
     deadline pressure or repeated host-fetch failure the engine steps down
     one rung at a time, and past the last rung (or when a batch's fetch
     exhausts its retries) answers compressed-only with ``degraded=True``.
@@ -284,13 +260,14 @@ class DegradePolicy:
 
 
 # Searchable knobs each backend accepts; anything else in **kw is a typo and
-# raises instead of being silently ignored. All probing backends take the
-# same ``n_probe`` spelling (mplsh's search fn calls it n_probes internally).
+# raises instead of being silently ignored. LIDER takes the fields of its
+# ``OperatingPoint``. All probing backends take the same ``n_probe``
+# spelling (mplsh's search fn calls it n_probes internally).
+_POINT_FIELDS = frozenset(
+    f.name for f in dataclasses.fields(lider_lib.OperatingPoint)
+)
 _BACKEND_KWARGS: dict[str, frozenset[str]] = {
-    "lider": frozenset({
-        "n_probe", "r0", "refine", "use_fused", "prune_margin",
-        "rescore_factor", "block_c", "block_q", "sketch_factor",
-    }),
+    "lider": _POINT_FIELDS,
     "flat": frozenset(),
     "pq": frozenset(),
     "ivfpq": frozenset({"n_probe"}),
@@ -329,43 +306,24 @@ def make_backend(
         raise ValueError(f"updatable backends require kind='lider', got {kind!r}")
 
     if kind == "lider":
+        base = lider_lib.OperatingPoint(**kw)
 
-        def _effective(point):
-            # A degradation-ladder rung overrides the base operating point
-            # (n_probe / prune_margin / rescore_factor / ...); the nominal
-            # path (point=None) is byte-for-byte the base kwargs.
-            if not point:
-                return kw
-            eff = dict(kw)
-            eff.update(point)
-            return eff
+        def _at(rung):
+            # A degradation-ladder rung overrides fields of the base point;
+            # the nominal path (rung=None) serves the base point itself.
+            return dataclasses.replace(base, **rung) if rung else base
 
         def lider_search(params, q, k, point=None):
             # With pruning on, the search also returns the (B, P) bool mask
             # of routed-but-pruned probes; the engine folds it into
             # EngineStats (per-batch pruned-probe fraction).
-            eff = _effective(point)
-            margin = eff.get("prune_margin")
+            at = _at(point)
             return lider_lib.search_lider(
-                params,
-                q,
-                k=k,
-                n_probe=eff.get("n_probe", 20),
-                r0=eff.get("r0", 4),
-                refine=eff.get("refine", False),
-                use_fused=eff.get("use_fused"),
-                prune_margin=margin,
-                with_stats=margin is not None,
-                rescore_factor=eff.get("rescore_factor", 4),
-                block_c=eff.get("block_c"),
-                block_q=eff.get("block_q"),
-                sketch_factor=eff.get("sketch_factor"),
+                params, q, k=k, with_stats=at.prune_margin is not None,
+                **dataclasses.asdict(at),
             )
 
         lider_search.accepts_point = True
-        # The engine's block_q autotuner consults this: an explicit static
-        # block_q in the backend kwargs overrides the auto choice.
-        lider_search.static_point = kw
 
         if updatable:
             # Staged spelling of the same operating point, for host-tier
@@ -373,53 +331,19 @@ def make_backend(
             # batch i+1 over batch i's host fetch + rescore (DESIGN.md
             # §Tiered embedding store). search_lider composes the identical
             # stages serially, so results match the unpipelined call.
-            def host_stage1(params, q, k, point=None, stats_out=None):
-                eff = _effective(point)
-                margin = eff.get("prune_margin")
-                block_q = eff.get("block_q")
-                # block_q flips stage 1 to the cluster-major spelling; the
-                # (prov, pruned) contract — and therefore the fetch/rescore
-                # pipeline downstream — is identical. ``stats_out`` (the
-                # online block_q autotuner's hook) only applies there: it
-                # returns the drained schedule's measured sharing and flips
-                # the schedule to worst-case fixed-shape padding so swapping
-                # block_q between drains never re-traces (see
-                # host_first_pass_cluster_major).
-                stage1_fn = (
-                    lider_lib.host_first_pass
-                    if block_q is None
-                    else partial(
-                        lider_lib.host_first_pass_cluster_major,
-                        block_q=block_q,
-                        stats_out=stats_out,
-                    )
-                )
-                prov, pruned = stage1_fn(
-                    params,
-                    q,
-                    k=k,
-                    n_probe=eff.get("n_probe", 20),
-                    r0=eff.get("r0", 4),
-                    refine=eff.get("refine", False),
-                    use_fused=eff.get("use_fused"),
-                    prune_margin=margin,
-                    rescore_factor=eff.get("rescore_factor", 4),
-                    block_c=eff.get("block_c"),
-                    sketch_factor=eff.get("sketch_factor"),
-                )
+            def host_stage1(params, q, k, point=None):
+                at = _at(point)
+                prov, pruned = lider_lib.first_pass(params, q, k=k, point=at)
                 # Same contract as the serial path: probe stats only when
                 # the margin rule is actually configured.
-                return prov, (pruned if margin is not None else None)
+                return prov, (pruned if at.prune_margin is not None else None)
+
+            rescore = base.only("use_fused", "block_c")
 
             def host_stage2(params, fetched, prov_rows, q, k):
                 return lider_lib.host_rescore(
-                    params.bank.gids,
-                    fetched,
-                    prov_rows,
-                    q,
-                    k=k,
-                    use_fused=kw.get("use_fused"),
-                    block_c=kw.get("block_c"),
+                    params.bank.gids, fetched, prov_rows, q, k=k,
+                    point=rescore,
                 )
 
             lider_search.host_stage1 = host_stage1
@@ -449,38 +373,6 @@ def make_backend(
     return search
 
 
-# Relative weight of one grouped-kernel step's cluster-tile DMA vs one
-# query slot's MXU work in the block_q cost model below. A step always
-# streams the cluster's Lp rows once (the DMA term) and scores block_q query
-# slots whether or not they are filled (the slot term) — so the model is
-# cost(bq) = steps(bq) · (DMA_WEIGHT + bq), with steps(bq) =
-# Σ_clusters ceil(pairs_c / bq) computed exactly from observed probe counts.
-DMA_WEIGHT = 4.0
-
-
-def pick_block_q(counts_list, ladder) -> int:
-    """Pick the cheapest ``block_q`` from ``ladder`` for the observed probe
-    distribution (online autotuning, DESIGN.md §Cluster-major schedule).
-
-    ``counts_list``: iterable of per-batch cluster pair-count arrays (how
-    many (query, probe) pairs landed on each probed cluster — the engine
-    keeps a bounded window of these). Steps are additive across batches, so
-    the exact step count each candidate ``block_q`` *would have* taken on
-    the window is ``Σ ceil(count / bq)`` — no schedule rebuild needed. A
-    wide ``block_q`` shares more DMA but pads more dead query slots on
-    sparse clusters; the cost model weighs both. Empty window -> first rung.
-    """
-    counts = [np.asarray(c, np.int64) for c in counts_list if len(c)]
-    allc = np.concatenate(counts) if counts else np.zeros((0,), np.int64)
-    best_bq, best_cost = ladder[0], float("inf")
-    for bq in ladder:
-        steps = int(np.sum(-(-allc // bq))) if allc.size else 0
-        cost = steps * (DMA_WEIGHT + bq)
-        if cost < best_cost:
-            best_bq, best_cost = int(bq), cost
-    return best_bq
-
-
 class RetrievalEngine:
     """Batched serving with scheduled admission and AQT accounting.
 
@@ -508,7 +400,6 @@ class RetrievalEngine:
         policy: DegradePolicy | None = None,
         fault_plan=None,
         scheduler: SchedulerConfig | None = None,
-        block_q_ladder: tuple | None = None,
     ):
         self.search_fn = search_fn
         self.batch_size = batch_size
@@ -540,27 +431,6 @@ class RetrievalEngine:
         # How many stage1-dispatched batches the host-tier pipeline keeps in
         # flight (2 = the PR 5 double buffer).
         self._pipeline_depth = 2
-        # Online block_q autotuning (DESIGN.md §Cluster-major schedule):
-        # with a ladder set (staged host-tier serving only), each dispatch
-        # runs the cluster-major first pass at the current auto choice with
-        # worst-case fixed-shape schedule padding, the drained schedule's
-        # measured probe distribution lands in ``_probe_counts``, and
-        # ``pick_block_q`` re-picks for the next dispatch. A static
-        # ``block_q`` in the backend kwargs or a ladder rung overrides the
-        # auto choice (the merge order in ``_effective_point``). Every
-        # (batch size, rung, ladder block_q) trace is pre-compiled by
-        # ``warmup`` so re-picks never re-trace on the query path.
-        self.block_q_ladder = (
-            tuple(int(b) for b in block_q_ladder)
-            if block_q_ladder is not None
-            else None
-        )
-        if self.block_q_ladder is not None and not self.block_q_ladder:
-            raise ValueError("block_q_ladder must be non-empty or None")
-        self._auto_block_q = (
-            self.block_q_ladder[0] if self.block_q_ladder else None
-        )
-        self._probe_counts: collections.deque = collections.deque(maxlen=32)
         # Bounded FIFO of answered (ids, scores) pairs. ``result()`` pops by
         # default, so a well-behaved client keeps this near-empty; the bound
         # is the backstop for clients that never collect (a long-running
@@ -595,25 +465,7 @@ class RetrievalEngine:
         if self.rung <= 0 or not ladder or not self._accepts_point:
             return None
         raw = ladder[min(self.rung, len(ladder)) - 1]
-        return {k: v for k, v in raw.items() if k in _POINT_KEYS}
-
-    def _effective_point(self) -> dict | None:
-        """Rung point merged with the autotuner's current block_q choice.
-
-        Precedence (most specific wins): ladder rung > static backend
-        ``block_q`` kwarg > autotuned choice — the static flag stays a
-        hard override, and a rung that pins block_q pins it."""
-        point = self._rung_point()
-        auto = self._auto_block_q
-        if auto is None:
-            return point
-        static = getattr(self.search_fn, "static_point", None) or {}
-        if static.get("block_q") is not None:
-            return point
-        merged = {"block_q": auto}
-        if point:
-            merged.update(point)
-        return merged
+        return {k: v for k, v in raw.items() if k in _POINT_FIELDS}
 
     def _search(self, q: jnp.ndarray):
         point = self._rung_point()
@@ -658,35 +510,18 @@ class RetrievalEngine:
                         # search warmed above. Warm them here too — outside
                         # any faults.activate window, so chaos-plan call
                         # counters are untouched — or the first live
-                        # dispatch pays the trace on the query path. With
-                        # the block_q autotuner on, warm EVERY ladder
-                        # choice (each is one fixed-shape trace per batch
-                        # size thanks to the worst-case schedule padding)
-                        # so online re-picks never re-trace.
-                        saved_auto = self._auto_block_q
-                        bqs = (
-                            list(self.block_q_ladder)
-                            if saved_auto is not None
-                            else [None]
+                        # dispatch pays the trace on the query path.
+                        prov, _ = self.search_fn.host_stage1(
+                            self.params, q, self.k, point=self._rung_point()
                         )
-                        for bq in bqs:
-                            self._auto_block_q = bq
-                            extra = (
-                                {"stats_out": {}} if bq is not None else {}
-                            )
-                            prov, _ = self.search_fn.host_stage1(
-                                self.params, q, self.k,
-                                point=self._effective_point(), **extra,
-                            )
-                            fetched = self.search_fn.host_fetch(
-                                self.params, prov.ids
-                            )
-                            out2 = self.search_fn.host_stage2(
-                                self.params, jnp.asarray(fetched), prov.ids,
-                                q, self.k,
-                            )
-                            jax.block_until_ready(out2.ids)
-                        self._auto_block_q = saved_auto
+                        fetched = self.search_fn.host_fetch(
+                            self.params, prov.ids
+                        )
+                        out2 = self.search_fn.host_stage2(
+                            self.params, jnp.asarray(fetched), prov.ids, q,
+                            self.k,
+                        )
+                        jax.block_until_ready(out2.ids)
         finally:
             self.rung = saved
 
@@ -1168,30 +1003,11 @@ class RetrievalEngine:
         before the batch completes."""
         bs, seq, t_disp = self._next_batch(chunk)
         q = self._device_batch(chunk, bs, seq)
-        stats_out = {} if self._auto_block_q is not None else None
         with self._span("engine.dispatch", seq) as disp:
-            if stats_out is not None:
-                prov, pruned = self.search_fn.host_stage1(
-                    self.params, q, self.k, point=self._effective_point(),
-                    stats_out=stats_out,
-                )
-            else:
-                prov, pruned = self.search_fn.host_stage1(
-                    self.params, q, self.k, point=self._rung_point()
-                )
-        self.scheduler.observe_service(bs, disp.s)
-        if stats_out:
-            # Feed the drained schedule's measured sharing into the stats
-            # and re-pick block_q for the NEXT dispatch from the bounded
-            # window of observed probe distributions. The pick is pure host
-            # arithmetic over small count arrays; every ladder choice was
-            # pre-warmed, so swapping costs zero query-path retraces.
-            self.stats.n_sched_pairs += stats_out["n_pairs"]
-            self.stats.n_sched_steps += stats_out["n_steps"]
-            self._probe_counts.append(stats_out["cluster_counts"])
-            self._auto_block_q = pick_block_q(
-                self._probe_counts, self.block_q_ladder
+            prov, pruned = self.search_fn.host_stage1(
+                self.params, q, self.k, point=self._rung_point()
             )
+        self.scheduler.observe_service(bs, disp.s)
         return _PendingBatch(
             chunk=chunk, bs=bs, q=q, prov=prov, pruned=pruned, rung=self.rung,
             seq=seq, t_disp=t_disp,

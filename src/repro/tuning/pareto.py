@@ -49,65 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import lider as lider_lib
+from ..core.lider import OperatingPoint
 from ..core.utils import mrr_at_10, recall_at_k
-
-
-@dataclasses.dataclass(frozen=True)
-class OperatingPoint:
-    """One point of the speed-quality control plane.
-
-    ``rescore_factor`` only affects quantized (int8/int4) indexes
-    (k' = factor * k provisional candidates exactly rescored); ``block_c``
-    is the verification kernel's candidate block size (None -> kernel
-    default); ``block_q`` switches the first pass to the cluster-major
-    multi-query schedule with that many query slots per cluster tile
-    (None -> per-query schedule; quantized banks only); ``sketch_factor``
-    turns on the 1-bit Hamming pre-filter keeping ``sketch_factor * k'``
-    survivors ahead of the code pass (None -> no pre-filter; quantized
-    banks only — DESIGN.md §Binary sketch tier). All are static search
-    knobs, so each distinct combo is one compile.
-    """
-
-    n_probe: int
-    r0: int = 4
-    prune_margin: float | None = None
-    refine: bool = False
-    rescore_factor: int = 4
-    block_c: int | None = None
-    block_q: int | None = None
-    sketch_factor: int | None = None
-
-    @property
-    def adaptive(self) -> bool:
-        return self.prune_margin is not None
-
-    def search_kwargs(self) -> dict:
-        return dict(
-            n_probe=self.n_probe,
-            r0=self.r0,
-            refine=self.refine,
-            prune_margin=self.prune_margin,
-            rescore_factor=self.rescore_factor,
-            block_c=self.block_c,
-            block_q=self.block_q,
-            sketch_factor=self.sketch_factor,
-        )
-
-    def label(self) -> str:
-        tag = f"probe{self.n_probe}/r{self.r0}"
-        if self.refine:
-            tag += "/refine"
-        if self.adaptive:
-            tag += f"/margin{self.prune_margin:g}"
-        if self.rescore_factor != 4:
-            tag += f"/rescore{self.rescore_factor}"
-        if self.block_c is not None:
-            tag += f"/blk{self.block_c}"
-        if self.block_q is not None:
-            tag += f"/bq{self.block_q}"
-        if self.sketch_factor is not None:
-            tag += f"/sk{self.sketch_factor}"
-        return tag
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,12 +80,11 @@ class SweepResult:
 def default_grid(
     n_probes: Sequence[int] = (2, 5, 10, 20, 40),
     margins: Sequence[float] = (0.02, 0.05, 0.1, 0.2),
-    r0: int = 4,
-    refine: bool = False,
     rescore_factors: Sequence[int] = (4,),
     block_cs: Sequence[int | None] = (None,),
     block_qs: Sequence[int | None] = (None,),
     sketch_factors: Sequence[int | None] = (None,),
+    **held,
 ) -> list[OperatingPoint]:
     """Fixed baselines (margin=None) plus adaptive variants per n_probe.
 
@@ -150,10 +92,18 @@ def default_grid(
     the sweep over the quantized bank's rescore depth, the kernel block
     size, the cluster-major query-tile width, and the 1-bit pre-filter's
     survivor multiple (defaults keep the grid size unchanged); every
-    (n_probe, margin) combo is crossed with them.
+    (n_probe, margin) combo is crossed with them. ``held`` holds further
+    :class:`OperatingPoint` fields at one value over the whole grid (e.g.
+    ``r0``, ``refine``, ``use_fused``).
     """
+    def point(p, m, rf, bc, bq, sf):
+        return OperatingPoint(
+            n_probe=p, prune_margin=m, rescore_factor=rf, block_c=bc,
+            block_q=bq, sketch_factor=sf, **held,
+        )
+
     fixed = [
-        OperatingPoint(p, r0, None, refine, rf, bc, bq, sf)
+        point(p, None, rf, bc, bq, sf)
         for p in n_probes
         for rf in rescore_factors
         for bc in block_cs
@@ -161,7 +111,7 @@ def default_grid(
         for sf in sketch_factors
     ]
     adaptive = [
-        OperatingPoint(p, r0, m, refine, rf, bc, bq, sf)
+        point(p, m, rf, bc, bq, sf)
         for p in n_probes
         if p > 1  # pruning a single probe can only be a no-op
         for m in margins
@@ -199,7 +149,6 @@ def sweep(
     k: int,
     relevant: jnp.ndarray | None = None,
     repeats: int = 3,
-    use_fused: bool | None = None,
 ) -> list[SweepResult]:
     """Measure every operating point on the held-out ``queries``.
 
@@ -223,15 +172,13 @@ def sweep(
         if base_key not in base_walls:
             route = jax.jit(
                 lambda q, p=point: lider_lib.route_queries(
-                    params, q, n_probe=p.n_probe, use_fused=use_fused,
+                    params, q, n_probe=p.n_probe, use_fused=p.use_fused,
                     block_c=p.block_c,
                 )
             )
             full = lambda q, p=point: lider_lib.search_lider(
-                params, q, k=k, n_probe=p.n_probe, r0=p.r0, refine=p.refine,
-                use_fused=use_fused, rescore_factor=p.rescore_factor,
-                block_c=p.block_c, block_q=p.block_q,
-                sketch_factor=p.sketch_factor,
+                params, q, k=k,
+                **dataclasses.asdict(dataclasses.replace(p, prune_margin=None)),
             )
             base_walls[base_key] = (
                 _time_fn(route, queries, repeats),
@@ -241,8 +188,7 @@ def sweep(
 
         def run(q, p=point):
             return lider_lib.search_lider(
-                params, q, k=k, use_fused=use_fused, with_stats=True,
-                **p.search_kwargs(),
+                params, q, k=k, with_stats=True, **dataclasses.asdict(p)
             )
         out, pruned = run(queries)
         pruned_frac = float(np.asarray(pruned).mean())
@@ -268,22 +214,10 @@ def sweep(
                 point.block_q, point.sketch_factor,
             )
             if fetch_key not in host_fetch_walls:
-                stage1_kwargs = dict(
-                    k=k, n_probe=point.n_probe, r0=point.r0,
-                    refine=point.refine, use_fused=use_fused,
-                    rescore_factor=point.rescore_factor,
-                    block_c=point.block_c,
-                    sketch_factor=point.sketch_factor,
+                prov, _ = lider_lib.first_pass(
+                    params, queries, k=k,
+                    point=dataclasses.replace(point, prune_margin=None),
                 )
-                if point.block_q is None:
-                    prov, _ = lider_lib.host_first_pass(
-                        params, queries, **stage1_kwargs
-                    )
-                else:
-                    prov, _ = lider_lib.host_first_pass_cluster_major(
-                        params, queries, block_q=point.block_q,
-                        **stage1_kwargs,
-                    )
                 t0 = time.perf_counter()
                 for _ in range(repeats):
                     lider_lib.host_fetch(params, prov.ids)
@@ -400,7 +334,10 @@ def degradation_ladder(
         cheaper = [cheaper[i] for i in dict.fromkeys(idx.tolist())]
     rungs = []
     for r in cheaper:
-        rung = r.point.search_kwargs()
+        # Only the swept fields: the engine's base point keeps its own
+        # verification kernel and centroid window.
+        rung = dataclasses.asdict(r.point)
+        del rung["use_fused"], rung["r0_centroid"]
         rung["expected_recall"] = r.recall
         rungs.append(rung)
     return rungs
@@ -498,13 +435,12 @@ def tune(
     recall_target: float | None = None,
     relevant=None,
     repeats: int = 3,
-    use_fused: bool | None = None,
 ) -> dict:
     """Sweep + frontier + selection, as one JSON-ready report dict."""
     grid = list(grid) if grid is not None else default_grid()
     results = sweep(
         params, queries, gt_ids, grid, k=k, relevant=relevant,
-        repeats=repeats, use_fused=use_fused,
+        repeats=repeats,
     )
     return make_report(
         results, k=k, n_queries=int(queries.shape[0]),
@@ -667,11 +603,9 @@ def main() -> None:
         )
     sel = report.get("selected")
     if sel:
-        sel_point = OperatingPoint(
-            sel["n_probe"], sel["r0"], sel["prune_margin"], sel["refine"],
-            sel["rescore_factor"], sel["block_c"], sel.get("block_q"),
-            sel.get("sketch_factor"),
-        )
+        sel_point = OperatingPoint(**{
+            f.name: sel[f.name] for f in dataclasses.fields(OperatingPoint)
+        })
         print(
             f"[pareto] operating point for recall>={args.recall_target}: "
             f"{sel['storage_dtype']}/{sel_point.label()} "

@@ -123,9 +123,21 @@ def test_no_overflow_build_reports_zero_drops(corpus):
     assert stats.capacity == p.capacity
 
 
+def test_search_takes_only_operating_point_fields(built):
+    """``search_lider`` and ``make_backend("lider")`` take the fields of
+    ``OperatingPoint`` as keywords; any other keyword is a TypeError."""
+    from repro.serving import make_backend
+
+    _, q, _, p = built
+    with pytest.raises(TypeError, match="n_probes"):
+        lider.search_lider(p, q, k=10, n_probes=8)
+    with pytest.raises(TypeError, match="n_probes"):
+        make_backend("lider", p, n_probes=8)
+
+
 def test_route_then_incluster_equals_search(built):
     x, q, _, p = built
-    routed = lider.route_queries(p, q, n_probe=8, r0=4)
+    routed = lider.route_queries(p, q, n_probe=8, r0_centroid=4)
     a = lider.incluster_search(p, q, routed.ids, k=10, r0=8)
     b = lider.search_lider(p, q, k=10, n_probe=8, r0=8, r0_centroid=4)
     np.testing.assert_array_equal(np.asarray(a.ids), np.asarray(b.ids))
@@ -212,7 +224,8 @@ def _funnel(p, q, cids, merge):
     """The first funnel each bank kind serves through."""
     if p.bank.rescore_tier == "host":
         out = lider.provisional_rows(
-            p, q, cids, k=CAND_K, r0=CAND_R0, merge=merge, sketch_factor=2
+            p, q, cids, k=CAND_K, merge=merge,
+            point=lider.OperatingPoint(r0=CAND_R0, sketch_factor=2),
         )
     else:
         out = lider.incluster_search(p, q, cids, k=CAND_K, r0=CAND_R0, merge=merge)

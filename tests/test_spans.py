@@ -21,6 +21,7 @@ CFG = lider.LiderConfig(
     storage_dtype="int8",
 )
 K, BS = 10, 16
+POINT = lider.OperatingPoint(n_probe=8, r0=8)
 ENGINE_SPANS = ("engine.take_batch", "engine.h2d", "engine.dispatch",
                 "engine.wait", "engine.d2h", "engine.record")
 
@@ -49,15 +50,19 @@ def _lowered(banks, which):
     x, q, dev, host = banks
     qs = q[:BS]
     if which == "device":
-        lo = lider._search_lider_device.lower(dev, qs, k=K, n_probe=8, r0=8)
+        lo = lider._search_lider_device.lower(dev, qs, k=K, point=POINT)
     elif which == "host_first_pass":
         lo = lider.host_first_pass.lower(
-            host, qs, k=K, n_probe=8, r0=8, sketch_factor=2
+            host, qs, k=K,
+            point=dataclasses.replace(POINT, sketch_factor=2),
         )
     else:
         fetched = jnp.zeros((BS, 4 * K, x.shape[1]), jnp.float32)
         rows = jnp.zeros((BS, 4 * K), jnp.int32)
-        lo = lider.host_rescore.lower(host.bank.gids, fetched, rows, qs, k=K)
+        lo = lider.host_rescore.lower(
+            host.bank.gids, fetched, rows, qs, k=K,
+            point=lider.OperatingPoint(),
+        )
     return lo.as_text(debug_info=True)
 
 
@@ -83,7 +88,7 @@ def test_host_first_pass_skips_the_sketch_scope_without_a_sketch(banks):
     """The sketch scope appears only where the pre-filter runs."""
     x, q, _, host = banks
     text = lider.host_first_pass.lower(
-        host, q[:BS], k=K, n_probe=8, r0=8
+        host, q[:BS], k=K, point=POINT
     ).as_text(debug_info=True)
     assert "/lider.code_pass/" in text and "/lider.sketch/" not in text
 

@@ -43,6 +43,16 @@ def tier_pair(corpus):
     return x, q, gt, pd, ph
 
 
+@pytest.fixture(scope="module")
+def tier_pair_int4(corpus):
+    """``tier_pair`` for an int4 index (codes two to a byte, sketches)."""
+    x, q, gt = corpus
+    cfg = dataclasses.replace(CFG, storage_dtype="int4")
+    pd = lider.build_lider(jax.random.PRNGKey(0), x, cfg)
+    ph = lider.set_rescore_tier(pd, "host")
+    return x, q, gt, pd, ph
+
+
 # ---------------------------------------------------------------------------
 # Tier plumbing & accounting
 # ---------------------------------------------------------------------------
@@ -199,9 +209,17 @@ def test_embstore_hash_is_content_stable(tier_pair):
 # ---------------------------------------------------------------------------
 
 
-def test_parity_all_live(tier_pair):
-    _, q, _, pd, ph = tier_pair
-    _assert_bit_parity(pd, ph, q)
+@pytest.mark.parametrize("sketch_factor", [None, 4])
+@pytest.mark.parametrize("storage", ["int8", "int4"])
+def test_parity_all_live(request, storage, sketch_factor):
+    """The device tier (one jit: first pass + resident rescore) and the host
+    tier (first-pass jit, host fetch, rescore jit) run the same compressed
+    first pass and the same rescore: bit-identical answers, with and
+    without the sketch pre-filter."""
+    pair = "tier_pair" if storage == "int8" else "tier_pair_int4"
+    _, q, _, pd, ph = request.getfixturevalue(pair)
+    assert pd.bank.storage_dtype == storage
+    _assert_bit_parity(pd, ph, q, sketch_factor=sketch_factor)
 
 
 def test_parity_with_pruning_and_stats(tier_pair):
@@ -386,76 +404,6 @@ def test_open_loop_drain_chunk_one_keeps_overlap(tier_pair):
     s = eng.stats
     assert s.n_host_fetches >= 2
     assert s.overlap_fraction > 0
-
-
-def test_pick_block_q_cost_model():
-    """The autotuner's cost model: singleton clusters (no sharing to
-    exploit) pick the shallowest rung, a hot cluster picks the deepest,
-    and an empty observation window falls back to the first rung."""
-    from repro.serving.engine import pick_block_q
-
-    assert pick_block_q([np.ones(64, np.int64)], (2, 4, 8)) == 2
-    assert pick_block_q([np.full(4, 128, np.int64)], (2, 4, 8)) == 8
-    assert pick_block_q([], (4, 8)) == 4
-
-
-def test_engine_autotunes_block_q_without_retrace(tier_pair):
-    """Online block_q autotuning (staged host-tier serving): each drained
-    batch's measured probe distribution re-picks the rung for the next
-    dispatch, hot traffic climbs to the deepest rung, the measured sharing
-    ratio lands in EngineStats, and — because every rung was pre-warmed in
-    ``warmup`` and the schedule padding is fixed worst-case — the whole
-    adaptation costs ZERO query-path retraces."""
-    from repro.core.lider import query_path_cache_size
-    from repro.serving.engine import pick_block_q
-
-    x, q, _, _, ph = tier_pair
-    ladder = (2, 4, 8)
-    search = make_backend("lider", None, updatable=True, n_probe=8, r0=8)
-    eng = RetrievalEngine(
-        search, batch_size=16, k=10, dim=x.shape[1], params=ph,
-        block_q_ladder=ladder,
-    )
-    eng.warmup()
-    before = query_path_cache_size()
-    # Hot trace: every query is a perturbation of one point, so all probes
-    # concentrate on the same n_probe clusters (counts ~16 per cluster).
-    rng = np.random.default_rng(0)
-    hot = np.asarray(q)[:1] + 1e-3 * rng.normal(size=(48, x.shape[1]))
-    hot /= np.linalg.norm(hot, axis=-1, keepdims=True)
-    rids = [eng.submit(v.astype(np.float32)) for v in hot]
-    eng.drain()
-    assert all(eng.result(r) is not None for r in rids)
-    assert query_path_cache_size() == before  # zero retraces while adapting
-    s = eng.stats
-    assert s.n_sched_pairs == 48 * 8
-    assert 0 < s.n_sched_steps < s.n_sched_pairs
-    assert s.sharing_ratio > 2.0
-    assert s.n_batches == 3  # one measurement per drained batch
-    assert eng._auto_block_q == 8  # hot traffic -> deepest rung...
-    # ...and the live pick is exactly the cost-model argmin over the window.
-    assert pick_block_q(eng._probe_counts, ladder) == 8
-
-
-def test_engine_static_block_q_overrides_autotune(tier_pair):
-    """A static backend ``block_q`` is an explicit operator override: the
-    ladder never injects an auto rung over it (the engine still serves)."""
-    x, q, _, _, ph = tier_pair
-    search = make_backend(
-        "lider", None, updatable=True, n_probe=8, r0=8, block_q=4
-    )
-    eng = RetrievalEngine(
-        search, batch_size=16, k=10, dim=x.shape[1], params=ph,
-        block_q_ladder=(2, 8),
-    )
-    eng.warmup()
-    # The auto rung is suppressed — the static kwarg reaches the search
-    # through the backend's own kwargs, not through an injected point.
-    assert (eng._effective_point() or {}).get("block_q") is None
-    assert search.static_point.get("block_q") == 4
-    rids = [eng.submit(v) for v in np.asarray(q)[:16]]
-    eng.drain()
-    assert all(eng.result(r) is not None for r in rids)
 
 
 def test_engine_host_tier_reports_pruned_probes(tier_pair):
